@@ -56,6 +56,9 @@ def main(backend="numpy", batches=40, overlap=True, store_async=True,
     from tigerbeetle_tpu.tidy.jaxlint import compile_registry
 
     if backend != "numpy":
+        from tigerbeetle_tpu import compilecache
+
+        compilecache.configure()
         compile_registry.install()
         compile_registry.track_default_entries()
     tmp = tempfile.mkdtemp(prefix="tbtpu-prof-")

@@ -71,5 +71,8 @@ def profile(mix):
 MAXS = commit_exact.MAX_SWEEPS
 
 if __name__ == "__main__":
+    from tigerbeetle_tpu import compilecache
+
+    compilecache.configure()
     for mix in sys.argv[1:] or ["config3", "config4"]:
         profile(mix)

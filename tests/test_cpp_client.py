@@ -48,14 +48,13 @@ def test_cpp_sample_against_live_server(sample_bin, tmp_path):
          "--replica", "0", str(path)],
         check=True, capture_output=True,
     )
-    proc = subprocess.Popen(
-        [sys.executable, "-m", "tigerbeetle_tpu.cli", "start",
-         f"--addresses=127.0.0.1:{port}", "--replica=0",
-         "--backend=numpy", str(path)],
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
+    from tigerbeetle_tpu.cli import spawn_replica
+
+    proc, _device = spawn_replica(
+        [f"--addresses=127.0.0.1:{port}", "--replica=0", "--backend=numpy"],
+        str(path),
     )
     try:
-        proc.stdout.readline()  # listening
         run = subprocess.run(
             [sample_bin, "127.0.0.1", str(port)],
             capture_output=True, text=True, timeout=60,

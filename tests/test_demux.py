@@ -178,14 +178,13 @@ class TestAsyncSubmitMany:
              "--replica", "0", str(path)],
             check=True, capture_output=True,
         )
-        proc = subprocess.Popen(
-            [sys.executable, "-m", "tigerbeetle_tpu.cli", "start",
-             f"--addresses=127.0.0.1:{port}", "--replica=0",
-             "--backend=numpy", str(path)],
-            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
+        from tigerbeetle_tpu.cli import spawn_replica
+
+        proc, _device = spawn_replica(
+            [f"--addresses=127.0.0.1:{port}", "--replica=0",
+             "--backend=numpy"], str(path),
         )
         try:
-            proc.stdout.readline()  # listening
             from tigerbeetle_tpu.client import Client
 
             c = Client([("127.0.0.1", port)])

@@ -76,6 +76,34 @@ class TestEnvProfile:
         assert fp["accel_count"] == 0
         assert "jax" not in fp
 
+    def test_failed_probe_raises(self, monkeypatch):
+        """A probe that was asked for and fails must not come back as a
+        cpu-only profile (a chip host whose chip another process holds
+        would be stamped accel_backend "none")."""
+        import jax
+
+        def held(*a, **k):
+            raise RuntimeError("The TPU is already in use by process 1")
+
+        monkeypatch.setattr(jax, "devices", held)
+        with pytest.raises(RuntimeError, match="already in use"):
+            envprofile.fingerprint()
+        # ... while the jax-free probe never asks
+        assert envprofile.fingerprint(allow_jax=False)["accel_count"] == 0
+
+    def test_with_accelerator_takes_what_the_server_reported(self):
+        base = envprofile.fingerprint(allow_jax=False)
+        tpu = envprofile.with_accelerator(base, "tpu", "TPU v5 lite", 1)
+        assert (tpu["accel_backend"], tpu["accel_kind"], tpu["accel_count"]) \
+            == ("tpu", "TPU v5 lite", 1)
+        assert tpu["profile_id"] != base["profile_id"]
+        assert tpu["profile_id"] == envprofile.profile_id_from(tpu)
+        # XLA-CPU and the numpy backend's "none" both stay cpu-only
+        for platform in ("cpu", "none"):
+            same = envprofile.with_accelerator(base, platform, platform, 1)
+            assert same["profile_id"] == base["profile_id"]
+            assert same["accel_backend"] == "none"
+
     def test_record_profile_id_precedence(self):
         env = {"profile_id": "abc123abc123"}
         assert envprofile.record_profile_id(

@@ -317,6 +317,37 @@ class TestCostModel:
         monkeypatch.setenv("TIGERBEETLE_TPU_ROOFLINE_FLOP_PER_BYTE", "50.0")
         assert devicestats.classify(100, 10) == "memory"  # 10 < 50
 
+    def test_classify_by_device_kind_never_by_an_assumed_peak(
+            self, clean_tracer, monkeypatch):
+        """On an accelerator the balance point comes from the peaks
+        table keyed by device_kind (v5e: 197e12 / 819e9 ≈ 240 FLOP/B);
+        a device the table does not list gets NO classification."""
+        monkeypatch.delenv("TIGERBEETLE_TPU_ROOFLINE_FLOP_PER_BYTE",
+                           raising=False)
+
+        class _Dev:
+            def __init__(self, kind):
+                self.device_kind = kind
+
+        class _Jax:
+            def __init__(self, kind):
+                self._kind = kind
+
+            def default_backend(self):
+                return "tpu"
+
+            def devices(self):
+                return [_Dev(self._kind)]
+
+        monkeypatch.setattr(devicestats, "_jax_if_loaded",
+                            lambda: _Jax("TPU v5 lite"))
+        assert devicestats.classify(100, 1) == "memory"    # 100 < 240
+        assert devicestats.classify(1000, 1) == "compute"  # 1000 > 240
+        monkeypatch.setattr(devicestats, "_jax_if_loaded",
+                            lambda: _Jax("TPU v9 imaginary"))
+        assert devicestats.classify(100, 1) is None
+        assert devicestats.classify(None, 1) == "n/a"
+
     def test_cost_for_unknown_entry_is_na(self, clean_tracer):
         devicestats.note_call("create_transfers_fast",
                               (np.zeros(4, np.int32),))

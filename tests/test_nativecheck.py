@@ -179,6 +179,31 @@ def test_strict_warnings_clean():
 
 
 @pytest.mark.skipif(not _HAS_CC, reason="no C compiler")
+def test_build_staleness_keys_on_source_text_not_mtime(tmp_path):
+    """A copied or unpacked tree carries arbitrary file times: a .so
+    NEWER than a source whose text changed must be rebuilt, and an
+    unchanged source must not be (whatever the times say)."""
+    from tigerbeetle_tpu import native
+
+    src = tmp_path / "probe.c"
+    lib = tmp_path / "libprobe.so"
+    src.write_text("int probe(void) { return 1; }\n")
+    assert native._build_lib(str(src), str(lib)) == str(lib)
+    built_one = lib.read_bytes()
+
+    src.write_text("int probe(void) { return 2; }\n")
+    future = os.path.getmtime(src) + 3600
+    os.utime(lib, (future, future))  # the stale .so looks fresher
+    assert native._build_lib(str(src), str(lib)) == str(lib)
+    assert lib.read_bytes() != built_one
+
+    os.utime(lib, (1, 1))  # ... and a current one looks ancient
+    before = os.stat(lib).st_ino
+    assert native._build_lib(str(src), str(lib)) == str(lib)
+    assert os.stat(lib).st_ino == before  # trusted, not rebuilt
+
+
+@pytest.mark.skipif(not _HAS_CC, reason="no C compiler")
 def test_sanitizer_detects_planted_overflow(tmp_path, monkeypatch):
     """The harness mechanism end-to-end on a seeded bug: a sidecar
     build of an out-of-bounds read must produce a sanitizer report in

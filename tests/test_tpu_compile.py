@@ -1,0 +1,151 @@
+"""Ask the TPU's compiler, without a TPU, whether it accepts the served
+path's kernels at production widths (`on-chip-measurement` guide,
+section 2, rehearsal 3): state tables for accounts_max = 2^20, the
+n = 8192 batch bucket an 8190-event message pads to, merge runs of
+2^15 rows. libtpu compiles for a v5e that is DESCRIBED, not attached.
+
+A compile that passes is not a chip run — nothing executes, so nothing
+here says anything about results or times. What it catches, at no chip
+time: a program the chip's compiler refuses, or one whose temporaries
+do not fit the chip's 16 GB.
+
+All of these live in THIS one file and describe the topology inside a
+module-scoped fixture — never at import — because only one process may
+load libtpu: a second test file could land on another worker, whose
+fixture would then skip every test in silence.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+from jax.sharding import SingleDeviceSharding
+
+from tigerbeetle_tpu.constants import PRODUCTION
+from tigerbeetle_tpu.ops import commit as commit_ops
+from tigerbeetle_tpu.ops import commit_exact, merge, qindex, scanops
+
+A = PRODUCTION.accounts_max  # 2^20 account slots on the device
+N = merge.bucket_pow2(PRODUCTION.batch_max)  # 8190 events pad to 8192
+HBM_BYTES = 16 * 10**9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
+    try:
+        t = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from describing it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip (the next one would
+    # warn and compile again): keep it off around these tests.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shapes(tree, sharding):
+    """The same pytree with every array leaf replaced by its shape on
+    the described chip (there is no device to hold an array)."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _compile(fn, one_chip, *args, **static):
+    compiled = fn.lower(*_shapes(args, one_chip), **static).compile()
+    mem = compiled.memory_analysis()
+    resident = (
+        mem.temp_size_in_bytes + mem.argument_size_in_bytes
+        + mem.output_size_in_bytes
+    )
+    assert resident < HBM_BYTES, mem
+
+
+def _ledger_state():
+    return jax.eval_shape(lambda: commit_ops.init_state(A))
+
+
+def _transfer_batch(n):
+    u32 = lambda *shape: np.zeros(shape, np.uint32)
+    return commit_ops.TransferBatch(
+        id=u32(n, 4), dr_slot=np.zeros(n, np.int32),
+        cr_slot=np.zeros(n, np.int32), amount=u32(n, 4),
+        pending_id=u32(n, 4), timeout=u32(n), ledger=u32(n), code=u32(n),
+        flags=u32(n), timestamp=u32(n, 2),
+    )
+
+
+def test_create_transfers_fast(one_chip):
+    _compile(
+        commit_ops.create_transfers_fast, one_chip,
+        _ledger_state(), _transfer_batch(N), np.zeros(N, np.uint32),
+    )
+
+
+@pytest.mark.parametrize("flag", [True, False], ids=["pv+chains", "plain"])
+def test_create_transfers_exact(one_chip, flag):
+    """Both ends of the static-flag square the state machine compiles
+    (has_pv / has_chains follow the batch's content)."""
+    i32 = lambda *shape: np.zeros(shape, np.int32)
+    pending = commit_exact.PendingInfo(
+        found=np.zeros(N, bool), amount=np.zeros((N, 4), np.uint32),
+        dr_slot=i32(N), cr_slot=i32(N),
+        timestamp=np.zeros((N, 2), np.uint32), timeout=np.zeros(N, np.uint32),
+        base_fulfillment=i32(N), group=i32(N),
+    )
+    plan = commit_exact.SortPlan(
+        perm=i32(2 * N), inv_perm=i32(2 * N), head_pos=i32(2 * N),
+        sub_head_pos=i32(2 * N), f_perm=i32(N), f_inv_perm=i32(N),
+        f_head_pos=i32(N), f_sub_head_pos=i32(N),
+    )
+    _compile(
+        commit_exact.create_transfers_exact, one_chip,
+        _ledger_state(), _transfer_batch(N), np.zeros(N, np.uint32),
+        pending, i32(N), plan,
+        has_pv=flag, has_chains=flag,
+    )
+
+
+def test_merge_kernel_tiled(one_chip):
+    rows = 1 << 15
+    run = np.zeros((rows, 3), np.uint32)
+    _compile(merge.merge_kernel_tiled, one_chip, run, run, run, run)
+
+
+def test_compact_fold_kernel(one_chip):
+    stack = np.zeros((8, 1 << 12, 3), np.uint32)
+    _compile(merge.compact_fold_kernel, one_chip, stack, stack)
+
+
+def test_query_index_keys(one_chip):
+    """The key build alone. Its sorted sibling, query_index_keys_sorted,
+    is the served route on a chip and takes the chip's compiler minutes
+    at this width (CHANGES.md, PR 21) — too long for this file."""
+    _compile(
+        qindex.query_index_keys, one_chip,
+        np.zeros((N, 9), np.uint32), np.zeros((N, 2), np.uint32),
+        np.zeros(N, np.uint32), np.zeros(N, np.uint32),
+    )
+
+
+def test_scan_intersect_mask(one_chip):
+    _compile(
+        scanops.scan_intersect_mask, one_chip,
+        np.zeros(N, np.uint32), np.zeros(1 << 17, np.uint32),
+    )

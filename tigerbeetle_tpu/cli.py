@@ -88,6 +88,13 @@ def cmd_start(args) -> int:
     )
     from tigerbeetle_tpu.vsr.clock import SystemTime
 
+    if args.backend == "jax":
+        # Before the first kernel compiles (the state machine's tables
+        # below): a cold start compiles for minutes on a TPU, and every
+        # later start of this checkout reads the cache instead.
+        from tigerbeetle_tpu import compilecache
+
+        compilecache.configure()
     addresses = parse_addresses(args.addresses)
     storage = FileStorage(args.path)
     aof = None
@@ -223,8 +230,12 @@ def cmd_start(args) -> int:
             print(f"metrics on http://127.0.0.1:{args.metrics_port}/metrics "
                   f"(trace: /trace, cluster: /cluster, device: /device)",
                   flush=True)
+        # The device fields are what THIS process — the one that holds
+        # the chip — sees: launchers (cmd_benchmark, chip_smoke.py) read
+        # them here instead of initialising JAX a second time.
         print(f"replica {args.replica}/{len(addresses)} listening on {host}:{port} "
-              f"(backend={args.backend}, status={replica.status})", flush=True)
+              f"(backend={args.backend}, status={replica.status}, "
+              f"{_device_fields(args.backend)})", flush=True)
         await server.serve_forever()
 
     try:
@@ -235,6 +246,85 @@ def cmd_start(args) -> int:
         if tracer.enabled():
             print("TRACER " + tracer.emit_json(), file=sys.stderr, flush=True)
     return 0
+
+
+def _device_fields(backend: str) -> str:
+    """The device part of the `listening` line: platform, device_kind
+    and device count as jax.devices() reports them in this process
+    ("none" for the numpy backend, which never loads JAX)."""
+    import json
+
+    if backend != "jax":
+        return 'platform=none, device_kind="none", device_count=0'
+    import jax
+
+    devices = jax.devices()
+    return (f"platform={devices[0].platform}, "
+            f"device_kind={json.dumps(devices[0].device_kind)}, "
+            f"device_count={len(devices)}")
+
+
+def parse_listening(line: str) -> dict:
+    """{"platform", "device_kind", "device_count"} from a replica's
+    `listening` line (see _device_fields)."""
+    import re
+
+    m = re.search(
+        r'listening on .*platform=(\w+), device_kind="([^"]*)", '
+        r"device_count=(\d+)", line,
+    )
+    if m is None:
+        raise ValueError(f"not a listening line: {line!r}")
+    return {
+        "platform": m.group(1),
+        "device_kind": m.group(2),
+        "device_count": int(m.group(3)),
+    }
+
+
+class ReplicaStartError(RuntimeError):
+    """The child exited (or closed stdout) before announcing its listener."""
+
+
+def spawn_replica(start_args: List[str], path: str, env=None):
+    """Start `cli.py start <start_args> <path>` as a child process and
+    wait for its `listening` line; returns (proc, parse_listening(line)).
+
+    The child's stderr is kept in `<path>.stderr` (appended, so a
+    restart on the same data file keeps the first run's), and a child
+    that exits before the announcement raises ReplicaStartError with the
+    end of it — a replica that died initialising its device must not
+    look like a client time-out. A daemon thread drains stdout
+    afterwards so a chatty replica never blocks on a full pipe."""
+    import subprocess
+    import threading
+
+    stderr_path = path + ".stderr"
+    with open(stderr_path, "ab") as err:
+        proc = subprocess.Popen(
+            [sys.executable, "-m", "tigerbeetle_tpu.cli", "start",
+             *start_args, path],
+            stdout=subprocess.PIPE, stderr=err, env=env,
+        )
+    # Boot chatter (the metrics line, warnings) may precede the announce.
+    for raw in proc.stdout:
+        line = raw.decode("utf-8", "replace")
+        if "listening" in line:
+            threading.Thread(target=proc.stdout.read, daemon=True).start()
+            return proc, parse_listening(line)
+    rc = proc.wait()
+    raise ReplicaStartError(
+        f"replica exited with code {rc} before listening "
+        f"(stderr kept in {stderr_path}):\n{replica_stderr_tail(path)}"
+    )
+
+
+def replica_stderr_tail(path: str, nbytes: int = 4000) -> str:
+    """The end of the stderr spawn_replica kept for data file `path`."""
+    with open(path + ".stderr", "rb") as f:
+        f.seek(0, 2)
+        f.seek(max(0, f.tell() - nbytes))
+        return f.read().decode("utf-8", "replace")
 
 
 def cmd_repl(args) -> int:
@@ -382,19 +472,24 @@ def _http_get_json(port: int, path: str, timeout: float = 10.0):
     return json.loads(body)
 
 
-def _emit_bench_json(result: dict, args) -> None:
+def _emit_bench_json(result: dict, args, device: dict) -> None:
     """Stamp the environment fingerprint (docs/DEVHUB.md — backend +
     host + accelerator profile, so a BENCH_JSON line from a TPU host is
     distinguishable from this container by construction) and print the
-    one machine-readable line both benchmark loops share. Called after
-    the timed phases only: fingerprint() may import jax."""
+    one machine-readable line both benchmark loops share. This process
+    stays off JAX — the spawned server holds the chip — so the
+    accelerator fields are the ones the server announced (`device`,
+    from its `listening` line)."""
     import json
 
-    from tigerbeetle_tpu.envprofile import fingerprint
+    from tigerbeetle_tpu import envprofile
     from tigerbeetle_tpu.net import codec
 
     result["backend"] = args.backend
-    result["env"] = fingerprint()
+    result["env"] = envprofile.with_accelerator(
+        envprofile.fingerprint(allow_jax=False),
+        device["platform"], device["device_kind"], device["device_count"],
+    )
     # Which wire datapath served this run (docs/NATIVE_DATAPATH.md): the
     # spawned server inherits this process's environment/toolchain, so
     # the driver's probe answers for both. Devhub change-point
@@ -412,7 +507,6 @@ def cmd_benchmark(args) -> int:
     percentile plus the server's per-op queue-wait/service decomposition
     and pipeline occupancy (scraped from /lifecycle) — bench.py parses
     that line; its regex over the human output is only a fallback."""
-    import json
     import os
     import subprocess
     import tempfile
@@ -439,7 +533,6 @@ def cmd_benchmark(args) -> int:
         ))
         assert rc == 0
         server_args = [
-            sys.executable, "-m", "tigerbeetle_tpu.cli", "start",
             f"--addresses=127.0.0.1:{port}", "--replica=0",
             f"--config={args.config}", f"--backend={args.backend}",
         ]
@@ -457,16 +550,8 @@ def cmd_benchmark(args) -> int:
             server_args.append("--serial-store")
         if args.commit_depth:
             server_args.append(f"--commit-depth={args.commit_depth}")
-        proc = subprocess.Popen(
-            server_args + [path],
-            stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-        )
+        proc, device = spawn_replica(server_args, path)
         try:
-            # Wait for the listener announcement (the metrics line may
-            # print first).
-            for _ in range(8):
-                if b"listening" in proc.stdout.readline():
-                    break
             client = Client([("127.0.0.1", port)])
             batch = min(args.batch, 8190)
 
@@ -525,7 +610,7 @@ def cmd_benchmark(args) -> int:
                         result["lifecycle_ops"] = lc.get("ops", 0)
                     except (OSError, ValueError) as e:
                         print(f"lifecycle scrape failed: {e}", file=sys.stderr)
-                _emit_bench_json(result, args)
+                _emit_bench_json(result, args, device)
                 return 0
 
             # Pipelined load via the AsyncClient session pool (reference
@@ -673,8 +758,13 @@ def cmd_benchmark(args) -> int:
                 print(f"query latency p90 = {q90 * 1e3:.2f} ms")
             # The machine-readable result line (bench.py parses this;
             # the regex over the human lines above is only a fallback).
-            _emit_bench_json(result, args)
+            _emit_bench_json(result, args, device)
         finally:
+            if proc.poll() is not None:
+                # The server died under the load: show why before the
+                # temporary directory takes its stderr along.
+                print(f"server exited with code {proc.returncode}:\n"
+                      f"{replica_stderr_tail(path)}", file=sys.stderr)
             proc.terminate()
             try:
                 proc.wait(timeout=5)

@@ -70,12 +70,17 @@ _ENTRY_MODULES = {  # tidy: atomic — immutable constant table, never written a
 
 # Roofline balance point (FLOPs per byte at which the machine is
 # compute- and memory-balanced): static arithmetic intensity below it
-# classifies memory-bound, above compute-bound. Backend defaults are
-# order-of-magnitude published ratios (TPU v4 ~275 TFLOP/s / 1.2 TB/s;
-# a GPU ~15-30; host CPUs ~5-10); override for a specific part via
-# TIGERBEETLE_TPU_ROOFLINE_FLOP_PER_BYTE. The classification needs the
-# right side of the balance point, not three digits of peak.
-_BALANCE_DEFAULTS = {"tpu": 230.0, "gpu": 15.0, "cpu": 8.0}  # tidy: atomic — immutable constant table, never written after import
+# classifies memory-bound, above compute-bound. An accelerator's comes
+# from its published peaks, keyed by the device_kind jax reports; a
+# device that is not in the table gets NO classification (bound null),
+# never another part's ratio. XLA-CPU keeps an order-of-magnitude host
+# ratio; TIGERBEETLE_TPU_ROOFLINE_FLOP_PER_BYTE overrides either.
+_DEVICE_PEAKS = {  # tidy: atomic — immutable constant table, never written after import
+    # device_kind: (peak FLOP/s, peak memory bytes/s) — Google Cloud
+    # documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM.
+    "TPU v5 lite": (197e12, 819e9),
+}
+_CPU_BALANCE = 8.0
 
 
 def _spec(x) -> tuple:
@@ -234,24 +239,37 @@ def _backend_platform() -> Optional[str]:
         return None
 
 
-def _balance_flop_per_byte() -> float:
+def _balance_flop_per_byte() -> Optional[float]:
+    """The balance point of the device this process runs on; None where
+    it is not known (no jax loaded, or a device_kind that _DEVICE_PEAKS
+    does not list)."""
     env = os.environ.get("TIGERBEETLE_TPU_ROOFLINE_FLOP_PER_BYTE")  # tidy: allow=env-read — roofline calibration knob, read per call so tests/hosts can retune without reimport
     if env:
         try:
             return float(env)
         except ValueError:
             pass
-    return _BALANCE_DEFAULTS.get(_backend_platform() or "", 10.0)
+    platform = _backend_platform()
+    if platform == "cpu":
+        return _CPU_BALANCE
+    if platform is None:
+        return None
+    peaks = _DEVICE_PEAKS.get(_jax_if_loaded().devices()[0].device_kind)
+    return peaks[0] / peaks[1] if peaks else None
 
 
-def classify(flops: Optional[float], nbytes: Optional[float]) -> str:
+def classify(flops: Optional[float], nbytes: Optional[float]) -> Optional[str]:
     """Roofline bound classification from STATIC cost: arithmetic
-    intensity (FLOPs / bytes accessed) against the backend balance
-    point. "n/a" whenever either static number is missing — a wrong
+    intensity (FLOPs / bytes accessed) against the device's balance
+    point. "n/a" whenever either static number is missing, None (null
+    on the wire) on a device whose peaks are not known — a wrong
     classification is worse than none."""
     if not flops or not nbytes:
         return "n/a"
-    return "compute" if flops / nbytes > _balance_flop_per_byte() else "memory"
+    balance = _balance_flop_per_byte()
+    if balance is None:
+        return None
+    return "compute" if flops / nbytes > balance else "memory"
 
 
 def cost_table(snap: Optional[dict] = None) -> list:

@@ -31,10 +31,10 @@ bench.py's section ordering); jax is only imported inside
 
 Profile-matching rules (docs/DEVHUB.md): tools/bench_gate.py compares
 candidate vs baseline `profile_id` and refuses a numeric verdict on
-mismatch; artifacts recorded before fingerprinting existed (BENCH_r01-
-r05, the pre-round-17 devhub.jsonl rows) are adopted as
-`LEGACY_PROFILE` — the dev container every one of them ran on — so the
-existing trajectory stays comparable.
+mismatch; artifacts recorded before fingerprinting existed (the
+pre-round-17 devhub.jsonl rows) are adopted as `LEGACY_PROFILE` — the
+dev container every one of them ran on — so the existing trajectory
+stays comparable.
 """
 
 from __future__ import annotations
@@ -60,7 +60,7 @@ PROFILE_ID_FIELDS = (
 # recorded on: the Linux/x86_64 2-core, no-accelerator dev container
 # (ROADMAP: "every number in BENCH_r*.json is a 2-core no-accelerator
 # container"). bench_gate/devhub adopt this profile for legacy
-# baselines/rows so the r01-r05 trajectory stays comparable; if the
+# baselines/rows so the early trajectory stays comparable; if the
 # container shape ever changes, legacy artifacts correctly stop
 # matching.
 LEGACY_PROFILE = {
@@ -91,9 +91,8 @@ def fingerprint(allow_jax: bool = True) -> dict:
 
     allow_jax=False keeps the probe jax-free (the accelerator fields
     report "none"); use it from processes that must not pull in the jax
-    runtime. On an accelerator host that makes the id differ from a
-    jax-aware probe — jax-free callers only stamp records that never
-    join a gated series (docs/DEVHUB.md)."""
+    runtime — a parent whose child holds the chip — and add the fields
+    the child reported with with_accelerator()."""
     info = {
         "system": platform.system(),
         "machine": platform.machine(),
@@ -110,22 +109,30 @@ def fingerprint(allow_jax: bool = True) -> dict:
     except Exception:  # pragma: no cover - numpy is baked into the image
         pass
     if allow_jax:
-        try:
-            import jax
+        # A probe that was asked for and fails RAISES: a chip host whose
+        # runtime would not initialise here (another process holds the
+        # chip) must never be stamped as a cpu-only profile.
+        import jax
 
-            info["jax"] = jax.__version__
-            backend = jax.default_backend()
-            if backend != "cpu":
-                devices = jax.devices()
-                info["accel_backend"] = str(backend)
-                info["accel_kind"] = str(
-                    getattr(devices[0], "device_kind", backend)
-                )
-                info["accel_count"] = len(devices)
-        except Exception:
-            # No jax / broken runtime: record a cpu-only profile rather
-            # than failing the benchmark that asked for a stamp.
-            pass
+        info["jax"] = jax.__version__
+        devices = jax.devices()
+        return with_accelerator(
+            info, devices[0].platform, devices[0].device_kind, len(devices)
+        )
+    info["profile_id"] = profile_id_from(info)
+    return info
+
+
+def with_accelerator(info: dict, platform: str, kind: str, count: int) -> dict:
+    """`info` with the accelerator fields taken from what the process
+    that holds the device reported (this one, or a server's `listening`
+    line), profile_id recomputed. XLA-CPU and "no backend" both stamp as
+    "none": a JAX_PLATFORMS=cpu run on a TPU host is a cpu-only profile."""
+    info = dict(info)
+    if platform not in ("cpu", "none"):
+        info["accel_backend"] = str(platform)
+        info["accel_kind"] = str(kind)
+        info["accel_count"] = int(count)
     info["profile_id"] = profile_id_from(info)
     return info
 

@@ -57,25 +57,28 @@ def _flags_hash(flags: Tuple[str, ...]) -> str:
 def _build_lib(src: str, lib: str, extra_flags: tuple = ()) -> Optional[str]:
     """Compile `src` → a shared object; returns the built path or None.
 
-    Staleness keys on BOTH the source mtime and a hash of the full flag
-    set (sidecar stamp `<lib>.flags`): changing flags rebuilds even when
-    the source did not change, and a .so produced under different flags
-    is never trusted. With _FLAGS_ENV set the output itself moves to a
-    flag-hashed sidecar name beside the production library.
+    Staleness keys on a hash of the source TEXT and the full flag set
+    (sidecar stamp `<lib>.flags`), never on file times: a tree that was
+    copied, unpacked or checked out carries arbitrary mtimes, and a .so
+    built from other sources or under other flags is never trusted.
+    With _FLAGS_ENV set the output itself moves to a flag-hashed sidecar
+    name beside the production library.
     """
     flags = (*_BASE_FLAGS, *extra_flags, *_env_flags())
-    fh = _flags_hash(flags)
     if _env_flags():
         base, ext = os.path.splitext(lib)
-        lib = f"{base}.{fh}{ext}"
+        lib = f"{base}.{_flags_hash(flags)}{ext}"
+    with open(src, "rb") as f:
+        fh = hashlib.sha256(
+            " ".join(flags).encode() + b"\0" + f.read()
+        ).hexdigest()[:16]
     stamp = f"{lib}.flags"
     try:
         with open(stamp) as f:
             stamp_ok = f.read().strip() == fh
     except OSError:
         stamp_ok = False
-    if (stamp_ok and os.path.exists(lib)
-            and os.path.getmtime(lib) >= os.path.getmtime(src)):
+    if stamp_ok and os.path.exists(lib):
         return lib
     tmp = f"{lib}.{os.getpid()}.tmp"  # pid-unique: concurrent first builds
     # must not interleave into one output (os.replace is atomic)

@@ -29,23 +29,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # newer jax: top-level export (check_vma spelling)
-    from jax import shard_map as _shard_map
-
-    _NO_REP_KW = {"check_vma": False}
-except ImportError:  # older jax: experimental namespace (check_rep spelling)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _NO_REP_KW = {"check_rep": False}
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """Version-portable shard_map: `check_vma=False` maps onto whichever
-    replication-check kwarg the installed jax spells."""
-    kw = {} if check_vma else dict(_NO_REP_KW)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
 
 from tigerbeetle_tpu.ops import commit as commit_ops
 from tigerbeetle_tpu.ops.commit import LedgerState, TransferBatch, F_PENDING

@@ -1024,37 +1024,30 @@ def _spawn_replica(
     replica: int = 0,
     env: Optional[Dict[str, str]] = None,
 ) -> "object":
-    """Start `cli.py start` detached; returns the Popen once the replica
-    announces its listener (after open(), i.e. after WAL replay — or at
-    EOF, when the process died and the caller's connect will fail). A
-    daemon thread drains stdout afterwards so a chatty replica can never
-    block on a full pipe mid-scenario. `extra_args` rides extra cli.py
-    start flags (the front-door loadgen passes --clients-max etc.).
-    `addresses`/`replica` spawn one member of a multi-replica cluster
-    (default: a single replica on its own port). `env` overlays extra
-    environment on the child (per-replica fault injection: ONE replica
-    started under TIGERBEETLE_TPU_NET_FAULT models one degraded host)."""
-    import subprocess
-    import sys
-    import threading
+    """Start `cli.py start` detached (cli.spawn_replica); returns the
+    Popen once the replica announces its listener (after open(), i.e.
+    after WAL replay). Its stderr is kept in `<path>.stderr`, and a
+    replica that exits before the announcement raises
+    cli.ReplicaStartError with the end of it. `extra_args` rides extra
+    cli.py start flags (the front-door loadgen passes --clients-max
+    etc.). `addresses`/`replica` spawn one member of a multi-replica
+    cluster (default: a single replica on its own port). `env` overlays
+    extra environment on the child (per-replica fault injection: ONE
+    replica started under TIGERBEETLE_TPU_NET_FAULT models one degraded
+    host)."""
+    from tigerbeetle_tpu.cli import spawn_replica
 
     if addresses is None:
         addresses = f"127.0.0.1:{port}"
-    proc = subprocess.Popen(
+    proc, _device = spawn_replica(
         [
-            sys.executable, "-m", "tigerbeetle_tpu.cli", "start",
             f"--addresses={addresses}", f"--replica={replica}",
             f"--config={config}", f"--backend={backend}",
-            f"--metrics-port={mport}", *extra_args, path,
+            f"--metrics-port={mport}", *extra_args,
         ],
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
+        path,
         env={**os.environ, **env} if env else None,
     )
-    for _ in range(256):  # boot chatter (warnings, logging) before the announce
-        line = proc.stdout.readline()
-        if not line or b"listening" in line:
-            break
-    threading.Thread(target=proc.stdout.read, daemon=True).start()
     return proc
 
 

@@ -379,7 +379,8 @@ class _Taint:
         if tail in UNTAINT_CALLS:
             return STATIC
         if d is not None and d.split(".")[0] in ("jnp", "jax"):
-            if tail in ("broadcast_shapes",):
+            # Shape arithmetic and named-axis sizes are trace-time ints.
+            if tail in ("broadcast_shapes", "axis_size"):
                 return STATIC
             return DEVICE
         # Locally-resolved callee with a `static=return` declaration.

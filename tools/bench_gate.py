@@ -28,7 +28,7 @@ a TPU-host run "regressing" against a 2-core-container baseline (or the
 reverse "improving") is a hardware difference, not a code change, so
 every row reports `n/a (profile mismatch)` and the exit is 2 — not
 pass, not fail. Baselines recorded before fingerprinting existed
-(BENCH_r01-r05) are adopted as the dev-container profile
+are adopted as the dev-container profile
 (envprofile.LEGACY_PROFILE) so the existing trajectory keeps gating.
 `--profile` switches baseline selection from "newest BENCH_r*.json" to
 "newest BENCH_*.json whose profile matches the candidate" — the

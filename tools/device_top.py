@@ -72,7 +72,7 @@ def render(statuses: List[Optional[dict]], ports: List[int]) -> str:
                     f"{_fmt(r.get('ms_per_call')):>8} "
                     f"{_fmt(r.get('achieved_gflops')):>8} "
                     f"{_fmt(r.get('achieved_gbps')):>8} "
-                    f"{r.get('bound', 'n/a'):>8s}"
+                    f"{r.get('bound') or 'n/a':>8s}"
                 )
         mem = st.get("mem", {})
         owners = mem.get("owners", {})
