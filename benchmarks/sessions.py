@@ -1,0 +1,223 @@
+"""Client sessions for the benchmark: real TCP, one request in flight each.
+
+A copy of `tigerbeetle_tpu/testing/loadgen.py`'s `_Session` and `LoadGen`
+cut to what a cell needs (one replica, no churn, no identity rotation)
+and mended where the original could not serve as a yardstick:
+
+- the traffic comes from a seeded generator handed in, never from a seed
+  fixed here;
+- every request is kept as a record (sent, done, the reply's body),
+  so the window is cut out afterwards by the times of the replies and
+  warm-up never mixes into a latency list;
+- a reply is kept, not counted: what it says is judged later against the
+  plain reference (`accepted_tx` in the original never reads a code).
+
+A closed loop: each session sends its next batch when the reply lands
+(the next batch is built while the reply is awaited, so a session's think
+time is the seal and the send). The original's open loop is not copied:
+no cell offers load at a fixed rate yet (PERF.md, Open questions).
+"""
+
+from __future__ import annotations
+
+import asyncio
+import dataclasses
+import secrets
+import time
+from typing import Callable, List, Optional
+
+from tigerbeetle_tpu.client import BUSY_RETRY_MAX, busy_backoff_s
+from tigerbeetle_tpu.net.bus import read_message
+from tigerbeetle_tpu.vsr import header as hdr
+from tigerbeetle_tpu.vsr.header import Command, Operation
+
+
+@dataclasses.dataclass
+class Record:
+    """One create_transfers request of one session."""
+
+    session: int
+    seq: int  # the session's own order: batch (session, seq) of the generator
+    events: int
+    sent: float = 0.0  # 0.0: never sent
+    done: float = 0.0  # 0.0: never answered
+    reply: Optional[bytes] = None  # the reply's body (EVENT_RESULT pairs)
+
+    @property
+    def latency(self) -> float:
+        return self.done - self.sent
+
+
+class Session:
+    """One VSR client session on its own TCP connection."""
+
+    CONNECT_RETRIES = 40
+
+    def __init__(self, address, request_timeout: float, cluster: int = 0):
+        self.address = address
+        self.request_timeout = request_timeout
+        self.cluster = cluster
+        self.client_id = secrets.randbits(127) | 1  # a session id, not traffic
+        self.request = 0
+        self.reader = self.writer = None
+        self.resends = 0
+        self.busy = 0
+
+    async def connect(self) -> None:
+        backoff, last = 0.05, None
+        for _ in range(self.CONNECT_RETRIES):
+            try:
+                self.reader, self.writer = await asyncio.open_connection(
+                    *self.address, limit=1 << 21)
+                hello = hdr.make_sealed(Command.PING_CLIENT, self.cluster,
+                                        client=self.client_id)
+                self.writer.write(hello.to_bytes())
+                await self.writer.drain()
+                return
+            except OSError as e:
+                last = e
+                self.reader = self.writer = None
+                await asyncio.sleep(backoff)
+                backoff = min(backoff * 2, 1.0)
+        raise ConnectionError(f"session could not connect: {last!r}")
+
+    def close(self) -> None:
+        if self.writer is not None and self.writer.transport is not None:
+            self.writer.transport.abort()
+        self.reader = self.writer = None
+
+    async def _read_reply(self, request: int):
+        while True:
+            msg = await read_message(self.reader)
+            if msg is None:
+                raise ConnectionResetError("connection lost")
+            h = msg.header
+            if h["command"] == Command.EVICTION and h["client"] == self.client_id:
+                raise ConnectionError("session evicted")
+            if h["client"] != self.client_id or h["request"] != request:
+                continue  # a pong, or a reply to an earlier send
+            if h["command"] in (Command.REPLY, Command.BUSY):
+                return msg
+
+    async def roundtrip(self, operation: int, body: bytes, on_sent=None):
+        """Send, absorb BUSY with the client's own backoff, resend on a
+        time-out or a lost connection (the same request number: the
+        primary answers a duplicate from its table)."""
+        self.request += 1
+        request = self.request
+        frame = hdr.make_sealed(
+            Command.REQUEST, self.cluster, body=body, client=self.client_id,
+            request=request, operation=operation).to_bytes()
+        busy_retries = sends = 0
+        while True:
+            if self.writer is None:
+                await self.connect()
+            try:
+                self.writer.write(frame)
+                if on_sent is not None and sends == 0:
+                    on_sent()
+                sends += 1
+                await self.writer.drain()
+                reply = await asyncio.wait_for(self._read_reply(request),
+                                               self.request_timeout)
+            except asyncio.TimeoutError:
+                self.resends += 1
+                if sends > 8:
+                    raise
+                continue
+            except (OSError, ConnectionResetError):
+                self.resends += 1
+                self.close()
+                if sends > 8:
+                    raise
+                continue
+            if reply.header["command"] == Command.BUSY:
+                busy_retries += 1
+                self.busy += 1
+                if busy_retries > BUSY_RETRY_MAX:
+                    raise TimeoutError("persistently BUSY")
+                await asyncio.sleep(busy_backoff_s(busy_retries))
+                continue
+            return reply
+
+    async def register(self) -> None:
+        await self.roundtrip(Operation.REGISTER, b"")
+
+
+class Load:
+    """The sessions of one run and every request they made.
+
+    `make(session, seq)` returns the events of that batch (a structured
+    array); it is called in each session's own order."""
+
+    def __init__(self, address, sessions: int, make: Callable, request_timeout: float):
+        self.make = make
+        self.sessions = [Session(address, request_timeout)
+                         for _ in range(sessions)]
+        self.records: List[Record] = []
+        self.completed = 0
+        self.stopping = False
+        self.errors: List[str] = []
+        self._progress = asyncio.Event()
+
+    async def _closed(self, s: int) -> None:
+        sess = self.sessions[s]
+        seq = 0
+        body = self.make(s, seq)
+        while not self.stopping:
+            rec = Record(s, seq, len(body))
+            self.records.append(rec)
+            call = asyncio.ensure_future(sess.roundtrip(
+                Operation.CREATE_TRANSFERS, body.tobytes(),
+                on_sent=lambda r=rec: self._stamp(r)))
+            await asyncio.sleep(0)  # let the frame go out first
+            seq += 1
+            body = self.make(s, seq)  # built while the reply is awaited
+            if not await self._finish(rec, call):
+                return
+
+    def _stamp(self, rec: Record) -> None:
+        rec.sent = time.perf_counter()
+
+    async def _finish(self, rec: Record, call) -> bool:
+        try:
+            reply = await call
+        except (OSError, ConnectionError, asyncio.TimeoutError, TimeoutError) as e:
+            self.errors.append(f"session {rec.session} batch {rec.seq}: {e!r}")
+            self._progress.set()
+            return False
+        rec.done = time.perf_counter()
+        rec.reply = reply.body
+        self.completed += 1
+        self._progress.set()
+        return True
+
+    # lifecycle -----------------------------------------------------------
+
+    async def start(self) -> None:
+        async def one(sess):
+            await sess.connect()
+            await sess.register()
+
+        await asyncio.gather(*[one(s) for s in self.sessions])
+        self.tasks = [asyncio.ensure_future(self._closed(s))
+                      for s in range(len(self.sessions))]
+
+    async def until_completed(self, batches: int) -> None:
+        """Returns once `batches` requests have been answered (or a
+        session has given up: the caller reads `errors`)."""
+        while self.completed < batches and not self.errors:
+            self._progress.clear()
+            await self._progress.wait()
+
+    async def drain(self, timeout: float) -> None:
+        """No new requests; wait for the ones in flight up to `timeout` seconds."""
+        self.stopping = True
+        done, pending = await asyncio.wait(self.tasks, timeout=timeout)
+        for t in pending:
+            t.cancel()
+        for t in done:
+            if t.exception() is not None:
+                self.errors.append(repr(t.exception()))
+        for s in self.sessions:
+            s.close()

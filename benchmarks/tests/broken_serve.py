@@ -1,0 +1,122 @@
+"""`benchmarks/serve.py` with a fault planted in the program underneath,
+chosen by BENCH_FAULT. Used by test_faults.py (on the CPU: `correct` must
+come out false for every fault a cell can have) and by control_on_chip.py
+(on the chip, at the cells' own size: the control and the faults set the
+upper reading of each compared number).
+
+  lossy_scatter    THE CONTROL: balances are posted by a scatter that
+                   does not accumulate, so of two events of one batch on
+                   one account only one counts: the shortcut a faster
+                   posting would be tempted by (`unique_indices`, `.set`).
+                   Breaks "every balance exact". The control of the cells
+                   on the fast kernel only: the exact kernel notices the
+                   pending amounts it lost (a post or void underflows),
+                   bails, and the host's serial path answers every batch
+                   in its place, rightly and at a crawl.
+  chains_unlinked  THE CONTROL where batches carry linked chains: every
+                   event is its own chain, so the links of a chain that
+                   must roll back are applied, the shortcut that spares
+                   the kernel its chain bookkeeping. Breaks "a linked
+                   chain commits whole or not at all".
+  state_unchanged  the commit kernels return the state they were given
+  half_left_out    the kernels' new state is kept for the even account
+                   slots only: half of the work left out
+  code_altered     event 0 of every batch is answered with a failure code
+                   though the kernel applied it: an answer altered where
+                   it is produced
+  store_altered    lookup_transfers returns the first transfer of every
+                   answer with its amount off by one
+"""
+
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.dirname(os.path.dirname(HERE)))
+sys.path.insert(0, os.path.dirname(HERE))
+
+
+def plant(fault: str) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from tigerbeetle_tpu.models import state_machine
+    from tigerbeetle_tpu.ops import commit, u128
+
+    def mix(new, old, keep_new):
+        return type(new)(*[jnp.where(keep_new(n), n, o) for n, o in zip(new, old)])
+
+    if fault == "lossy_scatter":
+        def scatter_add(table, slots, values, mask):
+            halves = u128.split_u16(values)
+            halves = jnp.where(mask[:, None], halves, jnp.zeros_like(halves))
+            safe = jnp.where(mask, slots, table.shape[0]).astype(jnp.int32)
+            acc = jnp.zeros((table.shape[0], 2 * table.shape[1]), dtype=jnp.uint32)
+            acc = acc.at[safe].set(halves, mode="drop")  # the last write wins
+            delta, delta_over = u128.combine_u16(acc)
+            new_table, over = u128.add(table, delta)
+            return new_table, (over | delta_over)
+
+        u128.scatter_add = scatter_add
+    elif fault in ("state_unchanged", "half_left_out", "code_altered"):
+        def keep(new_state, old_state):
+            if fault == "state_unchanged":
+                return old_state
+            if fault == "half_left_out":
+                even = lambda leaf: (jnp.arange(leaf.shape[0]) % 2 == 0).reshape(  # noqa: E731
+                    (-1,) + (1,) * (leaf.ndim - 1))
+                return mix(new_state, old_state, even)
+            return new_state
+
+        fast, exact = commit.create_transfers_fast, commit.create_transfers_exact
+
+        @jax.jit
+        def broken_fast(state, b, host_code):
+            new_state, codes, bail = fast(state, b, host_code)
+            if fault == "code_altered":
+                codes = codes.at[0].set(jnp.where(codes[0] == 0, 18, codes[0]))
+            return keep(new_state, state), codes, bail
+
+        def broken_exact(state, *args, **kw):
+            new_state, codes, *rest = exact(state, *args, **kw)
+            if fault == "code_altered":
+                codes = codes.at[0].set(jnp.where(codes[0] == 0, 18, codes[0]))
+            return (keep(new_state, state), codes, *rest)
+
+        commit.create_transfers_fast = broken_fast
+        commit.create_transfers_exact = broken_exact
+    elif fault == "chains_unlinked":
+        import numpy as np
+
+        from tigerbeetle_tpu.ops import commit_exact
+
+        exact = commit.create_transfers_exact
+
+        def unlinked_exact(state, b, host_code, pending, chain_id, plan=None, **kw):
+            alone = np.arange(len(chain_id), dtype=np.int32)
+            plan = commit_exact.build_sort_plan(
+                np.asarray(b.flags), np.asarray(b.dr_slot), np.asarray(b.cr_slot),
+                pending.dr_slot, pending.cr_slot, alone, pending.group,
+                int(state.ledger.shape[0]))
+            return exact(state, b, host_code, pending, alone, plan, **kw)
+
+        commit.create_transfers_exact = unlinked_exact
+    elif fault == "store_altered":
+        real = state_machine.StateMachine.lookup_transfers
+
+        def lookup_transfers(self, ids_lo, ids_hi):
+            out = real(self, ids_lo, ids_hi).copy()
+            if len(out):
+                out["amount_lo"][0] += 1
+            return out
+
+        state_machine.StateMachine.lookup_transfers = lookup_transfers
+    else:
+        raise SystemExit(f"unknown BENCH_FAULT {fault!r}")
+
+
+if __name__ == "__main__":
+    from benchmarks import serve
+
+    plant(os.environ["BENCH_FAULT"])
+    sys.exit(serve.main(sys.argv[1:]))

@@ -1,0 +1,82 @@
+"""BENCHMARK.json against the contract's limits that a typo can break,
+and against the files the harness will look for by name."""
+
+import json
+import os
+import re
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+
+def manifest() -> dict:
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def line(text: str) -> bool:
+    return 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
+
+
+def test_keys_names_units_and_lines():
+    m = manifest()
+    assert set(m) == {"command", "paths", "run_seconds", "configs", "workloads",
+                      "end_to_end", "per_layer"}
+    assert isinstance(m["run_seconds"], int) and 1 <= m["run_seconds"] <= 51
+    names = []
+    for c in m["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"]) and line(c["source"]) and line(c["why"])
+        assert c["file"].startswith(tuple(p + "/" for p in m["paths"]))
+        assert os.path.exists(os.path.join(REPO, c["file"]))
+        assert len(c["reduced"]) <= 16 and all(NAME.match(k) for k in c["reduced"])
+        with open(os.path.join(REPO, c["file"])) as f:
+            assert set(c["reduced"]) == set(json.load(f)["reduced"])
+        names.append(c["name"])
+    for w in m["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"]) and line(w["why"])
+        assert w["config"] in {c["name"] for c in m["configs"]} and w["chips"] in (1, 4)
+        assert os.path.exists(os.path.join(REPO, "benchmarks", "traffic", w["traffic"] + ".json"))
+        names.append(w["name"])
+    assert {w["config"] for w in m["workloads"]} == {c["name"] for c in m["configs"]}
+    assert len({(w["config"], w["traffic"]) for w in m["workloads"]}) == len(m["workloads"])
+    for metric in m["end_to_end"] + m["per_layer"]:
+        assert NAME.match(metric["name"]) and UNIT.match(metric["unit"])
+        assert metric["better"] in ("lower", "higher") and metric["source"] in SOURCES
+        names.append(metric["name"])
+    assert len(names) == len(set(names))
+    for e in m["end_to_end"]:
+        assert set(e) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
+        assert e["source"] in ("host_clock", "device_trace") and 0.01 <= e["bound"] <= 0.25
+    assert "setup_s" in {e["name"] for e in m["end_to_end"]}
+    assert len(json.dumps(m)) < 64 * 1024
+
+
+def test_each_layer_metric_has_its_reader_and_moves_what_its_cells_report():
+    m = manifest()
+    cells = [w["name"] for w in m["workloads"]]
+    reported = {e["name"]: set(e.get("workloads", cells)) for e in m["end_to_end"]}
+    layers = set()
+    for p in m["per_layer"]:
+        assert set(p) - {"workloads"} == {"name", "unit", "better", "source", "layer", "moves"}
+        assert line(p["layer"])
+        layers.add(p["layer"])
+        for cell in p.get("workloads", cells):
+            assert cell in reported[p["moves"]], (p["name"], cell)
+        with open(os.path.join(REPO, "benchmarks", "layer_metrics", p["name"] + ".json")) as f:
+            spec = json.load(f)
+        assert os.path.exists(os.path.join(REPO, "benchmarks", "readers", spec["reader"] + ".py"))
+        if spec.get("needed_work"):
+            assert os.path.exists(os.path.join(
+                REPO, "benchmarks", "needed_work", spec["needed_work"] + ".py"))
+        if p["name"].endswith("_roofline"):
+            assert p["unit"] == "%" and p["source"] == "device_trace"
+    for cell in cells:  # every cell reports a per-layer metric
+        assert any(cell in p.get("workloads", cells) for p in m["per_layer"])
+    with open(os.path.join(REPO, "PERF.md")) as f:
+        perf = f.read()
+    for layer in layers:  # PERF.md's list of layers has each by that name
+        assert f"**{layer}**" in perf, layer
