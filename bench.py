@@ -440,7 +440,7 @@ def bench_exact(mix: str):
     @jax.jit
     def window(state):
         def body(st, _):
-            st2, codes, amounts, dra, cra, bail = (
+            st2, codes, amounts, dra, cra, bail, _sweeps = (
                 commit_exact.create_transfers_exact_impl(
                     st, b, host_code, pending, chain_id, plan,
                     has_pv=has_pv, has_chains=has_chains,

@@ -22,7 +22,7 @@ def _window(args, s, has_pv, has_chains):
     @jax.jit
     def window(state):
         def body(st, _):
-            st2, *_, bail = commit_exact.create_transfers_exact_impl(
+            st2, *_, bail, _sweeps = commit_exact.create_transfers_exact_impl(
                 st, b, host_code, pending, chain_id, plan,
                 max_sweeps=s, has_pv=has_pv, has_chains=has_chains,
             )

@@ -93,19 +93,19 @@ def test_scripted_decomposition_exact(traced):
 
 
 def test_commit_inflight_flat_keys(traced):
-    """The cross-batch commit-window occupancy export: raw-depth
-    histogram → commit_inflight_mean/max/p99, plus the configured depth
-    from the pipeline.commit.depth_config gauge (recorded so A/Bs can
-    see which depth the adaptive default selected)."""
+    """The cross-batch commit-window occupancy export: the per-depth
+    counters → commit_inflight_mean/max/p99, exact, plus the configured
+    depth from the pipeline.commit.depth_config gauge (recorded so A/Bs
+    can see which depth the adaptive default selected)."""
     for d in (1, 2, 3, 4, 4, 4):
-        tracer.observe("pipeline.commit.inflight_depth", d)
+        tracer.count(f"pipeline.commit.inflight.d{d}")
     tracer.gauge("pipeline.commit.depth_config", 4)
     flat = tracer.lifecycle_summary()["flat"]
     assert flat["commit_inflight_mean"] == pytest.approx(3.0)
     assert flat["commit_inflight_max"] == 4
-    # Histogram percentile in RAW depth units (12.5% bucket resolution).
-    assert flat["commit_inflight_p99"] == pytest.approx(4.0, rel=0.13)
+    assert flat["commit_inflight_p99"] == 4.0
     assert flat["commit_depth"] == 4.0
+    assert "pipeline.commit.inflight_depth" not in tracer.snapshot()
 
 
 def test_commit_inflight_absent_without_samples(traced):

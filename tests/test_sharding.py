@@ -120,13 +120,14 @@ def test_sharded_exact_matches_single(mesh):
         group=np.full(N, N, dtype=np.int32),
     )
 
-    new_1, codes_1, amounts_1, _, _, bail_1 = commit_exact.create_transfers_exact(
+    new_1, codes_1, amounts_1, _, _, bail_1, sweeps_1 = commit_exact.create_transfers_exact(
         state_1, b, host_code, pinfo, chain_id
     )
     step = sharding.make_sharded_commit_exact(mesh, A)
-    new_n, codes_n, amounts_n, _, _, bail_n = step(state_n, b, host_code, pinfo, chain_id)
+    new_n, codes_n, amounts_n, _, _, bail_n, sweeps_n = step(state_n, b, host_code, pinfo, chain_id)
 
     assert not bool(bail_1) and not bool(bail_n)
+    assert int(sweeps_1) == int(sweeps_n) >= 1
     np.testing.assert_array_equal(np.asarray(codes_1), np.asarray(codes_n))
     np.testing.assert_array_equal(np.asarray(amounts_1), np.asarray(amounts_n))
     assert int((np.asarray(codes_1) == 0).sum()) > 0

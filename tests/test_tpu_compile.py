@@ -75,6 +75,7 @@ def _compile(fn, one_chip, *args, **static):
         + mem.output_size_in_bytes
     )
     assert resident < HBM_BYTES, mem
+    return compiled
 
 
 def _ledger_state():
@@ -114,12 +115,17 @@ def test_create_transfers_exact(one_chip, flag):
         sub_head_pos=i32(2 * N), f_perm=i32(N), f_inv_perm=i32(N),
         f_head_pos=i32(N), f_sub_head_pos=i32(N),
     )
-    _compile(
+    compiled = _compile(
         commit_exact.create_transfers_exact, one_chip,
         _ledger_state(), _transfer_batch(N), np.zeros(N, np.uint32),
         pending, i32(N), plan,
         has_pv=flag, has_chains=flag,
     )
+    # The v5e program carries the sweep count out beside the bail flag:
+    # (state, codes, amounts, dr_after, cr_after, bail, sweeps).
+    *_, bail, sweeps = compiled.out_info
+    assert (bail.shape, bail.dtype) == ((), np.bool_)
+    assert (sweeps.shape, sweeps.dtype) == ((), np.int32)
 
 
 def test_merge_kernel_tiled(one_chip):

@@ -199,6 +199,8 @@ class StateMachine:
         if backend == "jax":
             from tigerbeetle_tpu.ops import commit as commit_ops
 
+            # Before the first device call, so that its compile is seen.
+            tracer.attach_jax()
             if mesh is not None:
                 # Multi-chip: the same dispatcher over slot-sharded state
                 # (parallel/sharded_ops.py adapter).
@@ -632,18 +634,18 @@ class StateMachine:
         # the RETRY after repair resumes at the faulted stage — re-running
         # completed stages would give their trees extra beats for this op
         # and diverge the deterministic allocation order from peers.
-        quota = self._compact_quota()
-        tracer.gauge("sm.compact.quota", quota)
-        stages = (
-            lambda: self.transfer_log.flush_pending(max_blocks),
-            lambda: self.history.flush_pending(max_blocks),
-            lambda: self.transfer_index.compact_step(quota),
-            lambda: self.account_rows.compact_step(quota),
-            lambda: self.query_rows.compact_step(quota),
-            lambda: self.posted.compact_step(quota),
-            lambda: self.history.compact_step(quota),
-        )
         with tracer.span("sm.beat"):
+            quota = self._compact_quota()
+            tracer.gauge("sm.compact.quota", quota)
+            stages = (
+                lambda: self.transfer_log.flush_pending(max_blocks),
+                lambda: self.history.flush_pending(max_blocks),
+                lambda: self.transfer_index.compact_step(quota),
+                lambda: self.account_rows.compact_step(quota),
+                lambda: self.query_rows.compact_step(quota),
+                lambda: self.posted.compact_step(quota),
+                lambda: self.history.compact_step(quota),
+            )
             while self._beat_stage < len(stages):
                 stages[self._beat_stage]()
                 self._beat_stage += 1
@@ -1018,9 +1020,13 @@ class StateMachine:
         if n == 0:
             return np.zeros(0, dtype=types.EVENT_RESULT_DTYPE)
 
-        staged = self._ct_stage_native(events, timestamp)
+        with tracer.span("sm.ct.stage"):
+            staged = self._ct_stage_native(events, timestamp)
+            hard = None if staged is None else self._staged_hard(events, staged)
         if staged is not None:
-            return self._create_transfers_staged(events, timestamp, staged)
+            return self._create_transfers_staged(events, timestamp, staged, hard)
+        # No C staging shim: the numpy passes below keep their own finer
+        # spans (sm.ct.dupcheck, sm.ct.slots) and are not tiled.
         ts = np.uint64(timestamp) - np.uint64(n) + 1 + np.arange(n, dtype=np.uint64)
 
         flags16 = events["flags"]
@@ -1134,30 +1140,40 @@ class StateMachine:
         numpy-staged dispatchers land here): pack, run the fast kernel,
         bail to serial on overflow, store OK rows."""
         n = len(events)
-        b, host_code_p = self._device_batch(events, ts, dr_slots, cr_slots, host_code)
-        devicestats.note_call(
-            "create_transfers_fast", (self.state, b, host_code_p),
-            bucket=len(host_code_p),
-        )
-        t_disp = tracer.device_dispatch(
-            "create_transfers_fast", h2d_bytes=_staged_nbytes(b, host_code_p)
-        )
-        with tracer.span("sm.create_transfers.fast"):
+        with tracer.span("sm.ct.stage"):
+            b, host_code_p = self._device_batch(events, ts, dr_slots, cr_slots, host_code)
+            devicestats.note_call(
+                "create_transfers_fast", (self.state, b, host_code_p),
+                bucket=len(host_code_p),
+            )
+        with tracer.span("sm.ct.dispatch"):
+            t_disp = tracer.device_dispatch(
+                "create_transfers_fast", h2d_bytes=_staged_nbytes(b, host_code_p)
+            )
             new_state, codes_dev, bail = self._ops.create_transfers_fast(
                 self.state, b, host_code_p
             )
-        if bool(bail):
-            # The bail sync ends the device step: close the window here
-            # or the dispatch/step counters diverge on bail-heavy loads.
-            tracer.device_finish("create_transfers_fast", t_disp)
+        with tracer.span("sm.ct.sync"):
+            # The bail sync ends the device step too: the window closes
+            # here either way, or the dispatch/step counters diverge on
+            # bail-heavy loads.
+            bailed = bool(bail)
+            codes_h = None if bailed else np.asarray(codes_dev)
+            tracer.device_finish(
+                "create_transfers_fast", t_disp,
+                d2h_bytes=0 if bailed else codes_h.nbytes,
+            )
+        if bailed:
             self._count_route("bail_batches")
             return self._create_transfers_serial(events, timestamp)
         self.state = new_state
         self._count_route("fast_batches")
-        codes_h = np.asarray(codes_dev)
-        tracer.device_finish("create_transfers_fast", t_disp, d2h_bytes=codes_h.nbytes)
-        codes = codes_h[:n]
+        with tracer.span("sm.ct.post"):
+            return self._ct_post_fast(events, ts, codes_h[:n])
 
+    def _ct_post_fast(self, events, ts, codes) -> np.ndarray:
+        """The fast kernel's codes → the reply's result records, with the
+        OK rows deferred to the store."""
         ok = codes == 0
         if np.any(ok):
             if ok.all():
@@ -1184,25 +1200,13 @@ class StateMachine:
     # batch N+1 must see batch N's stored ids), and (b) stores still land
     # strictly in op order (dispatch writes nothing; finish stores).
 
-    def create_transfers_dispatch(self, events: np.ndarray, timestamp: int):
-        """Stage + dispatch the device fast kernel WITHOUT syncing.
-        Returns a handle for create_transfers_finish, or None when the
-        batch routes anywhere but the fast device path (duplicates,
-        exact-kernel flags, pending/post-void, id overlap with the
-        outstanding handle, no device backend) — the caller then runs the
-        ordinary create_transfers at its op's turn."""
-        if self._ops is None or self.mesh is not None:
-            return None
-        if len(self._ct_pending) >= DISPATCH_WINDOW_MAX:
-            # Window full: refuse — the caller settles the oldest batch
-            # first (a pipeline stall, never corruption). Also keeps the
-            # scratch ring's slot-reuse distance ≥ the in-flight count.
-            return None
-        events = np.atleast_1d(events)
+    def _ct_dispatch_stage(self, events: np.ndarray, timestamp: int):
+        """Everything between taking the batch and the jitted call: the C
+        staging pass, the routing bits, the overlap probes against the
+        outstanding handles, the bloom confirm, the padded device batch.
+        (ts, batch, host codes), or None when the batch cannot be
+        dispatched ahead."""
         n = len(events)
-        if n == 0:
-            return None
-        self.flush_deferred()
         staged = self._ct_stage_native(events, timestamp)
         if staged is None:
             return None  # no C staging shim: keep the single-phase path
@@ -1245,7 +1249,41 @@ class StateMachine:
             "create_transfers_fast", (self.state, b, host_code_p),
             bucket=len(host_code_p),
         )
+        return ts, b, host_code_p
+
+    def create_transfers_dispatch(self, events: np.ndarray, timestamp: int):
+        """Stage + dispatch the device fast kernel WITHOUT syncing.
+        Returns a handle for create_transfers_finish, or None when the
+        batch routes anywhere but the fast device path (duplicates,
+        exact-kernel flags, pending/post-void, id overlap with the
+        outstanding handle, no device backend) — the caller then runs the
+        ordinary create_transfers at its op's turn."""
+        if self._ops is None or self.mesh is not None:
+            return None
+        if len(self._ct_pending) >= DISPATCH_WINDOW_MAX:
+            # Window full: refuse — the caller settles the oldest batch
+            # first (a pipeline stall, never corruption). Also keeps the
+            # scratch ring's slot-reuse distance ≥ the in-flight count.
+            return None
+        events = np.atleast_1d(events)
+        n = len(events)
+        if n == 0:
+            return None
+        self.flush_deferred()
+        with tracer.span("sm.ct.stage"):
+            staged = self._ct_dispatch_stage(events, timestamp)
+        if staged is None:
+            return None
+        ts, b, host_code_p = staged
         with tracer.span("sm.ct.dispatch"):
+            # Device-step profiler: the window opens before the call, as
+            # on the single-phase and exact paths (the device may start at
+            # once), and the finish seam closes it. (No materialization
+            # here: this function is deliberately OUTSIDE the jaxlint sync
+            # seam.)
+            t_disp = tracer.device_dispatch(
+                "create_transfers_fast", h2d_bytes=_staged_nbytes(b, host_code_p)
+            )
             new_state, codes_dev, bail_dev = self._ops.create_transfers_fast(
                 self.state, b, host_code_p
             )
@@ -1253,15 +1291,7 @@ class StateMachine:
             "events": events, "ts": ts, "timestamp": timestamp, "n": n,
             "codes": codes_dev, "bail": bail_dev,
             "prev_state": self.state, "gen": self._state_gen,
-            "id_lo": events["id_lo"],
-            # Device-step profiler: dispatch timestamp; finish closes the
-            # dispatch→finish window — device time isolated from the host
-            # work between the two calls. (No materialization here: this
-            # function is deliberately OUTSIDE the jaxlint sync seam.)
-            "t_disp": tracer.device_dispatch(
-                "create_transfers_fast",
-                h2d_bytes=_staged_nbytes(b, host_code_p),
-            ),
+            "id_lo": events["id_lo"], "t_disp": t_disp,
         }
         # Chain optimistically: batch N+1's kernel may consume this token
         # before N's sync lands (the device orders the data dependency).
@@ -1289,30 +1319,26 @@ class StateMachine:
             tracer.device_finish("create_transfers_fast", handle.get("t_disp", 0))
             self._state_gen += 1
             return self._create_transfers_impl(events, timestamp)
-        if bool(handle["bail"]):
-            tracer.device_finish("create_transfers_fast", handle.get("t_disp", 0))
+        with tracer.span("sm.ct.sync"):
+            bailed = bool(handle["bail"])
+            codes_h = None if bailed else np.asarray(handle["codes"])
+            tracer.device_finish(
+                "create_transfers_fast", handle.get("t_disp", 0),
+                d2h_bytes=0 if bailed else codes_h.nbytes,
+            )
+        if bailed:
             self.state = handle["prev_state"]
             self._state_gen += 1
             self._count_route("bail_batches")
             return self._create_transfers_serial(events, timestamp)
         self._count_route("fast_batches")
-        ts = handle["ts"]
-        codes_h = np.asarray(handle["codes"])
-        tracer.device_finish(
-            "create_transfers_fast", handle.get("t_disp", 0),
-            d2h_bytes=codes_h.nbytes,
-        )
-        codes = codes_h[:n]
-        ok = codes == 0
-        if np.any(ok):
-            if ok.all():
-                self._defer_store(events, ts)
-            else:
-                recs = events[ok].copy()
-                recs["timestamp"] = ts[ok]
-                self._defer_store(recs)
-            self.commit_timestamp = int(ts[ok][-1])
-        return _codes_to_results(codes)
+        with tracer.span("sm.ct.post"):
+            results = self._ct_post_fast(events, handle["ts"], codes_h[:n])
+            # The handle holds the last references to the kernel's outputs
+            # and to the state before it: letting go of device buffers can
+            # take as long as the posting, and is part of it.
+            handle.clear()
+            return results
 
     def create_transfers_abandon_all(self) -> None:
         """Discard EVERY dispatched-but-unfinished handle (depth-N window
@@ -1357,17 +1383,12 @@ class StateMachine:
             return min(self.config.pipeline_max, 4)
         return 1
 
-    def _create_transfers_staged(
-        self, events: np.ndarray, timestamp: int, staged
-    ) -> np.ndarray:
-        """Routing + commit from the C-staged batch (same decisions as the
-        numpy fallback path in create_transfers, same byte-exact results —
-        the staged ladder IS host_kernel.validate's merged ladder)."""
-        (code, host_code, dr_slots, cr_slots, amt_lo, amt_hi,
-         pend_u8, maybe_u8, bits) = staged
+    def _staged_hard(self, events: np.ndarray, staged):
+        """(hard, is_pv, pv_keys): does the C-staged batch need the serial
+        path (duplicate ids in the batch or in the store, a post/void of a
+        pending created in this same batch)?"""
+        maybe_u8, bits = staged[-2:]
         n = len(events)
-        ts = np.uint64(timestamp) - np.uint64(n) + 1 + np.arange(n, dtype=np.uint64)
-
         hard = bool(bits & 1)  # duplicate ids within the batch
         if not hard and (bits & 4):
             # Bloom hits: stored ids (or ~2% false positives) — confirm
@@ -1395,6 +1416,20 @@ class StateMachine:
                 hit, np.ones(len(pv_keys), dtype=bool),
             )
             hard = bool(np.any(hit == 0))
+        return hard, is_pv, pv_keys
+
+    def _create_transfers_staged(
+        self, events: np.ndarray, timestamp: int, staged, routed
+    ) -> np.ndarray:
+        """Routing + commit from the C-staged batch (same decisions as the
+        numpy fallback path in create_transfers, same byte-exact results —
+        the staged ladder IS host_kernel.validate's merged ladder).
+        `routed` is `_staged_hard`'s answer for it."""
+        (code, host_code, dr_slots, cr_slots, amt_lo, amt_hi,
+         pend_u8, _maybe_u8, bits) = staged
+        hard, is_pv, pv_keys = routed
+        n = len(events)
+        ts = np.uint64(timestamp) - np.uint64(n) + 1 + np.arange(n, dtype=np.uint64)
         if hard:
             self._count_route("serial_batches")
             with tracer.span("sm.create_transfers.serial"):
@@ -1443,20 +1478,9 @@ class StateMachine:
             self._count_route("bail_batches")
             return self._create_transfers_serial(events, timestamp)
         self._count_route("fast_batches")
-        if np.any(ok):
-            # Defer the store past the reply send (replica._finish_commit
-            # flushes in op order): the reply is fully determined here.
-            if ok.all():
-                # Zero-copy: the log's append stamps timestamps during
-                # its own copy; `events` is never mutated (the view keeps
-                # the wire body alive via the array base).
-                self._defer_store(events, ts)
-            else:
-                recs = events[ok].copy()
-                recs["timestamp"] = ts[ok]
-                self._defer_store(recs)
-            self.commit_timestamp = int(ts[ok][-1])
-        return _codes_to_results(codes)
+        # The store is deferred past the reply send (replica._finish_commit
+        # flushes in op order): the reply is fully determined here.
+        return self._ct_post_fast(events, ts, codes)
 
     def _device_batch(self, events, ts, dr_slots, cr_slots, host_code):
         """Pack events into the kernel's SoA form, padded to a power-of-two
@@ -1729,202 +1753,212 @@ class StateMachine:
         # first (the stage is then idle for the inline writes too).
         self.store_barrier()
         n = len(events)
-        pv_code, pinfo_np, pending_recs, p_rec_idx = self._exact_prefetch(
-            events, is_pv, pv_keys
-        )
-
-        # Merge the post/void store rungs at their precedence (25-30 sit
-        # between the host ladder's early rungs and the device's late ones).
-        big = np.uint32(0xFFFFFFFF)
-        merged = np.minimum(
-            np.where(host_code == 0, big, host_code),
-            np.where(pv_code == 0, big, pv_code),
-        )
-        host_code = np.where(merged == big, np.uint32(0), merged)
-
-        # Linked-chain segments: contiguous, chain id = head index
-        # (singleton chains for unlinked events). An unterminated trailing
-        # chain fails with CHAIN_OPEN before any other rung (oracle._execute).
-        linked = (events["flags"] & np.uint16(TransferFlags.LINKED)) != 0
-        new_chain = np.ones(n, dtype=bool)
-        if n > 1:
-            new_chain[1:] = ~linked[:-1]
-        chain_id = np.maximum.accumulate(
-            np.where(new_chain, np.arange(n), 0)
-        ).astype(np.int32)
-        if linked[n - 1]:
-            host_code[n - 1] = np.uint32(int(TR.LINKED_EVENT_CHAIN_OPEN))
-
-        b, host_code_p = self._device_batch(events, ts, dr_slots, cr_slots, host_code)
-        n_pad = int(b.flags.shape[0])
-
-        def padp(a, fill):
-            out = np.full((n_pad, *a.shape[1:]), fill, dtype=a.dtype)
-            out[:n] = a
-            return out
-
-        pinfo = commit_exact.PendingInfo(
-            found=padp(pinfo_np["found"], False),
-            amount=padp(pinfo_np["amount"], 0),
-            dr_slot=padp(pinfo_np["dr_slot"], -1),
-            cr_slot=padp(pinfo_np["cr_slot"], -1),
-            timestamp=padp(types.u64_to_limbs(pinfo_np["timestamp"]), 0),
-            timeout=padp(pinfo_np["timeout"], 0),
-            base_fulfillment=padp(pinfo_np["base_fulfillment"], commit_exact.FULFILL_NONE),
-            group=padp(pinfo_np["group"], n_pad),
-        )
-        chain_id_p = np.arange(n_pad, dtype=np.int32)  # tidy: allow=retrace-shape — n_pad IS the bucket size (_device_batch's padded shape)
-        chain_id_p[:n] = chain_id
-
-        # Host-side sort plan: a ~100 µs numpy lexsort here replaces ~ms of
-        # device lax.sort inside the kernel (SortPlan docstring).
-        # tidy: allow=retrace-shape — every input is n_pad-shaped (the padded batch b / padp outputs), so the plan's shapes are bucket-stable
-        plan = commit_exact.build_sort_plan(
-            np.asarray(b.flags), np.asarray(b.dr_slot), np.asarray(b.cr_slot),
-            pinfo.dr_slot, pinfo.cr_slot, chain_id_p, pinfo.group,
-            int(self.state.ledger.shape[0]),
-        )
-        has_pv, has_chains = bool(np.any(is_pv)), bool(np.any(linked))
-        devicestats.note_call(
-            "create_transfers_exact",
-            (self.state, b, host_code_p, pinfo, chain_id_p, plan),
-            kwargs=dict(has_pv=has_pv, has_chains=has_chains),
-            bucket=n_pad,
-        )
-        t_disp = tracer.device_dispatch(
-            "create_transfers_exact",
-            h2d_bytes=_staged_nbytes(b, host_code_p)
-            + _staged_nbytes(pinfo, chain_id_p) + _staged_nbytes(plan, 0),
-        )
-        new_state, codes_dev, amounts_dev, dr_after, cr_after, bail = (
-            self._ops.create_transfers_exact(
-                self.state, b, host_code_p, pinfo, chain_id_p, plan,
-                # tidy: allow=retrace-static-arg — deliberate bounded specialization: two bools → at most 4 kernel variants, each skipping a whole sweep phase
-                has_pv=has_pv, has_chains=has_chains,
+        with tracer.span("sm.ct.prefetch"):
+            pv_code, pinfo_np, pending_recs, p_rec_idx = self._exact_prefetch(
+                events, is_pv, pv_keys
             )
-        )
-        if bool(bail):
-            # The bail sync ends the device step (same close-on-bail rule
-            # as _commit_fast_device, or dispatch/step counters diverge).
-            tracer.device_finish("create_transfers_exact", t_disp)
+
+        with tracer.span("sm.ct.stage"):
+            # Merge the post/void store rungs at their precedence (25-30 sit
+            # between the host ladder's early rungs and the device's late ones).
+            big = np.uint32(0xFFFFFFFF)
+            merged = np.minimum(
+                np.where(host_code == 0, big, host_code),
+                np.where(pv_code == 0, big, pv_code),
+            )
+            host_code = np.where(merged == big, np.uint32(0), merged)
+
+            # Linked-chain segments: contiguous, chain id = head index
+            # (singleton chains for unlinked events). An unterminated trailing
+            # chain fails with CHAIN_OPEN before any other rung (oracle._execute).
+            linked = (events["flags"] & np.uint16(TransferFlags.LINKED)) != 0
+            new_chain = np.ones(n, dtype=bool)
+            if n > 1:
+                new_chain[1:] = ~linked[:-1]
+            chain_id = np.maximum.accumulate(
+                np.where(new_chain, np.arange(n), 0)
+            ).astype(np.int32)
+            if linked[n - 1]:
+                host_code[n - 1] = np.uint32(int(TR.LINKED_EVENT_CHAIN_OPEN))
+
+            b, host_code_p = self._device_batch(events, ts, dr_slots, cr_slots, host_code)
+            n_pad = int(b.flags.shape[0])
+
+            def padp(a, fill):
+                out = np.full((n_pad, *a.shape[1:]), fill, dtype=a.dtype)
+                out[:n] = a
+                return out
+
+            pinfo = commit_exact.PendingInfo(
+                found=padp(pinfo_np["found"], False),
+                amount=padp(pinfo_np["amount"], 0),
+                dr_slot=padp(pinfo_np["dr_slot"], -1),
+                cr_slot=padp(pinfo_np["cr_slot"], -1),
+                timestamp=padp(types.u64_to_limbs(pinfo_np["timestamp"]), 0),
+                timeout=padp(pinfo_np["timeout"], 0),
+                base_fulfillment=padp(pinfo_np["base_fulfillment"], commit_exact.FULFILL_NONE),
+                group=padp(pinfo_np["group"], n_pad),
+            )
+            chain_id_p = np.arange(n_pad, dtype=np.int32)  # tidy: allow=retrace-shape — n_pad IS the bucket size (_device_batch's padded shape)
+            chain_id_p[:n] = chain_id
+
+            # Host-side sort plan: a ~100 µs numpy lexsort here replaces ~ms of
+            # device lax.sort inside the kernel (SortPlan docstring).
+            # tidy: allow=retrace-shape — every input is n_pad-shaped (the padded batch b / padp outputs), so the plan's shapes are bucket-stable
+            plan = commit_exact.build_sort_plan(
+                np.asarray(b.flags), np.asarray(b.dr_slot), np.asarray(b.cr_slot),
+                pinfo.dr_slot, pinfo.cr_slot, chain_id_p, pinfo.group,
+                int(self.state.ledger.shape[0]),
+            )
+            has_pv, has_chains = bool(np.any(is_pv)), bool(np.any(linked))
+            devicestats.note_call(
+                "create_transfers_exact",
+                (self.state, b, host_code_p, pinfo, chain_id_p, plan),
+                kwargs=dict(has_pv=has_pv, has_chains=has_chains),
+                bucket=n_pad,
+            )
+        with tracer.span("sm.ct.dispatch"):
+            t_disp = tracer.device_dispatch(
+                "create_transfers_exact",
+                h2d_bytes=_staged_nbytes(b, host_code_p)
+                + _staged_nbytes(pinfo, chain_id_p) + _staged_nbytes(plan, 0),
+            )
+            new_state, codes_dev, amounts_dev, dr_after, cr_after, bail, sweeps = (
+                self._ops.create_transfers_exact(
+                    self.state, b, host_code_p, pinfo, chain_id_p, plan,
+                    # tidy: allow=retrace-static-arg — deliberate bounded specialization: two bools → at most 4 kernel variants, each skipping a whole sweep phase
+                    has_pv=has_pv, has_chains=has_chains,
+                )
+            )
+        with tracer.span("sm.ct.sync"):
+            # The bail sync ends the device step too (same close-on-bail
+            # rule as _commit_fast_device).
+            bailed = bool(bail)
+            d2h = 0
+            if not bailed:
+                # Materialize the FULL padded arrays: sliced views would
+                # undercount the device→host volume (same rule as
+                # _read_balances).
+                codes_h = np.asarray(codes_dev)
+                amounts_h = np.asarray(amounts_dev)
+                d2h = codes_h.nbytes + amounts_h.nbytes
+                if tracer.enabled():
+                    # The while_loop's own carry, 4 bytes, at the seam the
+                    # kernel's results are taken at anyway.
+                    tracer.count("sm.exact.sweeps", int(sweeps))
+            tracer.device_finish("create_transfers_exact", t_disp, d2h_bytes=d2h)
+        if bailed:
             self._count_route("bail_batches")
             return self._create_transfers_serial(events, timestamp)
         self.state = new_state
         self._count_route("exact_batches")
-        # Materialize the FULL padded arrays first: sliced views would
-        # undercount the device→host volume (same rule as _read_balances).
-        codes_h = np.asarray(codes_dev)
-        amounts_h = np.asarray(amounts_dev)
-        tracer.device_finish(
-            "create_transfers_exact", t_disp,
-            d2h_bytes=codes_h.nbytes + amounts_h.nbytes,
-        )
-        codes = codes_h[:n]
-        amounts = amounts_h[:n]
-        amt_lo, amt_hi = types.limbs_to_u64_pair(amounts)
+        with tracer.span("sm.ct.post"):
+            codes = codes_h[:n]
+            amounts = amounts_h[:n]
+            amt_lo, amt_hi = types.limbs_to_u64_pair(amounts)
 
-        ok = codes == 0
-        if np.any(ok):
-            # Transfers are stored with their POST-CLAMP amounts
-            # (state_machine.zig:1330 stores t2.amount = clamped); post/void
-            # records derive their account/ledger/code/user_data fields from
-            # the pending (state_machine.zig:1462-1480, oracle 563-579).
-            recs = events[ok].copy()
-            recs["timestamp"] = ts[ok]
-            recs["amount_lo"] = amt_lo[ok]
-            recs["amount_hi"] = amt_hi[ok]
-            sel = is_pv[ok]
-            if np.any(sel):
-                pi = p_rec_idx[ok][sel]
-                assert np.all(pi >= 0), "ok post/void must have resolved its pending"
-                prec = pending_recs[pi]
-                for f in (
-                    "debit_account_id_lo", "debit_account_id_hi",
-                    "credit_account_id_lo", "credit_account_id_hi",
-                ):
-                    recs[f][sel] = prec[f]
-                recs["ledger"][sel] = prec["ledger"]
-                recs["code"][sel] = prec["code"]
-                recs["timeout"][sel] = 0
-                ud128_zero = (recs["user_data_128_lo"][sel] == 0) & (
-                    recs["user_data_128_hi"][sel] == 0
-                )
-                recs["user_data_128_lo"][sel] = np.where(
-                    ud128_zero, prec["user_data_128_lo"], recs["user_data_128_lo"][sel]
-                )
-                recs["user_data_128_hi"][sel] = np.where(
-                    ud128_zero, prec["user_data_128_hi"], recs["user_data_128_hi"][sel]
-                )
-                recs["user_data_64"][sel] = np.where(
-                    recs["user_data_64"][sel] == 0,
-                    prec["user_data_64"], recs["user_data_64"][sel],
-                )
-                recs["user_data_32"][sel] = np.where(
-                    recs["user_data_32"][sel] == 0,
-                    prec["user_data_32"], recs["user_data_32"][sel],
-                )
-            self._store_new_transfers(recs)
-            self.commit_timestamp = int(ts[ok][-1])
-
-            # Posted-groove updates (reference PostedGroove insert) —
-            # fully vectorized into the durable index.
-            pv_ok_ix = np.nonzero(ok & is_pv)[0]
-            if len(pv_ok_ix):
-                p_ts_ok = pending_recs["timestamp"][p_rec_idx[pv_ok_ix]]
-                posted_ok = (
-                    events["flags"][pv_ok_ix]
-                    & np.uint16(TransferFlags.POST_PENDING_TRANSFER)
-                ) != 0
-                self.posted.insert_arrays(
-                    p_ts_ok,
-                    np.where(
-                        posted_ok,
-                        np.uint32(oracle_mod.FULFILLMENT_POSTED),
-                        np.uint32(oracle_mod.FULFILLMENT_VOIDED),
-                    ),
-                )
-
-            # History rows from the kernel's post-event balances
-            # (state_machine.zig:1342-1364), in event order; post/void
-            # writes no history row (mirroring the oracle). Vectorized:
-            # limb→u64-pair conversions + key gathers, no per-row Python
-            # (VERDICT r3 weak #6 closed).
-            hist_flag = np.uint32(AccountFlags.HISTORY)
-            dr_hist = np.zeros(n, dtype=bool)
-            cr_hist = np.zeros(n, dtype=bool)
-            dr_valid = dr_slots >= 0
-            cr_valid = cr_slots >= 0
-            dr_hist[dr_valid] = (self.acc_flags[dr_slots[dr_valid]] & hist_flag) != 0
-            cr_hist[cr_valid] = (self.acc_flags[cr_slots[cr_valid]] & hist_flag) != 0
-            need = ok & (dr_hist | cr_hist) & ~is_pv
-            if np.any(need):
-                from tigerbeetle_tpu.lsm.groove import HISTORY_DTYPE
-
-                ix = np.nonzero(need)[0]
-                rows = np.zeros(len(ix), dtype=HISTORY_DTYPE)
-                rows["timestamp"] = ts[ix]
-                for side, side_hist, slots_all, after in (
-                    ("dr", dr_hist, dr_slots, dr_after),
-                    ("cr", cr_hist, cr_slots, cr_after),
-                ):
-                    m = side_hist[ix]
-                    if not m.any():
-                        continue
-                    s = slots_all[ix[m]]
-                    rows[f"{side}_account_id_lo"][m] = self.acc_key["lo"][s]
-                    rows[f"{side}_account_id_hi"][m] = self.acc_key["hi"][s]
-                    for fld, limbs in zip(
-                        ("debits_pending", "debits_posted",
-                         "credits_pending", "credits_posted"),
-                        after,
+            ok = codes == 0
+            if np.any(ok):
+                # Transfers are stored with their POST-CLAMP amounts
+                # (state_machine.zig:1330 stores t2.amount = clamped); post/void
+                # records derive their account/ledger/code/user_data fields from
+                # the pending (state_machine.zig:1462-1480, oracle 563-579).
+                recs = events[ok].copy()
+                recs["timestamp"] = ts[ok]
+                recs["amount_lo"] = amt_lo[ok]
+                recs["amount_hi"] = amt_hi[ok]
+                sel = is_pv[ok]
+                if np.any(sel):
+                    pi = p_rec_idx[ok][sel]
+                    assert np.all(pi >= 0), "ok post/void must have resolved its pending"
+                    prec = pending_recs[pi]
+                    for f in (
+                        "debit_account_id_lo", "debit_account_id_hi",
+                        "credit_account_id_lo", "credit_account_id_hi",
                     ):
-                        lo_c, hi_c = types.limbs_to_u64_pair(
-                            np.asarray(limbs)[:n][ix[m]]
-                        )
-                        rows[f"{side}_{fld}_lo"][m] = lo_c
-                        rows[f"{side}_{fld}_hi"][m] = hi_c
-                self.history.append_batch(rows)
-        return _codes_to_results(codes)
+                        recs[f][sel] = prec[f]
+                    recs["ledger"][sel] = prec["ledger"]
+                    recs["code"][sel] = prec["code"]
+                    recs["timeout"][sel] = 0
+                    ud128_zero = (recs["user_data_128_lo"][sel] == 0) & (
+                        recs["user_data_128_hi"][sel] == 0
+                    )
+                    recs["user_data_128_lo"][sel] = np.where(
+                        ud128_zero, prec["user_data_128_lo"], recs["user_data_128_lo"][sel]
+                    )
+                    recs["user_data_128_hi"][sel] = np.where(
+                        ud128_zero, prec["user_data_128_hi"], recs["user_data_128_hi"][sel]
+                    )
+                    recs["user_data_64"][sel] = np.where(
+                        recs["user_data_64"][sel] == 0,
+                        prec["user_data_64"], recs["user_data_64"][sel],
+                    )
+                    recs["user_data_32"][sel] = np.where(
+                        recs["user_data_32"][sel] == 0,
+                        prec["user_data_32"], recs["user_data_32"][sel],
+                    )
+                self._store_new_transfers(recs)
+                self.commit_timestamp = int(ts[ok][-1])
+
+                # Posted-groove updates (reference PostedGroove insert) —
+                # fully vectorized into the durable index.
+                pv_ok_ix = np.nonzero(ok & is_pv)[0]
+                if len(pv_ok_ix):
+                    p_ts_ok = pending_recs["timestamp"][p_rec_idx[pv_ok_ix]]
+                    posted_ok = (
+                        events["flags"][pv_ok_ix]
+                        & np.uint16(TransferFlags.POST_PENDING_TRANSFER)
+                    ) != 0
+                    self.posted.insert_arrays(
+                        p_ts_ok,
+                        np.where(
+                            posted_ok,
+                            np.uint32(oracle_mod.FULFILLMENT_POSTED),
+                            np.uint32(oracle_mod.FULFILLMENT_VOIDED),
+                        ),
+                    )
+
+                # History rows from the kernel's post-event balances
+                # (state_machine.zig:1342-1364), in event order; post/void
+                # writes no history row (mirroring the oracle). Vectorized:
+                # limb→u64-pair conversions + key gathers, no per-row Python
+                # (VERDICT r3 weak #6 closed).
+                hist_flag = np.uint32(AccountFlags.HISTORY)
+                dr_hist = np.zeros(n, dtype=bool)
+                cr_hist = np.zeros(n, dtype=bool)
+                dr_valid = dr_slots >= 0
+                cr_valid = cr_slots >= 0
+                dr_hist[dr_valid] = (self.acc_flags[dr_slots[dr_valid]] & hist_flag) != 0
+                cr_hist[cr_valid] = (self.acc_flags[cr_slots[cr_valid]] & hist_flag) != 0
+                need = ok & (dr_hist | cr_hist) & ~is_pv
+                if np.any(need):
+                    from tigerbeetle_tpu.lsm.groove import HISTORY_DTYPE
+
+                    ix = np.nonzero(need)[0]
+                    rows = np.zeros(len(ix), dtype=HISTORY_DTYPE)
+                    rows["timestamp"] = ts[ix]
+                    for side, side_hist, slots_all, after in (
+                        ("dr", dr_hist, dr_slots, dr_after),
+                        ("cr", cr_hist, cr_slots, cr_after),
+                    ):
+                        m = side_hist[ix]
+                        if not m.any():
+                            continue
+                        s = slots_all[ix[m]]
+                        rows[f"{side}_account_id_lo"][m] = self.acc_key["lo"][s]
+                        rows[f"{side}_account_id_hi"][m] = self.acc_key["hi"][s]
+                        for fld, limbs in zip(
+                            ("debits_pending", "debits_posted",
+                             "credits_pending", "credits_posted"),
+                            after,
+                        ):
+                            lo_c, hi_c = types.limbs_to_u64_pair(
+                                np.asarray(limbs)[:n][ix[m]]
+                            )
+                            rows[f"{side}_{fld}_lo"][m] = lo_c
+                            rows[f"{side}_{fld}_hi"][m] = hi_c
+                    self.history.append_batch(rows)
+            return _codes_to_results(codes)
 
     def _create_transfers_numpy_fast(
         self, events, ts, keys, dr_slots, cr_slots, host_code
@@ -2077,6 +2111,10 @@ class StateMachine:
         # The oracle reads (and its writeback writes) the whole store
         # tier: the async stage must be idle.
         self.store_barrier()
+        with tracer.span("sm.ct.serial"):
+            return self._create_transfers_oracle(events, timestamp)
+
+    def _create_transfers_oracle(self, events: np.ndarray, timestamp: int) -> np.ndarray:
         orc = self._make_oracle()
         # Prefetch round 1: dr/cr accounts, existing transfers by event id
         # and by pending_id (reference prefetch, state_machine.zig:560-655).

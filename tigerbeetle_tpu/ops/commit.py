@@ -200,18 +200,22 @@ def create_transfers_fast_impl(state: LedgerState, b: TransferBatch, host_code: 
     Returns (new_state, codes, bail) — bail True means a u128 overflow was
     possible and the host must redo the batch serially (never in practice).
     """
-    code, unsupported = validate_simple(state, b)
-    code = merge_codes(code, host_code)
+    # The named scopes are metadata only: they name the kernel's phases in
+    # a profiler trace (docs/OBSERVABILITY.md) and change no operation.
+    with jax.named_scope("validate"):
+        code, unsupported = validate_simple(state, b)
+        code = merge_codes(code, host_code)
 
     ok = (code == 0) & ~unsupported
     pend = (b.flags & F_PENDING) != 0
 
-    new_state, overflow = apply_posting_streamed(
-        state, b.dr_slot, b.cr_slot, b.amount,
-        dr_pend=ok & pend, dr_post=ok & ~pend,
-        cr_pend=ok & pend, cr_post=ok & ~pend,
-    )
-    bail = overflow | jnp.any(unsupported)
+    with jax.named_scope("post"):
+        new_state, overflow = apply_posting_streamed(
+            state, b.dr_slot, b.cr_slot, b.amount,
+            dr_pend=ok & pend, dr_post=ok & ~pend,
+            cr_pend=ok & pend, cr_post=ok & ~pend,
+        )
+        bail = overflow | jnp.any(unsupported)
     return new_state, code, bail
 
 

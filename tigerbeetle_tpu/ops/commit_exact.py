@@ -415,9 +415,10 @@ def create_transfers_exact_impl(
 
     Returns (new_state, codes (n,), amounts (n,4) — post-clamp/resolved,
     dr_after, cr_after (Observed — post-event balances for history rows),
-    bail). `bail` is True when the batch did not stabilize within
+    bail, sweeps). `bail` is True when the batch did not stabilize within
     max_sweeps or a posting overflow/underflow fired — the host must redo
-    the batch serially.
+    the batch serially. `sweeps` (i32 scalar) is the fixed-point loop's own
+    count of passes, for the tracer's `sm.exact.sweeps`.
     """
     n = b.flags.shape[0]
     a_count = state.ledger.shape[0]
@@ -435,12 +436,16 @@ def create_transfers_exact_impl(
     # (state_machine.zig:1442; exact only when p is found).
     resolved_pv = u128.select(u128.is_zero(b.amount), pending.amount, b.amount)
 
-    ts_expired = _pending_expired(b, pending)
-    reg_code = merge_codes(_static_ladder(state, b, is_pv), host_code)
-    pv_code_pre_expiry = merge_codes(
-        _pv_static_ladder(b, pending, is_pv, resolved_pv), host_code
-    )
-    ts_over = _timeout_overflows(b)
+    # The named scopes here and below (validate, sweep with observe and
+    # balance_check inside it, chain_rollback, post) are metadata only:
+    # they name the kernel's phases in a profiler trace.
+    with jax.named_scope("validate"):
+        ts_expired = _pending_expired(b, pending)
+        reg_code = merge_codes(_static_ladder(state, b, is_pv), host_code)
+        pv_code_pre_expiry = merge_codes(
+            _pv_static_ladder(b, pending, is_pv, resolved_pv), host_code
+        )
+        ts_over = _timeout_overflows(b)
 
     dr_ix = jnp.clip(b.dr_slot, 0, a_max)
     cr_ix = jnp.clip(b.cr_slot, 0, a_max)
@@ -757,15 +762,17 @@ def create_transfers_exact_impl(
     false_n = jnp.zeros((n,), dtype=bool)
 
     def step(ok, amount):
-        chain_ok_ev = chain_all_ok(ok)
-        obs, under = observe(ok, chain_ok_ev, amount)
-        if has_pv:
-            ep, ev = fulfillment_prefix(ok, chain_ok_ev)
-        else:
-            # Statically no post/void events: the in-batch fulfillment
-            # prefix is identically false — skip its cumsum pass.
-            ep, ev = false_n, false_n
-        code, amt = evaluate(obs, ep, ev)
+        with jax.named_scope("observe"):
+            chain_ok_ev = chain_all_ok(ok)
+            obs, under = observe(ok, chain_ok_ev, amount)
+            if has_pv:
+                ep, ev = fulfillment_prefix(ok, chain_ok_ev)
+            else:
+                # Statically no post/void events: the in-batch fulfillment
+                # prefix is identically false — skip its cumsum pass.
+                ep, ev = false_n, false_n
+        with jax.named_scope("balance_check"):
+            code, amt = evaluate(obs, ep, ev)
         return code, amt, under, chain_ok_ev, obs
 
     def sweep(carry):
@@ -787,16 +794,18 @@ def create_transfers_exact_impl(
     # from the old "everything passes unclamped" seed). The fixed point is
     # unique (triangular chain dependency), so the seed cannot change the
     # result — only the iteration count.
-    seed_code, seed_amt = evaluate(base, false_n, false_n)
+    with jax.named_scope("balance_check"):
+        seed_code, seed_amt = evaluate(base, false_n, false_n)
     init_ok = seed_code == 0
     zero_obs = Observed(*([jnp.zeros((2 * n, 4), dtype=U32)] * 4))
     init = (
         init_ok, masked(init_ok, seed_amt), jnp.int32(0), jnp.array(False),
         seed_code, zero_obs, jnp.array(False),
     )
-    ok, amount, sweeps, stable, codes, obs, under_final = jax.lax.while_loop(
-        lambda c: (~c[3]) & (c[2] < max_sweeps), sweep, init
-    )
+    with jax.named_scope("sweep"):
+        ok, amount, sweeps, stable, codes, obs, under_final = jax.lax.while_loop(
+            lambda c: (~c[3]) & (c[2] < max_sweeps), sweep, init
+        )
 
     # At the fixed point the carried codes/amount are the consistent final
     # evaluation (the loop body's step already re-evaluated them).
@@ -813,22 +822,24 @@ def create_transfers_exact_impl(
     # Singleton-only batches (has_chains=False) skip this: every failing
     # event is its own chain's first failure, so codes are unchanged.
     if has_chains:
-        excl_f, incl_f = fail_prefix(ok)
-        chain_fails = (incl_f[e_tail] - excl_f[chain_id]) > 0
-        first_fail_here = (~ok) & (excl_f == excl_f[chain_id])
-        keep = first_fail_here | (
-            codes == jnp.uint32(int(TR.LINKED_EVENT_CHAIN_OPEN))
-        )
-        codes = jnp.where(
-            chain_fails & ~keep, jnp.uint32(int(TR.LINKED_EVENT_FAILED)), codes
-        )
+        with jax.named_scope("chain_rollback"):
+            excl_f, incl_f = fail_prefix(ok)
+            chain_fails = (incl_f[e_tail] - excl_f[chain_id]) > 0
+            first_fail_here = (~ok) & (excl_f == excl_f[chain_id])
+            keep = first_fail_here | (
+                codes == jnp.uint32(int(TR.LINKED_EVENT_CHAIN_OPEN))
+            )
+            codes = jnp.where(
+                chain_fails & ~keep, jnp.uint32(int(TR.LINKED_EVENT_FAILED)), codes
+            )
     ok = codes == 0
     amounts = masked(ok, amounts)
 
-    new_state, overflow = _apply(
-        state, b, pending, is_pv, is_post, pend, ok, amounts,
-        balance_apply=balance_apply,
-    )
+    with jax.named_scope("post"):
+        new_state, overflow = _apply(
+            state, b, pending, is_pv, is_post, pend, ok, amounts,
+            balance_apply=balance_apply,
+        )
 
     # Post-event balances (observed + own delta) for history rows
     # (state_machine.zig:1342-1364 — regular events only; post/void writes
@@ -851,7 +862,7 @@ def create_transfers_exact_impl(
     )
 
     bail = (~stable) | overflow | under_final
-    return new_state, codes, amounts, dr_after, cr_after, bail
+    return new_state, codes, amounts, dr_after, cr_after, bail, sweeps
 
 
 def _apply(state, b, pending, is_pv, is_post, pend, ok, amounts, balance_apply=None):

@@ -276,8 +276,14 @@ def merge_device(keys_a, vals_a, keys_b, vals_b):
     ka, pa = to_device_run(keys_a, vals_a)
     kb, pb = to_device_run(keys_b, vals_b)
     devicestats.note_call("merge_kernel_tiled", (ka, pa, kb, pb))
-    ok, op = merge_kernel_tiled(ka, pa, kb, pb)
-    return from_device_run(ok, op, n + m)
+    with tracer.device_step("merge_kernel_tiled"):
+        ok, op = merge_kernel_tiled(ka, pa, kb, pb)
+        out = from_device_run(ok, op, n + m)
+    tracer.device_bytes(
+        h2d=ka.nbytes + pa.nbytes + kb.nbytes + pb.nbytes,
+        d2h=ok.nbytes + op.nbytes,
+    )
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=())
@@ -297,10 +303,12 @@ def compact_fold_kernel(keys_stack, pays_stack):
     pays = [pays_stack[i] for i in range(k)]
     while len(keys) > 1:
         nk, npay = [], []
-        for i in range(0, len(keys), 2):
-            ok, op = merge_kernel_tiled(keys[i], pays[i], keys[i + 1], pays[i + 1])
-            nk.append(ok)
-            npay.append(op)
+        # Metadata only: names each level of the fold in a profiler trace.
+        with jax.named_scope(f"fold_{len(keys)}_runs"):
+            for i in range(0, len(keys), 2):
+                ok, op = merge_kernel_tiled(keys[i], pays[i], keys[i + 1], pays[i + 1])
+                nk.append(ok)
+                npay.append(op)
         keys, pays = nk, npay
     return keys[0], pays[0]
 

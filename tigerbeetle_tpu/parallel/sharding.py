@@ -271,7 +271,7 @@ def make_sharded_commit_exact(mesh: Mesh, accounts_max: int, with_plan: bool = F
         mesh=mesh,
         # Batch inputs replicated: the sweep is batch-global (see above).
         in_specs=tuple(in_specs),
-        out_specs=(state_specs(), P(), P(), obs_spec, obs_spec, P()),
+        out_specs=(state_specs(), P(), P(), obs_spec, obs_spec, P(), P()),
         check_vma=False,
     )
     return jax.jit(sm)

@@ -49,6 +49,15 @@ loop and their stall/idle time decides throughput:
     dispatch-window accounting (flight dumps include it), and the
     Perfetto async device lane built from closed dispatch→finish pairs.
 
+  - One clock with the device trace: the stage-granularity spans that
+    tile the commit and store threads (ANNOTATED_SPANS) also enter a
+    `jax.profiler.TraceAnnotation`, so a running `jax.profiler` session
+    writes them onto the host plane of the same `*.xplane.pb` as the
+    device planes; backend compiles land as span `device.compile` on the
+    thread that compiled (`jax.monitoring`); and the time with no
+    dispatch window open is span `device.unfed` — a whole-run lower
+    bound on the device's idle time.
+
 Thread model: every recording path (span/count/observe) writes only
 thread-local state created lazily per thread and registered for merge;
 `snapshot()`/`trace_events()` read across threads without stopping
@@ -138,7 +147,43 @@ _device_mem_hw = [0]  # tidy: guarded-by=_registry_lock
 _device_inflight: Dict[str, Dict[int, int]] = {}  # tidy: guarded-by=_registry_lock
 _DEVICE_INFLIGHT_MAX = 64  # per entry; beyond = abandoned tokens
 _device_pairs: deque = deque(maxlen=4096)  # tidy: guarded-by=_registry_lock
+# [open dispatch windows across all entries, perf_counter_ns at which the
+# count last fell to 0 (0: no window has closed yet)]. The stretch from
+# that instant to the next dispatch is `device.unfed`: the host had handed
+# the device nothing whose result it had not already taken back.
+_device_open = [0, 0]  # tidy: guarded-by=_registry_lock
 _tls = threading.local()
+
+# The tiling of the two worker threads (docs/OBSERVABILITY.md, "Thread
+# tiling"): on its thread every LEAF is disjoint from the others, so leaf
+# seconds may be added up, and busy seconds are elapsed minus the WAITS.
+COMMIT_LEAVES = (
+    "sm.ct.stage", "sm.ct.prefetch", "sm.ct.dispatch", "sm.ct.sync",
+    "sm.ct.post", "sm.ct.serial", "replica.execute.tail", "stage.reply",
+    "stage.complete",
+)
+COMMIT_WAITS = ("pipeline.commit.idle", "pipeline.store.stall", "sm.store.barrier")
+STORE_LEAVES = (
+    "sm.store.log", "sm.store.idx", "sm.store.rows", "sm.store.query",
+    "sm.beat", "pipeline.store.prefetch",
+)
+STORE_WAITS = ("pipeline.store.idle",)
+TILING_PARENTS = ("replica.execute", "stage.store_async")
+# Spans that also enter a `jax.profiler.TraceAnnotation` (attach_jax): the
+# tiling, the two parents around it, and below stage granularity the
+# compaction merges (where the device's time goes) and the WAL write. A
+# fixed list and not every span: the store thread's `lsm.*` spans run
+# thousands a second.
+ANNOTATED_SPANS = frozenset(
+    COMMIT_LEAVES + COMMIT_WAITS + STORE_LEAVES + STORE_WAITS + TILING_PARENTS
+    + ("lsm.compact.merge", "wal.write")
+)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+# jax.profiler.TraceAnnotation once JAX is in the process and the tracer
+# is on (attach_jax); None keeps every span a plain span.
+_annotation = None  # tidy: atomic — set once, by attach_jax
+_compile_listener_on = False  # tidy: guarded-by=_registry_lock
 
 
 class _ThreadState:
@@ -205,15 +250,20 @@ def _state() -> _ThreadState:
 class _Span:
     """Reusable timed-region context manager (pooled per thread)."""
 
-    __slots__ = ("state", "event", "t0")
+    __slots__ = ("state", "event", "t0", "annotation")
 
     def __enter__(self) -> "_Span":
+        if self.annotation is not None:
+            self.annotation.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         state = self.state
         state.record(self.event, self.t0, time.perf_counter_ns())
+        if self.annotation is not None:
+            self.annotation.__exit__(exc_type, exc, tb)
+            self.annotation = None
         if len(state.pool) < 64:
             state.pool.append(self)
         return False
@@ -246,6 +296,42 @@ def null_span() -> _NullSpan:
 def enable() -> None:
     global _enabled
     _enabled = True
+    if "jax" in sys.modules:
+        attach_jax()
+
+
+def attach_jax() -> None:
+    """JAX is in this process (the state machine's jax backend calls this
+    before its first device call; `enable()` calls it when JAX was there
+    first): from here on the ANNOTATED_SPANS enter a profiler annotation
+    and backend compiles are recorded on the thread that compiled. Nothing
+    happens with the tracer off, and the numpy backend never comes here,
+    so it never imports JAX on the tracer's account."""
+    global _annotation, _compile_listener_on
+    if not _enabled:
+        return
+    import jax.monitoring
+    import jax.profiler
+
+    _annotation = jax.profiler.TraceAnnotation
+    with _registry_lock:
+        register = not _compile_listener_on
+        _compile_listener_on = True
+    if register:
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def _on_jax_duration(event: str, seconds: float, **_kw) -> None:
+    """`jax.monitoring` calls this on the compiling thread. The backend-
+    compile event fires for a compile and for a read from the persistent
+    cache alike (either is a shape the process had not seen); a cache
+    read announces itself first, by its own event."""
+    if not _enabled:
+        return
+    if event == COMPILE_EVENT:
+        observe("device.compile", int(seconds * 1e9))
+    elif event == CACHE_READ_EVENT:
+        count("device.compile.cache_reads")
 
 
 def disable() -> None:
@@ -281,6 +367,7 @@ def reset() -> None:
         _device_mem_hw[0] = 0
         _device_inflight.clear()
         _device_pairs.clear()
+        _device_open[0] = _device_open[1] = 0
 
 
 def configure(ring_size: Optional[int] = None) -> None:
@@ -309,6 +396,10 @@ def span(event: str):
     s = pool.pop() if pool else _Span()
     s.state = st
     s.event = event
+    s.annotation = (
+        _annotation(event)
+        if _annotation is not None and event in ANNOTATED_SPANS else None
+    )
     return s
 
 
@@ -1002,7 +1093,7 @@ def lifecycle_summary() -> dict:
     prepares resident per stage), and flight-recorder status. `flat`
     holds the benchmark-facing key set (queue_wait_*/service_*/
     occupancy_*) that bench.py records and tools/bench_gate.py gates."""
-    agg, hists, _counters = _merged()
+    agg, hists, counters = _merged()
     with _registry_lock:
         first, last, _n = _op_window
         flight = {
@@ -1094,21 +1185,28 @@ def lifecycle_summary() -> dict:
         flat[f"{key}_p50_ms"] = s["p50_ms"]
         flat[f"{key}_p99_ms"] = s["p99_ms"]
     # Cross-batch commit-window occupancy (vsr/replica.py
-    # _stage_note_inflight): one raw-depth sample per processed batch —
-    # mean in-flight dispatched batches, the high-water, and the p99 of
-    # the per-depth histogram. commit_depth is the CONFIGURED window
+    # _stage_note_inflight): one `pipeline.commit.inflight.d<depth>` count
+    # per processed batch — mean in-flight dispatched batches, the
+    # high-water, and the p99, exact. commit_depth is the CONFIGURED window
     # (pipeline.commit.depth_config gauge) so A/Bs across hosts can see
     # which depth the adaptive default actually selected.
-    inflight = agg.get("pipeline.commit.inflight_depth")
-    if inflight is not None and inflight[0]:
-        n_if, total_if, max_if = inflight
-        flat["commit_inflight_mean"] = round(total_if / n_if, 3)
-        flat["commit_inflight_max"] = int(max_if)
-        h_if = hists.get("pipeline.commit.inflight_depth")
-        if h_if:
-            flat["commit_inflight_p99"] = float(
-                _hist_percentile(h_if, sum(h_if), 0.99)
-            )
+    prefix = "pipeline.commit.inflight.d"
+    depths = sorted(
+        (int(name[len(prefix):]), n) for name, n in counters.items()
+        if name.startswith(prefix) and n > 0
+    )
+    if depths:
+        batches = sum(n for _d, n in depths)
+        flat["commit_inflight_mean"] = round(
+            sum(d * n for d, n in depths) / batches, 3
+        )
+        flat["commit_inflight_max"] = depths[-1][0]
+        rank, seen = 0.99 * (batches - 1), 0
+        for d, n in depths:
+            seen += n
+            if seen > rank:
+                flat["commit_inflight_p99"] = float(d)
+                break
     with _registry_lock:
         depth_cfg = _gauges.get("pipeline.commit.depth_config")
         device_hw = _device_mem_hw[0]
@@ -1173,13 +1271,57 @@ def _device_entry_check(entry: str) -> None:
         )
 
 
+def _device_window_open_locked() -> int:  # tidy: holds=_registry_lock
+    """One more dispatch window is open. Returns when the last one closed
+    if none was open till now (the caller records `device.unfed` from then
+    to now, outside the lock), else 0."""
+    idle_since = _device_open[1] if _device_open[0] == 0 else 0
+    _device_open[0] += 1
+    return idle_since
+
+
+def _record_unfed(idle_since: int, now: int) -> None:
+    # Another thread's window may have closed after `now` was read.
+    if idle_since and now > idle_since:
+        _state().record("device.unfed", idle_since, now)
+
+
+def _device_window_close_locked(now: int) -> None:  # tidy: holds=_registry_lock
+    _device_open[0] -= 1
+    if _device_open[0] <= 0:
+        _device_open[0] = 0
+        _device_open[1] = now
+
+
+class _DeviceStep:
+    """A blocking jit entry's span, which is also one dispatch window."""
+
+    __slots__ = ("span",)
+
+    def __init__(self, span: "_Span") -> None:
+        self.span = span
+
+    def __enter__(self) -> "_DeviceStep":
+        self.span.__enter__()
+        with _registry_lock:
+            idle_since = _device_window_open_locked()
+        _record_unfed(idle_since, self.span.t0)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.span.__exit__(exc_type, exc, tb)
+        with _registry_lock:
+            _device_window_close_locked(time.perf_counter_ns())
+        return False
+
+
 def device_step(entry: str):
     """Span over a BLOCKING jit entry (call + materialization):
     `device.<entry>` — wall time the host spends inside the kernel."""
     if not _enabled:
         return _NULL_SPAN
     _device_entry_check(entry)
-    return span(f"device.{entry}")
+    return _DeviceStep(span(f"device.{entry}"))
 
 
 def device_dispatch(entry: str, h2d_bytes: int = 0) -> int:
@@ -1187,7 +1329,8 @@ def device_dispatch(entry: str, h2d_bytes: int = 0) -> int:
     token for device_finish (0 when disabled). Counts the host→device
     bytes staged for the call and opens an in-flight window (the staged
     bytes ride the token so the finish seam can attribute h2d bandwidth
-    over the same dispatch→finish interval)."""
+    over the same dispatch→finish interval). A window that opens with no
+    other open, on any entry, ends a stretch of `device.unfed`."""
     if not _enabled:
         return 0
     _device_entry_check(entry)
@@ -1197,11 +1340,17 @@ def device_dispatch(entry: str, h2d_bytes: int = 0) -> int:
     token = time.perf_counter_ns()
     with _registry_lock:
         toks = _device_inflight.setdefault(entry, {})
+        while token in toks:
+            token += 1  # two dispatches in one clock tick are two windows
         toks[token] = h2d_bytes
         while len(toks) > _DEVICE_INFLIGHT_MAX:
             # Abandoned dispatches (e.g. a bail-path abandon_all that
-            # never reaches a finish seam) must not grow the map.
+            # never reaches a finish seam) must not grow the map, nor
+            # hold the device "fed" for ever.
             del toks[next(iter(toks))]
+            _device_window_close_locked(token)
+        idle_since = _device_window_open_locked()
+    _record_unfed(idle_since, token)
     return token
 
 
@@ -1224,7 +1373,11 @@ def device_finish(entry: str, token: int, d2h_bytes: int = 0) -> None:
         count("device.d2h_bytes", d2h_bytes)
     with _registry_lock:
         toks = _device_inflight.get(entry)
-        h2d_bytes = toks.pop(token, 0) if toks else 0
+        h2d_bytes = toks.pop(token, None) if toks else None
+        if h2d_bytes is None:
+            h2d_bytes = 0  # evicted as abandoned: its window is closed already
+        else:
+            _device_window_close_locked(now)
         _device_pairs.append((entry, token, now, h2d_bytes, d2h_bytes))
     if dur > 0:
         if h2d_bytes:
@@ -1331,6 +1484,21 @@ def snapshot() -> Dict[str, dict]:
             }
         else:
             rec["count"] += counters[event]
+    return out
+
+
+def by_thread() -> Dict[str, Dict[str, Tuple[int, int]]]:
+    """thread name → {event: (count, total_ns)}: the spans as each thread
+    recorded them, unmerged. What says WHICH thread compiled, waited or
+    staged; threads that share a name are added up."""
+    with _registry_lock:
+        states = list(_states)
+    out: Dict[str, Dict[str, Tuple[int, int]]] = {}
+    for st in states:
+        mine = out.setdefault(st.name, {})
+        for event, (n, total, _mx) in list(st.agg.items()):
+            seen = mine.get(event, (0, 0))
+            mine[event] = (seen[0] + n, seen[1] + total)
     return out
 
 

@@ -43,7 +43,6 @@ instead of wedging with a silently dead stage.
 from __future__ import annotations
 
 import threading
-import time
 from collections import deque
 from typing import Callable, List, Optional, Tuple
 
@@ -57,13 +56,11 @@ RUN_MAX = 8
 def _timed_wait(cond: threading.Condition, event: str) -> None:
     """One condition wait, recorded as stage idle/stall time when tracing
     is on (the per-stage stall/idle registry rows — the quantity that
-    decides whether a stage overlaps usefully or just time-slices)."""
-    if not tracer.enabled():
+    decides whether a stage overlaps usefully or just time-slices). A
+    span, so that a profiler trace names the wait too (the three events
+    are on the tracer's annotation list)."""
+    with tracer.span(event):
         cond.wait()
-        return
-    t0 = time.perf_counter_ns()  # tidy: allow=wall-clock — tracing only, never reaches state
-    cond.wait()
-    tracer.observe(event, time.perf_counter_ns() - t0)  # tidy: allow=wall-clock — tracing only, never reaches state
 
 
 class CommitExecutor:

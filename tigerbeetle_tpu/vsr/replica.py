@@ -1836,13 +1836,12 @@ class Replica:
         """Occupancy sample, once per processed batch: how many batches
         are in flight through the commit window at its dispatch (1 on the
         serial/full path — the batch itself). Gauge for live scrapes,
-        histogram (raw depth units) for the per-depth distribution and
-        the benchmark's commit_inflight_mean."""
+        one counter per depth for the distribution: commit_inflight_mean
+        (the lifecycle summary's and the benchmark's) is their mean."""
         if depth > self.stage_inflight_max:
             self.stage_inflight_max = depth
         if tracer.enabled():
             tracer.gauge("pipeline.commit.inflight", depth)
-            tracer.observe("pipeline.commit.inflight_depth", depth)
             # Exact per-depth histogram (bounded: depth ≤ pipeline_max).
             tracer.count(f"pipeline.commit.inflight.d{depth}")
             # Re-asserted per batch so the configured depth survives a
@@ -1894,8 +1893,9 @@ class Replica:
         tracer.count("vsr.commits")
         with tracer.span("replica.execute"):
             results = sm.create_transfers_finish(job.pop("_handle")).tobytes()
-            sm.prepare_timestamp = max(sm.prepare_timestamp, int(h["timestamp"]))
-            job["spec"] = self._execute_tail(msg, results, build_reply=False)
+            with tracer.span("replica.execute.tail"):
+                sm.prepare_timestamp = max(sm.prepare_timestamp, int(h["timestamp"]))
+                job["spec"] = self._execute_tail(msg, results, build_reply=False)
 
     def _stage_settle(self, job: dict, run_exec) -> tuple:
         """Execute one op and publish its completion EARLY — the reply is
@@ -1916,7 +1916,7 @@ class Replica:
         tracer.op_stamp(lc, tracer.OP_EXEC_END)
         self._stage_emit(job)
         if not boundary:
-            self.executor.complete(job)
+            self._stage_publish(job)
         try:
             self._finish_commit(lc)
         except GridReadFault as fault:
@@ -1926,8 +1926,16 @@ class Replica:
             # Completion already out: publish a finish-fault marker.
             return {"op": job["op"], "finish_fault": fault, "lc": lc}, False
         if boundary:
-            self.executor.complete(job)
+            self._stage_publish(job)
         return None, True
+
+    def _stage_publish(self, job: dict) -> None:
+        """Hand the completion to the event loop. The wake-up writes to the
+        loop's socket, which lets go of the interpreter lock: with the
+        store thread busy, taking it back is milliseconds of this
+        thread's time, and they belong to a span like any other."""
+        with tracer.span("stage.complete"):
+            self.executor.complete(job)
 
     def _stage_emit(self, job: dict) -> None:
         """Build the op's reply through the preallocated scratch builder
@@ -3150,9 +3158,10 @@ class Replica:
         tracer.count("vsr.commits")
         with tracer.span("replica.execute"):
             results = self._execute_op(prepare)
-            out = self._execute_tail(
-                prepare, results, replay=replay, build_reply=build_reply
-            )
+            with tracer.span("replica.execute.tail"):
+                out = self._execute_tail(
+                    prepare, results, replay=replay, build_reply=build_reply
+                )
         if replay:
             # Replay has no reply to race ahead of: finish the op's apply
             # sequence inline (live commit paths call _finish_commit after
