@@ -785,8 +785,7 @@ def check_routes(text: str, traffic: Traffic) -> None:
             "device.step.create_transfers_fast",
             "device.step.create_transfers_exact",
             "device.step.query_index_keys_sorted",
-            "device.step.merge_kernel_tiled",
-            "device.step.compact_fold_kernel", UNREACHED,
+            "device.step.merge_kernel_tiled", UNREACHED,
         )
     }
     routes = commit_paths(text)
@@ -800,11 +799,7 @@ def check_routes(text: str, traffic: Traffic) -> None:
         "this entry (query_transfers probes; only ScanBuilder.execute("
         "strategy='materialize') intersects, and only bench.py and the "
         "tests call that)")
-    merges = spans.pop("device.step.merge_kernel_tiled") + spans.pop(
-        "device.step.compact_fold_kernel")
     missing = [e for e, n in spans.items() if n == 0]
-    if merges == 0:
-        missing.append("device.step.merge_kernel_tiled or compact_fold_kernel")
     if missing:
         raise Failure(f"device routes not taken: {missing}")
     sent = {r: sum(1 for _n, route, _t in traffic.special if route == r)

@@ -393,10 +393,8 @@ def main(backend="numpy", batches=40, overlap=True, store_async=True,
 
     # Streaming-compaction decomposition (docs/COMMIT_PIPELINE.md
     # "Streaming compaction"): the merge/bloom/build sub-spans NEST
-    # inside the beat row (sm.beat → compact_step), and compact.device
-    # (the split-phase fold's dispatch→materialize latency) OVERLAPS the
-    # host-side build between its two halves — so this is its own table,
-    # never added to the disjoint stage attribution above. compact.beat
+    # inside the beat row (sm.beat → compact_step) — so this is its own
+    # table, never added to the disjoint stage attribution above. compact.beat
     # repeats the beat row as the table's enclosing total; forward is
     # the fault-retry fast-forward replay (zero in a healthy run).
     compact_rows = {
@@ -405,12 +403,10 @@ def main(backend="numpy", batches=40, overlap=True, store_async=True,
         "compact.merge": ("lsm.compact.merge",),
         "compact.bloom": ("lsm.compact.bloom",),
         "compact.build": ("lsm.compact.build",),
-        "compact.device": ("device.step.compact_fold_kernel",),
     }
     if any(span_ms(keys) for keys in compact_rows.values()
            if keys != ("sm.beat",)):
-        print("\nstreaming compaction (nested inside the beat row; device "
-              "half overlaps host build):")
+        print("\nstreaming compaction (nested inside the beat row):")
         print(f"  {'span':16s} {'ms/batch':>9s} {'p50_us':>9s} {'p99_us':>9s}")
         for stage, keys in compact_rows.items():
             ms = span_ms(keys)

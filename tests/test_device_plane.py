@@ -102,19 +102,19 @@ def _transfer_batch(ids, amount=5):
 class TestDeviceMemLedger:
     def test_set_adjust_release_and_high_water(self, clean_tracer):
         tracer.device_mem_set("balances", 1000)
-        tracer.device_mem_adjust("compact_fold", 500)
+        tracer.device_mem_adjust("query_runs", 500)
         t = tracer.device_mem_totals()
-        assert t["owners"] == {"balances": 1000, "compact_fold": 500}
+        assert t["owners"] == {"balances": 1000, "query_runs": 500}
         assert t["total_bytes"] == 1500 and t["high_water_bytes"] == 1500
         # Release drops the owner AND its gauge; high-water persists.
-        tracer.device_mem_adjust("compact_fold", -500)
-        tracer.device_mem_release("compact_fold")
+        tracer.device_mem_adjust("query_runs", -500)
+        tracer.device_mem_release("query_runs")
         t = tracer.device_mem_totals()
-        assert "compact_fold" not in t["owners"]
+        assert "query_runs" not in t["owners"]
         assert t["total_bytes"] == 1000 and t["high_water_bytes"] == 1500
         g = tracer.gauges()
         assert g["device.mem.balances.bytes"] == 1000.0
-        assert "device.mem.compact_fold.bytes" not in g
+        assert "device.mem.query_runs.bytes" not in g
 
     def test_adjust_clamps_at_zero(self, clean_tracer):
         tracer.device_mem_adjust("query_runs", 100)

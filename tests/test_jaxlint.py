@@ -125,31 +125,29 @@ def test_retrace_fixture_exact_findings():
     assert "np.zeros" in src[named.line - 1]
 
 
-def test_compact_fold_entry_is_compile_gated():
-    """The streaming-compaction device fold is a registered jit entry:
-    runtime-shaped chunk stacks reaching it are flagged (a retrace per
-    chunk size, i.e. a fresh XLA compile mid-storm), while the sanctioned
-    _stack_pow2 pad helper's pow-2 buckets pass clean — the shape gate
-    that keeps config5's steady_compiles exact."""
+def test_merge_entry_is_compile_gated():
+    """The device run merge is a registered jit entry: runtime-sized runs
+    reaching it are flagged (a retrace per run length, i.e. a fresh XLA
+    compile inside a beat on the store thread), while the sanctioned
+    _pad_pow2 pad helper's pow-2 buckets pass clean."""
     from tigerbeetle_tpu.tidy import jaxlint, manifest
 
     # The real kernel + its gate are registered, not just the fixture's.
-    assert "compact_fold_kernel" in manifest.JIT_ENTRIES
-    assert "_stack_pow2" in manifest.JAXLINT_PAD_HELPERS
+    assert "merge_kernel_tiled" in manifest.JIT_ENTRIES
+    assert {"_pad_pow2", "to_device_run"} <= manifest.JAXLINT_PAD_HELPERS
     assert (
-        "tigerbeetle_tpu/ops/merge.py", "compact_fold_materialize"
+        "tigerbeetle_tpu/ops/qindex.py", "materialize_fold"
     ) in manifest.JAXLINT_SYNC_SEAM
 
     findings = jaxlint.analyze_file(
-        FIXTURES / "retrace_compact.py", REPO, passes=("retrace",)
+        FIXTURES / "retrace_merge_runs.py", REPO, passes=("retrace",)
     )
     got = [(f.code, f.scope, f.subject) for f in findings]
     assert got == [
-        ("retrace-shape", "fold_ungated", "compact_fold_kernel"),
-        ("retrace-shape", "fold_ungated", "compact_fold_kernel"),
-    ], findings
-    # No finding in fold_gated: _stack_pow2's result is shape-stabilized.
-    assert all(f.scope != "fold_gated" for f in findings)
+        ("retrace-shape", "merge_ungated", "merge_kernel_tiled"),
+    ] * 4, findings
+    # No finding in merge_gated: _pad_pow2's result is shape-stabilized.
+    assert all(f.scope != "merge_gated" for f in findings)
 
 
 # --- reduction pass ------------------------------------------------------

@@ -150,13 +150,10 @@ class TestDurableIndex:
         assert (idx2.lookup_batch(q) == vals[::17]).all()
         assert idx2.count == idx.count
 
-    def test_device_and_host_compaction_same_tables(self, monkeypatch):
-        """The north-star bar: compaction through the device merge kernel
-        produces byte-identical table contents to the host merge. The
-        device route is FORCED (device_merge_pays() is false on CPU-only
-        backends since the query-index pipeline's routing policy) so the
-        kernel path stays exercised here."""
-        monkeypatch.setenv("TIGERBEETLE_TPU_DEVICE_MERGE", "1")
+    def test_jax_and_numpy_backend_compaction_same_tables(self):
+        """The north-star bar: a jax-backend tree's compaction leaves
+        byte-identical table contents to the numpy backend's (both merge
+        their runs with the host C k-way merge)."""
         _, idx_h, lo, hi, vals = self._rand_index(backend="numpy")
         _, idx_d, _, _, _ = self._rand_index(backend="jax")
 
@@ -171,26 +168,34 @@ class TestDurableIndex:
 
         assert dump(idx_h) == dump(idx_d)
 
-    def test_storm_device_and_host_identical(self, monkeypatch):
+    def _paced_storm(self, backend, step=None, quota=2048, drained=False):
+        """A forced all-level major compaction in beats of `quota` entries;
+        `step(idx, beat)` replaces the plain compact_step where given.
+        `drained`: the level jobs run first, so the storm folds two long
+        tables (and reads the grid inside its steps) instead of many
+        short ones that fit its read-ahead."""
+        grid, idx, lo, hi, vals = self._rand_index(backend=backend)
+        if drained:
+            idx.drain_compaction()
+        assert idx.request_major() > 0
+        beats = 0
+        while idx.storm_active():
+            if step is None:
+                idx.compact_step(quota)  # paced: the job spans many beats
+            else:
+                step(idx, beats)
+            beats += 1
+            assert beats < 10_000
+        assert beats > 1  # actually incremental, not one mega-step
+        return grid, idx, lo, hi, vals
+
+    def test_storm_jax_and_numpy_backends_identical(self):
         """Determinism guard for the streaming storm engine: a forced
-        all-level major compaction through the device fold kernel
-        (split-phase, double-buffered) leaves byte-identical state —
-        manifest, fences, and raw grid bytes — to the host tier."""
-        monkeypatch.setenv("TIGERBEETLE_TPU_DEVICE_MERGE", "1")
-
-        def run(backend):
-            grid, idx, lo, hi, vals = self._rand_index(backend=backend)
-            assert idx.request_major() > 0
-            beats = 0
-            while idx.storm_active():
-                idx.compact_step(2048)  # paced: the job spans many beats
-                beats += 1
-                assert beats < 10_000
-            assert beats > 1  # actually incremental, not one mega-step
-            return grid, idx, lo, hi, vals
-
-        grid_h, idx_h, lo, hi, vals = run("numpy")
-        grid_d, idx_d, _, _, _ = run("jax")
+        all-level major compaction on the jax backend leaves
+        byte-identical state — manifest, fences, and raw grid bytes —
+        to the numpy backend."""
+        grid_h, idx_h, lo, hi, vals = self._paced_storm("numpy")
+        grid_d, idx_d, _, _, _ = self._paced_storm("jax")
         assert idx_h.checkpoint().tobytes() == idx_d.checkpoint().tobytes()
         fh, ch = idx_h.checkpoint_fences()
         fd, cd = idx_d.checkpoint_fences()
@@ -201,6 +206,77 @@ class TestDurableIndex:
         q = pack_keys(lo[::13], hi[::13])
         assert (idx_h.lookup_batch(q) == vals[::13]).all()
         assert (idx_d.lookup_batch(q) == vals[::13]).all()
+
+    @pytest.mark.parametrize("shape", ["level_jobs", "paced_storm"])
+    def test_jax_backend_compaction_stays_off_the_device(self, shape, monkeypatch):
+        """Compaction's runs come off the grid on the host and go back
+        to it on the host: even where the device merges pay
+        (TIGERBEETLE_TPU_DEVICE_MERGE=1 stands in for an accelerator
+        backend), level jobs and a paced storm on the jax backend ship
+        no byte either way and enter no device step."""
+        from tigerbeetle_tpu import tracer
+
+        monkeypatch.setenv("TIGERBEETLE_TPU_DEVICE_MERGE", "1")
+        was = tracer.enabled()
+        tracer.enable()
+        tracer.reset()
+        try:
+            if shape == "level_jobs":
+                _, idx, *_ = self._rand_index(backend="jax")
+                idx.drain_compaction()
+            else:
+                _, idx, *_ = self._paced_storm("jax")
+            snap = tracer.snapshot()
+        finally:
+            tracer.reset()
+            if not was:
+                tracer.disable()
+        assert snap["lsm.compact.merge"]["count"] > 0  # multi-run chunks merged
+        assert snap["lsm.compaction_installs"]["count"] > 0
+        assert "device.h2d_bytes" not in snap and "device.d2h_bytes" not in snap
+        assert not [e for e in snap if e.startswith("device.step.")]
+
+    def test_grid_read_fault_mid_step_retries_to_same_grid_bytes(self):
+        """A corrupt input block in the middle of a storm step on the jax
+        backend: the step's partial merges are dropped, the retried job
+        re-merges from its owed position into the same reserved blocks,
+        and the grid ends byte-identical to a run that never faulted."""
+        from tigerbeetle_tpu.io.grid import GridReadFault
+
+        faults = []
+
+        def step(idx, beat):
+            """compact_step under a grid whose first read after the
+            SECOND beat has merged a chunk fails, once."""
+            real = idx.grid.read_block
+
+            def faulty(index, *a, **kw):
+                job = idx._job
+                if (not faults and beat == 1
+                        and job.progress > job.progress_at_step_start):
+                    faults.append(beat)
+                    raise GridReadFault(index, None)
+                return real(index, *a, **kw)
+
+            idx.grid.read_block = faulty
+            try:
+                idx.compact_step(8192)
+            except GridReadFault:
+                assert idx._job is None and idx._aborted_resv is not None
+                assert idx.storm_active()
+            finally:
+                del idx.grid.read_block
+
+        grid_a, idx_a, lo, hi, vals = self._paced_storm(
+            "jax", quota=8192, drained=True)
+        grid_b, idx_b, _, _, _ = self._paced_storm(
+            "jax", step=step, drained=True)
+        assert faults == [1]
+        assert idx_a.checkpoint().tobytes() == idx_b.checkpoint().tobytes()
+        span = grid_a.block_count * grid_a.block_size
+        assert grid_a.storage.read(0, span) == grid_b.storage.read(0, span)
+        q = pack_keys(lo[::13], hi[::13])
+        assert (idx_b.lookup_batch(q) == vals[::13]).all()
 
     def test_fused_blooms_bit_identical_and_fp_pinned(self):
         """Compaction outputs carry Blooms built INSIDE the merge's
@@ -668,3 +744,42 @@ class TestWideKwayMerge:
                     assert (ref.words == b.words).all(), (k, start, end)
                     assert ref.count == b.count
                 start = end
+
+    @pytest.mark.parametrize("k", [2, 9, 65])
+    @pytest.mark.parametrize("crosses_table", [False, True])
+    def test_compaction_combine_is_sort_kv_with_posthoc_blooms(self, k, crosses_table):
+        """_CompactionJob._combine, the one route a compaction chunk takes
+        on every backend: its rows are sort_kv's over the runs'
+        concatenation (65 runs: past the shim's 64-run bound, so a
+        pre-fold), and the output tables' Blooms are what a pass of
+        _bloom_fill over the finished rows sets — also for a chunk that
+        starts inside one output table and ends inside the next."""
+        from tigerbeetle_tpu.lsm.store import Bloom, _bloom_fill, sort_kv
+        from tigerbeetle_tpu.lsm.tree import _CompactionJob
+
+        rng = np.random.default_rng(100 + k)
+        parts_k, parts_v = self._parts(rng, k, dup_heavy=True)
+        total = sum(len(p) for p in parts_k)
+
+        def job():
+            j = object.__new__(_CompactionJob)
+            # The chunk starts 100 rows into table 0; the boundary falls
+            # in its middle, or far behind its end.
+            j._span = 100 + total // 2 if crosses_table else 100 + 2 * total
+            j._out_pos = 100
+            j._blooms = [Bloom(2 * j._span), Bloom(2 * j._span)]
+            return j
+
+        fused, posthoc = job(), job()
+        mk, mv, prefilled = fused._combine(
+            [p.copy() for p in parts_k], [p.copy() for p in parts_v])
+        assert prefilled
+        rk, rv = sort_kv(np.concatenate(parts_k), np.concatenate(parts_v))
+        assert mk.tobytes() == rk.tobytes() and mv.tobytes() == rv.tobytes()
+        ends, blooms = posthoc._segments(total)
+        assert len(ends) == (2 if crosses_table else 1) and ends[-1] == total
+        _bloom_fill(rk, ends, blooms)
+        for got, want in zip(fused._blooms, posthoc._blooms):
+            assert (got.words == want.words).all() and got.count == want.count
+        assert posthoc._blooms[0].count > 0
+        assert (posthoc._blooms[1].count > 0) == crosses_table

@@ -128,15 +128,15 @@ def test_create_transfers_exact(one_chip, flag):
     assert (sweeps.shape, sweeps.dtype) == ((), np.int32)
 
 
-def test_merge_kernel_tiled(one_chip):
+@pytest.mark.parametrize("runs_folded", [1, 3], ids=["first_fold", "later_fold"])
+def test_merge_kernel_tiled(one_chip, runs_folded):
+    """The memtable fold of lazy key runs (qindex.fold_runs_device): run
+    B joins what the fold has gathered so far, so from the second step
+    on A is several runs long and no power of two."""
     rows = 1 << 15
-    run = np.zeros((rows, 3), np.uint32)
-    _compile(merge.merge_kernel_tiled, one_chip, run, run, run, run)
-
-
-def test_compact_fold_kernel(one_chip):
-    stack = np.zeros((8, 1 << 12, 3), np.uint32)
-    _compile(merge.compact_fold_kernel, one_chip, stack, stack)
+    a = np.zeros((runs_folded * rows, 3), np.uint32)
+    b = np.zeros((rows, 3), np.uint32)
+    _compile(merge.merge_kernel_tiled, one_chip, a, a, b, b)
 
 
 def test_query_index_keys(one_chip):

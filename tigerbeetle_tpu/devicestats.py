@@ -62,7 +62,6 @@ _ENTRY_MODULES = {  # tidy: atomic — immutable constant table, never written a
     "create_transfers_exact": "tigerbeetle_tpu.ops.commit_exact",
     "merge_kernel": "tigerbeetle_tpu.ops.merge",
     "merge_kernel_tiled": "tigerbeetle_tpu.ops.merge",
-    "compact_fold_kernel": "tigerbeetle_tpu.ops.merge",
     "query_index_keys": "tigerbeetle_tpu.ops.qindex",
     "query_index_keys_sorted": "tigerbeetle_tpu.ops.qindex",
     "scan_intersect_mask": "tigerbeetle_tpu.ops.scanops",

@@ -11,9 +11,10 @@ The TPU-first re-design of the reference's tree/table/compaction stack
     matching the prefetch-batch design, groove.zig:644-909); it flushes as a
     sorted level-0 table.
   - *Compaction* merges a full level into the next when it exceeds the
-    growth factor, streamed block-by-block through the merge kernel
-    (ops/merge.py — device binary-search merge on the jax backend, byte-
-    identical numpy merge on the host backend). Memory stays O(block), not
+    growth factor, streamed in chunks through the host's stable k-way
+    merge (lsm/store.merge_host_kway_bloom, the C shim) on every
+    backend: the runs come off the grid and go back to it on the host.
+    Memory stays O(block), not
     O(level): the streaming cursor logic here plays the role of the
     reference's k-way merge iterator pacing (k_way_merge.zig:8).
 
@@ -248,9 +249,6 @@ class _MergeStream:
         k, v = self.keys[:cut], self.vals[:cut]
         self.keys, self.vals = self.keys[cut:], self.vals[cut:]
         return k, v
-
-    def last_buffered_lo(self) -> int:
-        return int(self.keys[-1]["lo"])
 
     def bound_lo(self, target_rows: int) -> int:
         """A safe chunk bound ~target_rows into the buffer. Any buffered
@@ -714,7 +712,6 @@ class DurableIndex:
             # kept, so the retried job forwards to the position peers
             # hold and stays install-op aligned.
             owed = self._job.progress_at_step_start + self._job.pending_ff
-            self._job.discard_pending()
             self._job.writer.abort()
             self._aborted_resv = (
                 self._job.level, self._job.tables, self._job.reservation,
@@ -860,49 +857,6 @@ class DurableIndex:
         a manifest must never reference a half-written merge)."""
         while self.compact_step(1 << 62):
             pass
-
-    def _merge_chunk(self, ka, va, kb, vb) -> Tuple[np.ndarray, np.ndarray]:
-        # ops.merge only on the jax backend (importing it pulls in jax).
-        if self.backend == "jax":
-            from tigerbeetle_tpu.ops import merge as merge_ops
-
-            if merge_ops.device_merge_pays():
-                return merge_ops.merge_device(ka, va, kb, vb)
-        return merge_host_kway([ka, kb], [va, vb])
-
-    def _merge_tables(
-        self, tables_a: List[TableInfo], tables_b: List[TableInfo]
-    ) -> List[TableInfo]:
-        """Streaming stable merge of two key-ordered table sequences,
-        O(block) memory; emits one or more non-overlapping tables."""
-        a = _MergeStream(self, tables_a)
-        b = _MergeStream(self, tables_b)
-        out = _TableWriter(self)
-        while True:
-            a_empty, b_empty = a.exhausted(), b.exhausted()
-            if a_empty and b_empty:
-                break
-            if b_empty:
-                out.append(*a.take(None))
-                continue
-            if a_empty:
-                out.append(*b.take(None))
-                continue
-            # Emit everything up to the smaller of the two buffered tail
-            # lo-keys — all later input sorts at or past it; a lo-tie run
-            # split across windows is fine (point lookups verify hi, and
-            # the non-unique read path sorts values per key).
-            bound = min(a.last_buffered_lo(), b.last_buffered_lo())
-            ka, va = a.take(bound)
-            kb, vb = b.take(bound)
-            if len(ka) and len(kb):
-                mk, mv = self._merge_chunk(ka, va, kb, vb)
-                out.append(mk, mv)
-            elif len(ka):
-                out.append(ka, va)
-            elif len(kb):
-                out.append(kb, vb)
-        return out.finish()
 
     def compact_all(self) -> None:
         """Forced major compaction: merge every level into one bottom run
@@ -1188,7 +1142,7 @@ class DurableIndex:
                 if e > s:
                     # Tables are LO-major ordered only: a merge drains
                     # equal-lo ties oldest-stream-first with within-run
-                    # order preserved (_merge_tables), so hi need NOT
+                    # order preserved (_CompactionJob), so hi need NOT
                     # ascend inside the segment — window by mask, never
                     # searchsorted.
                     run_hi = bk["hi"][s:e]
@@ -1271,7 +1225,7 @@ class DurableIndex:
         UNSELECTIVE predicate costs O(|cand| · log gap) per touched
         segment instead of a full scan + sort. Tables are LO-major
         ordered only (equal-lo merge ties drain oldest-stream-first,
-        within-run order preserved — _merge_tables), so the hi window is
+        within-run order preserved — _CompactionJob), so the hi window is
         a MASK and the segment's values need not ascend (flush-fresh
         segments do: commit order IS row order). Returns newly marked
         count; counts pruned/probed runs on lsm.scan.* (satellite:
@@ -1606,10 +1560,6 @@ class _CompactionJob:
             for t in range(n_tables)
         ]
         self._out_pos = 0
-        # Split-phase double buffer: a dispatched-but-unmaterialized
-        # device merge chunk (flushed in dispatch order; never outlives
-        # one step call).
-        self._pending = None
         self.writer = _TableWriter(tree, reservation, blooms=self._blooms)
         # Cumulative entries merged — persisted with the checkpoint
         # descriptor so a restarted replica fast-forwards to the SAME
@@ -1628,15 +1578,9 @@ class _CompactionJob:
         """Merge ≥1 chunk, up to ~quota_entries; True when exhausted."""
         self.progress_at_step_start = self.progress
         merged = 0
-        use_device = False
-        if self.tree.backend == "jax":
-            from tigerbeetle_tpu.ops import merge as merge_ops
-
-            use_device = merge_ops.device_merge_pays()
         while merged < quota_entries:
             live = [s for s in self.streams if not s.exhausted()]
             if not live:
-                self._flush_pending()
                 return True
             if len(live) == 1:
                 k, v = live[0].take(None)
@@ -1658,28 +1602,11 @@ class _CompactionJob:
                     parts_k.append(k)
                     parts_v.append(v)
             n_chunk = sum(len(k) for k in parts_k)
-            if use_device and len(parts_k) > 1:
-                # Split-phase: dispatch THIS chunk's device fold before
-                # materializing the PREVIOUS one, so the device merge
-                # overlaps the previous chunk's host-side bloom feed and
-                # table build (the streaming engine's double buffer).
-                # Chunks append strictly in dispatch order, so output
-                # bytes are identical to the synchronous path.
-                from tigerbeetle_tpu.ops import merge as merge_ops
-
-                with tracer.span("lsm.compact.merge"):
-                    handle = merge_ops.compact_fold_dispatch(
-                        parts_k, parts_v
-                    )
-                self._flush_pending()
-                self._pending = handle
-            else:
-                with tracer.span("lsm.compact.merge"):
-                    ck, cv, prefilled = self._combine(parts_k, parts_v)
-                self._append(ck, cv, prefilled=prefilled)
+            with tracer.span("lsm.compact.merge"):
+                ck, cv, prefilled = self._combine(parts_k, parts_v)
+            self._append(ck, cv, prefilled=prefilled)
             merged += n_chunk
             self.progress += n_chunk
-        self._flush_pending()
         return False
 
     def _combine(
@@ -1688,8 +1615,8 @@ class _CompactionJob:
         """Host k-way combine → (keys, vals, bloom_prefilled)."""
         if len(parts_k) == 1:
             return parts_k[0], parts_v[0], False
-        # Host path: each part is sorted and parts arrive oldest-first,
-        # so the stable galloping k-way merge (C shim) produces the
+        # Each part is sorted and parts arrive oldest-first, so the
+        # stable galloping k-way merge (C shim) produces the
         # radix sort's exact bytes at merge cost instead of sort cost —
         # and the fused variant sets the output tables' Bloom bits on the
         # rows while they are cache-hot from the copy, erasing the
@@ -1722,10 +1649,7 @@ class _CompactionJob:
         self, keys: np.ndarray, vals: np.ndarray, prefilled: bool = False
     ) -> None:
         """Feed output rows to the writer, populating table Blooms for
-        any path that did not fuse them (single-stream passthrough,
-        device-fold chunks). Flushes a pending device chunk first so
-        output rows land in merge order."""
-        self._flush_pending()
+        the rows no merge fused them into (single-stream passthrough)."""
         if len(keys) == 0:
             return
         if not prefilled and self._blooms:
@@ -1735,30 +1659,6 @@ class _CompactionJob:
         self._out_pos += len(keys)
         with tracer.span("lsm.compact.build"):
             self.writer.append(keys, vals)
-
-    def _flush_pending(self) -> None:
-        """Materialize + append the previously dispatched device chunk
-        (the back half of the split-phase double buffer)."""
-        if self._pending is None:
-            return
-        from tigerbeetle_tpu.ops import merge as merge_ops
-
-        handle, self._pending = self._pending, None
-        with tracer.span("lsm.compact.merge"):
-            k, v = merge_ops.compact_fold_materialize(handle)
-        self._append(k, v)
-
-    def discard_pending(self) -> None:
-        """Drop a dispatched-but-unappended device chunk (fault abort
-        path): closes its tracer dispatch token and releases its
-        memory-ledger bytes; the retried job simply re-merges the
-        chunk."""
-        if self._pending is None:
-            return
-        from tigerbeetle_tpu.ops import merge as merge_ops
-
-        handle, self._pending = self._pending, None
-        merge_ops.compact_fold_discard(handle)
 
     def prefetch_one(self) -> bool:
         """Warm one upcoming input block (idle read-ahead); see

@@ -268,9 +268,10 @@ class StateMachine:
             memtable_max=config.index_memtable_rows, backend=backend,
             name="query_rows", merge_hint="dups",
         )
-        # Device query-index pipeline (ops/qindex.py): key build + run
-        # merge on the device, lazy host materialization. Only where the
-        # device path pays (accelerator backends; TIGERBEETLE_TPU_DEVICE_MERGE
+        # Device query-index pipeline (ops/qindex.py): key build + the
+        # memtable's run merge on the device, lazy host materialization
+        # (compaction of the flushed tables merges on the host on every
+        # backend). Only where the device path pays (accelerator backends; TIGERBEETLE_TPU_DEVICE_MERGE
         # forces either way) — the numpy/CPU fallback keeps the host block
         # in _store_query_index, byte-identical by the qindex property
         # tests.

@@ -1,4 +1,4 @@
-"""Device streaming-merge kernel for LSM compaction (north-star part 2).
+"""Device merge kernel for sorted runs that are already on the device.
 
 The reference's compaction inner loop is a serial k-way merge iterator
 (/root/reference/src/lsm/compaction.zig:743 + k_way_merge.zig:8): pop the
@@ -19,18 +19,13 @@ lsm/store.py). The hi word rides as payload, so compares touch 2 limbs,
 not 4; a third pad-flag limb makes padding sort strictly last even when a
 real key's lo is all-ones.
 
-K-way level merges fold pairwise over this kernel, streaming block-sized
-windows through HBM (lsm/tree.py paces the windows). Stability contract:
-A's elements precede B's at equal keys — callers pass the OLDER run as A so
-duplicate-key secondary indexes keep insertion (row) order.
-
-Measured honestly (262k-row merges, v5e-1): the merge-path tiled kernel
-below runs 3.6x the global binary-search form (random HBM gathers), but a
-pure standalone merge remains latency-bound, not FLOP-bound — a single
-host core's searchsorted still wins for an isolated merge. The device
-kernel earns its keep when compaction overlaps device-resident commit
-work (no host round trip for state already on-chip) and as the substrate
-for fusing dedup/tombstone logic into the same pass.
+Who folds over it: the memtable flush of lazy device key runs
+(ops/qindex.fold_runs_device), where the runs never left the chip. Level and storm compaction do NOT:
+their runs come off the grid on the host and go back to it on the host,
+so lsm/tree.py merges them there (the C k-way merge of lsm/store.py) on
+every backend. Stability contract: A's elements precede B's at equal
+keys — callers pass the OLDER run as A so duplicate-key secondary indexes
+keep insertion (row) order.
 
 Byte-equality vs the host merge (merge_host below) is enforced by
 tests/test_lsm.py property tests.
@@ -284,105 +279,6 @@ def merge_device(keys_a, vals_a, keys_b, vals_b):
         d2h=ok.nbytes + op.nbytes,
     )
     return out
-
-
-@functools.partial(jax.jit, static_argnames=())
-def compact_fold_kernel(keys_stack, pays_stack):
-    """Whole-chunk k-way compaction fold in ONE dispatch: (k, b, 3)
-    stacked sorted runs → one merged (k·b, 3) run, folded pairwise
-    through merge_kernel_tiled inside this trace (traced inner jit calls
-    are one compile, not k). k and b are both pow-2 (callers pad via
-    _stack_pow2), so compile count is bounded by the handful of
-    (k-bucket, b-bucket) pairs a compaction quota can produce — the
-    steady_compiles exact gate stays green. Stability: runs are stacked
-    oldest-first and every pairwise merge keeps A-side (earlier) rows
-    first at equal keys, so the tree fold preserves the global
-    oldest-first order."""
-    k = keys_stack.shape[0]
-    keys = [keys_stack[i] for i in range(k)]
-    pays = [pays_stack[i] for i in range(k)]
-    while len(keys) > 1:
-        nk, npay = [], []
-        # Metadata only: names each level of the fold in a profiler trace.
-        with jax.named_scope(f"fold_{len(keys)}_runs"):
-            for i in range(0, len(keys), 2):
-                ok, op = merge_kernel_tiled(keys[i], pays[i], keys[i + 1], pays[i + 1])
-                nk.append(ok)
-                npay.append(op)
-        keys, pays = nk, npay
-    return keys[0], pays[0]
-
-
-def _stack_pow2(parts_k, parts_v):
-    """Host KEY_DTYPE runs → the fold kernel's stacked ((k_pad, b, 3)
-    keys, (k_pad, b, 3) payload) layout: every run padded to ONE common
-    pow-2 bucket b (pad rows set the pad-flag limb, sorting strictly
-    last), the run list padded to a pow-2 count with all-pad runs.
-    Returns (keys, payload, total_real_rows)."""
-    k = len(parts_k)
-    k_pad = 1 << max(0, (k - 1).bit_length())
-    b = bucket_pow2(max(len(p) for p in parts_k))
-    ks = np.zeros((k_pad, b, 3), dtype=np.uint32)
-    ks[:, :, 2] = 1
-    ps = np.zeros((k_pad, b, 3), dtype=np.uint32)
-    total = 0
-    for i, (pk, pv) in enumerate(zip(parts_k, parts_v)):
-        n = len(pk)
-        total += n
-        ks[i, :n, 0] = pk["lo"] & np.uint64(0xFFFFFFFF)
-        ks[i, :n, 1] = pk["lo"] >> np.uint64(32)
-        ks[i, :n, 2] = 0
-        ps[i, :n, 0] = pk["hi"] & np.uint64(0xFFFFFFFF)
-        ps[i, :n, 1] = pk["hi"] >> np.uint64(32)
-        ps[i, :n, 2] = pv
-    return ks, ps, total
-
-
-def compact_fold_dispatch(parts_k, parts_v):
-    """Stage + dispatch one compaction chunk's k-way fold; NO device→host
-    sync — the split-phase front half of the streaming compaction engine
-    (the handle is resolved by compact_fold_materialize, typically one
-    chunk later so the transfer overlaps the next chunk's merge)."""
-    ks, ps, total = _stack_pow2(parts_k, parts_v)
-    devicestats.note_call("compact_fold_kernel", (ks, ps))
-    t_disp = tracer.device_dispatch(
-        "compact_fold_kernel", h2d_bytes=ks.nbytes + ps.nbytes
-    )
-    keys_dev, pays_dev = compact_fold_kernel(ks, ps)
-    # Memory ledger: the fold's device-resident output lives until the
-    # handle is materialized or discarded. `.nbytes` is shape metadata
-    # — never a sync.
-    tracer.device_mem_adjust("compact_fold", _fold_nbytes(keys_dev, pays_dev))
-    return keys_dev, pays_dev, total, t_disp
-
-
-def _fold_nbytes(keys_dev, pays_dev) -> int:
-    return int(
-        getattr(keys_dev, "nbytes", 0) + getattr(pays_dev, "nbytes", 0)
-    )
-
-
-def compact_fold_materialize(handle):
-    """Sync + strip a compact_fold_dispatch handle (sanctioned seam, the
-    chunk-append boundary): (KEY_DTYPE keys, u32 vals) of the real rows."""
-    keys_dev, pays_dev, total, t_disp = handle
-    ok = np.asarray(keys_dev)
-    op = np.asarray(pays_dev)
-    tracer.device_finish(
-        "compact_fold_kernel", t_disp, d2h_bytes=ok.nbytes + op.nbytes
-    )
-    tracer.device_mem_adjust("compact_fold", -_fold_nbytes(keys_dev, pays_dev))
-    return from_device_run(ok.reshape(-1, 3), op.reshape(-1, 3), total)
-
-
-def compact_fold_discard(handle) -> None:
-    """Close a dispatched fold handle WITHOUT materializing it (the
-    fault-abort path, lsm/tree.py discard_pending): closes the dispatch
-    window and returns the chunk's ledger bytes. Metadata reads only —
-    discarding must never force the sync it exists to avoid."""
-    keys_dev, pays_dev, _total, t_disp = handle
-    tracer.device_finish("compact_fold_kernel", t_disp)
-    tracer.device_mem_adjust("compact_fold", -_fold_nbytes(keys_dev, pays_dev))
 
 
 # Host-side stable k-way merge: lives in lsm/store.py (jax-free, next to

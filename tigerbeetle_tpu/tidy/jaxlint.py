@@ -991,8 +991,7 @@ class CompileRegistry:
             (commit, ("create_transfers_fast", "register_accounts",
                       "write_balances", "read_balances")),
             (commit_exact, ("create_transfers_exact",)),
-            (merge, ("merge_kernel", "merge_kernel_tiled",
-                     "compact_fold_kernel")),
+            (merge, ("merge_kernel", "merge_kernel_tiled")),
             (qindex, ("query_index_keys", "query_index_keys_sorted")),
         ):
             for n in names:

@@ -108,7 +108,6 @@ JIT_ENTRIES = {
     "read_balances": (),
     "merge_kernel": (),
     "merge_kernel_tiled": ("tile",),
-    "compact_fold_kernel": (),
     "query_index_keys": (),
     "query_index_keys_sorted": (),
     "scan_intersect_mask": (),
@@ -131,9 +130,6 @@ JAXLINT_SYNC_SEAM = frozenset((
     # table-build boundary (lsm/tree._flush_sorted_kv).
     ("tigerbeetle_tpu/ops/qindex.py", "QueryKeyRun.materialize"),
     ("tigerbeetle_tpu/ops/qindex.py", "materialize_fold"),
-    # The streaming-compaction device fold's only sync point: the back
-    # half of the split-phase double buffer (_CompactionJob._flush_pending).
-    ("tigerbeetle_tpu/ops/merge.py", "compact_fold_materialize"),
     # The device scan-intersect's only sync point: mask compression on
     # the QUERY path (read-side, like store_barrier — never the commit
     # path, which does not call into ops/scanops at all).
@@ -143,7 +139,7 @@ JAXLINT_SYNC_SEAM = frozenset((
 # Functions whose results count as shape-stabilized (bucket-padded):
 # jit-entry arguments produced by these escape the retrace-shape rule.
 JAXLINT_PAD_HELPERS = frozenset((
-    "_device_batch", "_pad_pow2", "_pad_slots", "_stack_pow2", "pad1",
+    "_device_batch", "_pad_pow2", "_pad_slots", "pad1",
     "p1", "stage_query_batch", "to_device_run", "_pad_sorted_u32",
 ))
 

@@ -463,8 +463,8 @@ def gauges() -> Dict[str, float]:
 #
 # Who holds device memory right now, by owner tag: the dispatch scratch
 # ring's generation-keyed buckets (`scratch.<entry>.b<n_pad>`), the
-# resident balance tables (`balances`), lazy query-key runs
-# (`query_runs`), and in-flight compaction fold chunks (`compact_fold`).
+# resident balance tables (`balances`) and lazy query-key runs
+# (`query_runs`).
 # Byte counts are `.nbytes` shape metadata — never a device sync — and
 # every write republishes the owner's `device.mem.<owner>.bytes` gauge
 # so the ledger rides the ordinary scrape surface. The high-water mark
