@@ -99,10 +99,13 @@ def test_create_transfers_fast(one_chip):
     )
 
 
-@pytest.mark.parametrize("flag", [True, False], ids=["pv+chains", "plain"])
-def test_create_transfers_exact(one_chip, flag):
-    """Both ends of the static-flag square the state machine compiles
-    (has_pv / has_chains follow the batch's content)."""
+@pytest.mark.parametrize("has_pv,has_chains", [
+    (True, True), (False, False), (False, True), (True, False),
+], ids=["pv+chains", "plain", "chains", "pv"])
+def test_create_transfers_exact(one_chip, has_pv, has_chains):
+    """All four corners of the static-flag square the state machine
+    compiles (has_pv / has_chains follow the batch's content): settlement
+    batches carry both, TPC-B's chains alone."""
     i32 = lambda *shape: np.zeros(shape, np.int32)
     pending = commit_exact.PendingInfo(
         found=np.zeros(N, bool), amount=np.zeros((N, 4), np.uint32),
@@ -119,7 +122,7 @@ def test_create_transfers_exact(one_chip, flag):
         commit_exact.create_transfers_exact, one_chip,
         _ledger_state(), _transfer_batch(N), np.zeros(N, np.uint32),
         pending, i32(N), plan,
-        has_pv=flag, has_chains=flag,
+        has_pv=has_pv, has_chains=has_chains,
     )
     # The v5e program carries the sweep count out beside the bail flag:
     # (state, codes, amounts, dr_after, cr_after, bail, sweeps).

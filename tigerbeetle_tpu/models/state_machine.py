@@ -1805,13 +1805,26 @@ class StateMachine:
 
             # Host-side sort plan: a ~100 µs numpy lexsort here replaces ~ms of
             # device lax.sort inside the kernel (SortPlan docstring).
-            # tidy: allow=retrace-shape — every input is n_pad-shaped (the padded batch b / padp outputs), so the plan's shapes are bucket-stable
-            plan = commit_exact.build_sort_plan(
-                np.asarray(b.flags), np.asarray(b.dr_slot), np.asarray(b.cr_slot),
-                pinfo.dr_slot, pinfo.cr_slot, chain_id_p, pinfo.group,
-                int(self.state.ledger.shape[0]),
-            )
+            with tracer.span("sm.ct.plan"):
+                # tidy: allow=retrace-shape — every input is n_pad-shaped (the padded batch b / padp outputs), so the plan's shapes are bucket-stable
+                plan = commit_exact.build_sort_plan(
+                    np.asarray(b.flags), np.asarray(b.dr_slot), np.asarray(b.cr_slot),
+                    pinfo.dr_slot, pinfo.cr_slot, chain_id_p, pinfo.group,
+                    int(self.state.ledger.shape[0]),
+                )
             has_pv, has_chains = bool(np.any(is_pv)), bool(np.any(linked))
+            if tracer.enabled():
+                # What the batch brings the kernel: chains of two or more
+                # events (by their heads) and how its 2n postings fall on
+                # slots. Counted at the sync below, for batches that stay
+                # on this route.
+                chain_heads = new_chain & linked
+                pv_p = padp(is_pv, False)
+                posted = int(np.count_nonzero(np.concatenate([
+                    np.where(pv_p, pinfo.dr_slot, np.asarray(b.dr_slot)),
+                    np.where(pv_p, pinfo.cr_slot, np.asarray(b.cr_slot)),
+                ]) >= 0))
+                slots_touched, slot_postings_max = commit_exact.plan_slot_segments(plan, posted)
             devicestats.note_call(
                 "create_transfers_exact",
                 (self.state, b, host_code_p, pinfo, chain_id_p, plan),
@@ -1847,6 +1860,13 @@ class StateMachine:
                     # The while_loop's own carry, 4 bytes, at the seam the
                     # kernel's results are taken at anyway.
                     tracer.count("sm.exact.sweeps", int(sweeps))
+                    tracer.count("sm.exact.chains", int(np.count_nonzero(chain_heads)))
+                    tracer.count(
+                        "sm.exact.chains_rolled_back",
+                        int(np.count_nonzero(chain_heads & (codes_h[:n] != 0))),
+                    )
+                    tracer.count("sm.exact.slots_touched", slots_touched)
+                    tracer.count("sm.exact.slot_postings_max", slot_postings_max)
             tracer.device_finish("create_transfers_exact", t_disp, d2h_bytes=d2h)
         if bailed:
             self._count_route("bail_batches")

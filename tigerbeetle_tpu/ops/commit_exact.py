@@ -181,6 +181,18 @@ def build_sort_plan(
     )
 
 
+def plan_slot_segments(plan: SortPlan, posted: int):
+    """(distinct slots, longest slot segment) among a plan's first `posted`
+    records: the batch's postings in (slot, event) order. The records
+    without a slot (padding, an account not found) sort behind them."""
+    import numpy as np
+
+    if posted <= 0:
+        return 0, 0
+    heads = np.flatnonzero(plan.head_pos[:posted] == np.arange(posted))
+    return len(heads), int(np.diff(heads, append=posted).max())
+
+
 def _static_ladder(state: LedgerState, b: TransferBatch, is_pv):
     """Order-independent rungs for REGULAR (non-post/void) events
     (reference ladder up to the exists check), with the balancing
