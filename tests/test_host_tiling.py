@@ -77,6 +77,16 @@ class _Bus:
         self.replies.append(msg)
 
 
+# The exact route's two cases: a batch with a post/void event waits for the
+# store and writes it inline; a batch without one (linked chains only) reads
+# nothing from the store and hands its rows to the store thread.
+EXACT = ("exact", "exact_chains")
+
+
+def _counter(route: str) -> str:
+    return ("exact" if route in EXACT else route) + "_batches"
+
+
 def _batch(route: str, i: int) -> np.ndarray:
     t = np.zeros(N, dtype=types.TRANSFER_DTYPE)
     t["id_lo"] = 1000 + N * i + np.arange(N)
@@ -85,10 +95,10 @@ def _batch(route: str, i: int) -> np.ndarray:
     t["amount_lo"] = 1 + i
     t["ledger"] = 1
     t["code"] = 7
-    if route == "exact":  # linked chains, a pending, a post of the batch before
+    if route in EXACT:  # linked chains, a pending and, in one case, a post of the batch before
         t["flags"][0:8:2] = int(TransferFlags.LINKED)
         t["flags"][10] = int(TransferFlags.PENDING)
-        if i > 0:
+        if route == "exact" and i > 0:
             t["flags"][11] = int(TransferFlags.POST_PENDING_TRANSFER)
             t["pending_id_lo"][11] = 1000 + N * (i - 1) + 10
     if route == "serial":  # a duplicate id inside the batch
@@ -175,6 +185,7 @@ def _drive(route: str, depth: int, ops: int = 40) -> dict:
             "threads": tracer.by_thread(),
             "routes": {k[len("sm.route."):]: v["count"] for k, v in snap.items()
                        if k.startswith("sm.route.")},
+            "deferred": snap.get("sm.exact.store_deferred", {}).get("count"),
             "chain": dict(replica.commit_checksums),
             "digest": hdr.checksum(snapshot_mod.encode(replica)),
         }
@@ -221,6 +232,7 @@ TILED = 204  # batches a tiling stretch commits, WARM of them before it starts
 @pytest.mark.parametrize("route,thread", [
     ("fast", "commit-executor"),
     ("exact", "commit-executor"),
+    ("exact_chains", "commit-executor"),
     ("serial", "commit-executor"),
     ("fast", "store-executor"),
 ])
@@ -238,7 +250,7 @@ def test_leaf_spans_tile_the_thread(traced, route, thread):
     best = 0.0
     for _attempt in range(3):
         run = _drive(route, 2, ops=TILED)
-        assert run["routes"] == {f"{route}_batches": TILED - WARM}
+        assert run["routes"] == {_counter(route): TILED - WARM}
         best = max(best, _cycle_share(thread, leaves, waits, boundary))
         if best >= 0.90:
             break
@@ -250,6 +262,8 @@ ROUTE_LEAVES = {  # route, commit depth -> the commit thread's leaves, each at l
     ("fast", 1): ("sm.ct.stage", "sm.ct.dispatch", "sm.ct.sync", "sm.ct.post"),
     ("exact", 2): ("sm.ct.prefetch", "sm.ct.stage", "sm.ct.dispatch", "sm.ct.sync",
                    "sm.ct.post", "sm.store.barrier"),
+    ("exact_chains", 2): ("sm.ct.prefetch", "sm.ct.stage", "sm.ct.dispatch", "sm.ct.sync",
+                          "sm.ct.post"),
     ("serial", 2): ("sm.ct.stage", "sm.ct.serial", "sm.store.barrier"),
 }
 
@@ -271,10 +285,14 @@ def test_every_stage_of_the_route_has_its_leaf(traced, route, depth):
             "replica.execute.tail", "stage.reply", "stage.complete"):
         assert commit.get(event, (0, 0))[0] >= batches, (event, commit.get(event))
     assert commit["replica.execute"][0] == store["sm.beat"][0] == batches
-    if route == "fast":  # the store thread applies the batch; else it is inline, in sm.ct.post
+    if route in ("fast", "exact_chains"):  # the store thread applies the batch
         assert store["sm.store.log"][0] == batches and "sm.store.log" not in commit
-    else:
+        assert "sm.store.barrier" not in commit
+    else:  # it is inline, in sm.ct.post (exact) or sm.ct.serial, behind the barrier
         assert commit["sm.store.log"][0] == batches and "sm.store.log" not in store
+        assert commit["sm.store.barrier"][0] == batches
+    # every exact batch says whether it deferred its store; no other route does
+    assert run["deferred"] == {"exact": 0, "exact_chains": batches}.get(route)
     assert _tiled_share(run, "commit-executor", COMMIT_LEAVES, COMMIT_WAITS) <= 1.05
     mine = sorted(
         (t0, t1, event) for event, name, _tid, t0, t1 in tracer.trace_events()
@@ -494,7 +512,7 @@ def test_annotated_span_lands_in_the_profilers_trace(traced, tmp_path, event):
 
 
 @needs_staging
-@pytest.mark.parametrize("route", ["fast", "exact", "serial"])
+@pytest.mark.parametrize("route", ["fast", "exact", "exact_chains", "serial"])
 def test_on_vs_off_byte_identical(route):
     """The spans, the sweeps read and the window accounting observe the
     commit path and never steer it: the same batches commit the same bytes
@@ -508,7 +526,7 @@ def test_on_vs_off_byte_identical(route):
         tracer.enable()
         tracer.reset()
         on = _drive(route, 2, ops=12)
-        assert on["routes"] == {f"{route}_batches": 12 - WARM}
+        assert on["routes"] == {_counter(route): 12 - WARM}
         assert "sm.ct.stage" in on["threads"]["commit-executor"]
         assert (off["chain"], off["digest"]) == (on["chain"], on["digest"])
     finally:

@@ -243,6 +243,83 @@ class TestStoreExecutor:
         assert applied == [1, 2, 3, 4]
         se.stop()
 
+    def test_exact_batch_that_reads_no_store_commits_past_a_parked_stage(self):
+        """The stage parks on a beat's fault (the job's rows have landed by
+        then) while the commit path goes on: a linked-chain exact batch
+        takes no barrier, so it commits, hands its rows on and queues
+        behind the park as a fast batch does; a batch with a post in it
+        takes the barrier and meets the fault there, unexecuted. After the
+        resume the jobs drain in op order and every id reads back."""
+        from tigerbeetle_tpu.constants import TEST_MIN
+        from tigerbeetle_tpu.flags import TransferFlags
+        from tigerbeetle_tpu.io.grid import GridReadFault
+        from tigerbeetle_tpu.models.state_machine import StateMachine
+
+        sm = StateMachine(TEST_MIN, backend="jax")
+        acc = np.zeros(4, dtype=types.ACCOUNT_DTYPE)
+        acc["id_lo"] = np.arange(1, 5)
+        acc["ledger"] = 1
+        acc["code"] = 10
+        assert len(sm.create_accounts(acc, timestamp=4)) == 0
+        applied, fail_once = [], [True]
+
+        def process(job):  # replica._store_process: the rows, then the beat
+            if not job.get("stored"):
+                recs, ts = job["store"]
+                sm._store_new_transfers(recs, ts=ts, add_bloom=False)
+                job["stored"] = True
+            if job["op"] == 1 and fail_once[0]:
+                fail_once[0] = False
+                job["fault"] = GridReadFault(7, None)
+                return job
+            sm.compact_beat(flush=False)
+            applied.append(job["op"])
+            return None
+
+        se = StoreExecutor(process=process, post=lambda cb: None, notify=lambda: None)
+        sm.attach_store_stage(se)
+
+        def chains(op, post_of=0):
+            t = np.zeros(6, dtype=types.TRANSFER_DTYPE)
+            t["id_lo"] = 10 * op + np.arange(6)
+            t["debit_account_id_lo"] = [1, 2, 3, 1, 2, 3]
+            t["credit_account_id_lo"] = [2, 3, 4, 4, 1, 2]
+            t["amount_lo"] = op
+            t["ledger"] = 1
+            t["code"] = 7
+            t["flags"] = [1, 1, 0, 1, 1, 0]  # linked, linked, closed
+            if post_of:
+                t["flags"][5] = int(TransferFlags.POST_PENDING_TRANSFER)
+                t["pending_id_lo"][5] = post_of
+                t["amount_lo"][5] = 0  # the pending's own
+            return t
+
+        def commit(op, events):  # execute, then replica._finish_commit's submit
+            results = sm.create_transfers(events, timestamp=100 * op)
+            se.submit({"op": op, "store": sm.take_deferred_store()})
+            return results
+
+        try:
+            first = chains(1)
+            first["flags"][5] = int(TransferFlags.PENDING)
+            assert len(commit(1, first)) == 0
+            _wait(lambda: se.parked)
+            assert len(commit(2, chains(2))) == 0 and len(commit(3, chains(3))) == 0  # no barrier, no fault
+            assert sm.stats["exact_batches"] == 3 and applied == []
+            assert [int(r["id_lo"][0]) for r, _ts in se.unapplied_stores()] == [20, 30]
+            with pytest.raises(GridReadFault):  # the post reads the store: today's route
+                sm.create_transfers(chains(4, post_of=15), timestamp=400)
+            assert sm.stats["exact_batches"] == 3 and not sm.stats["serial_batches"]
+            se.resume(se.pop_done())
+            assert len(commit(4, chains(4, post_of=15))) == 0  # drains 1, 2, 3, then stores inline
+            assert applied == [1, 2, 3] and se.unapplied_stores() == []
+            ids = np.concatenate([chains(op)["id_lo"] for op in (1, 2, 3, 4)])
+            got = sm.lookup_transfers(ids, np.zeros(len(ids), np.uint64))
+            assert got["id_lo"].tolist() == ids.tolist()
+            assert got["timestamp"].tolist() == sorted(got["timestamp"].tolist())
+        finally:
+            se.stop()
+
     def test_submit_backpressure_bounds_queue(self):
         release = threading.Event()
 

@@ -417,8 +417,8 @@ class StateMachine:
 
     def take_deferred_store(self):  # tidy: thread=commit
         """Pop the deferred batch for an async store job (replica
-        _finish_commit). None when the op stored inline (exact/serial
-        paths) or wrote nothing."""
+        _finish_commit). None when the op stored inline (the serial
+        path, an exact batch behind its barrier) or wrote nothing."""
         tidy_runtime.assert_role("commit", "loop")
         d = self._deferred_store
         self._deferred_store = None
@@ -1749,11 +1749,28 @@ class StateMachine:
         linked chains, and pending post/void."""
         from tigerbeetle_tpu.ops import commit_exact
 
-        # Prefetch reads the id index/object log/posted groove, and the
-        # tail writes grooves inline: queued async store jobs must land
-        # first (the stage is then idle for the inline writes too).
-        self.store_barrier()
         n = len(events)
+        # Which exact batches wait for the store. A batch with a post/void
+        # event reads the id index, the object log and the posted groove
+        # in its prefetch and writes the posted groove in its tail; a
+        # batch on a history account writes history rows in its tail.
+        # Both need every queued async store job landed first (the stage
+        # is then idle for the inline writes too): they take the barrier
+        # and store inline. A batch with neither reads nothing from the
+        # store and writes nothing but its transfer rows, so it takes the
+        # fast path's discipline: no barrier, its OK rows deferred to
+        # _finish_commit (the store thread when the stage is attached).
+        hist_flag = np.uint32(AccountFlags.HISTORY)
+        dr_hist = np.zeros(n, dtype=bool)
+        cr_hist = np.zeros(n, dtype=bool)
+        dr_valid = dr_slots >= 0
+        cr_valid = cr_slots >= 0
+        dr_hist[dr_valid] = (self.acc_flags[dr_slots[dr_valid]] & hist_flag) != 0
+        cr_hist[cr_valid] = (self.acc_flags[cr_slots[cr_valid]] & hist_flag) != 0
+        has_pv = bool(np.any(is_pv))
+        defer = not (has_pv or dr_hist.any() or cr_hist.any())
+        if not defer:
+            self.store_barrier()
         with tracer.span("sm.ct.prefetch"):
             pv_code, pinfo_np, pending_recs, p_rec_idx = self._exact_prefetch(
                 events, is_pv, pv_keys
@@ -1812,7 +1829,7 @@ class StateMachine:
                     pinfo.dr_slot, pinfo.cr_slot, chain_id_p, pinfo.group,
                     int(self.state.ledger.shape[0]),
                 )
-            has_pv, has_chains = bool(np.any(is_pv)), bool(np.any(linked))
+            has_chains = bool(np.any(linked))
             if tracer.enabled():
                 # What the batch brings the kernel: chains of two or more
                 # events (by their heads) and how its 2n postings fall on
@@ -1873,6 +1890,7 @@ class StateMachine:
             return self._create_transfers_serial(events, timestamp)
         self.state = new_state
         self._count_route("exact_batches")
+        tracer.count("sm.exact.store_deferred", int(defer))
         with tracer.span("sm.ct.post"):
             codes = codes_h[:n]
             amounts = amounts_h[:n]
@@ -1918,7 +1936,12 @@ class StateMachine:
                         recs["user_data_32"][sel] == 0,
                         prec["user_data_32"], recs["user_data_32"][sel],
                     )
-                self._store_new_transfers(recs)
+                if defer:
+                    # Nothing below finds a row to write: no post/void, no
+                    # history account.
+                    self._defer_store(recs)
+                else:
+                    self._store_new_transfers(recs)
                 self.commit_timestamp = int(ts[ok][-1])
 
                 # Posted-groove updates (reference PostedGroove insert) —
@@ -1944,13 +1967,6 @@ class StateMachine:
                 # writes no history row (mirroring the oracle). Vectorized:
                 # limb→u64-pair conversions + key gathers, no per-row Python
                 # (VERDICT r3 weak #6 closed).
-                hist_flag = np.uint32(AccountFlags.HISTORY)
-                dr_hist = np.zeros(n, dtype=bool)
-                cr_hist = np.zeros(n, dtype=bool)
-                dr_valid = dr_slots >= 0
-                cr_valid = cr_slots >= 0
-                dr_hist[dr_valid] = (self.acc_flags[dr_slots[dr_valid]] & hist_flag) != 0
-                cr_hist[cr_valid] = (self.acc_flags[cr_slots[cr_valid]] & hist_flag) != 0
                 need = ok & (dr_hist | cr_hist) & ~is_pv
                 if np.any(need):
                     from tigerbeetle_tpu.lsm.groove import HISTORY_DTYPE
