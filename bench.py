@@ -719,44 +719,14 @@ def bench_config5_lsm():
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
 
-    # Device streaming-merge kernel in isolation (north-star part 2).
-    jax, jnp = _import_jax()
-
-    from tigerbeetle_tpu.ops.merge import merge_kernel_tiled
-
-    m = 1 << 17
-    rng = np.random.default_rng(6)
-    ka = np.sort(rng.integers(0, 1 << 31, m, dtype=np.int64)).astype(np.uint32)
-    kb = np.sort(rng.integers(0, 1 << 31, m, dtype=np.int64)).astype(np.uint32)
-    keys_a = np.zeros((m, 4), dtype=np.uint32)
-    keys_a[:, 0] = ka
-    keys_b = np.zeros((m, 4), dtype=np.uint32)
-    keys_b[:, 0] = kb
-    va = np.arange(m, dtype=np.uint32)
-    ja, jb = jnp.asarray(keys_a), jnp.asarray(keys_b)
-    jva = jnp.asarray(va)
-
-    # Timing note: block on the merged arrays themselves and keep the
-    # dispatch queue full with sequential calls.
-    ok, ov = merge_kernel_tiled(ja, jva, jb, jva)
-    np.asarray(ov)  # force warmup completion
-    reps = 8
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        ok, ov = merge_kernel_tiled(ja, jva, jb, jva)
-    jax.block_until_ready((ok, ov))
-    dt = (time.perf_counter() - t0) / reps
-    out["device_merge_tiled_rows_per_s"] = round(2 * m / dt, 1)
-
-    # Host k-way flush merge (ops/merge.merge_host_kway, the device
-    # query-index pipeline's CPU substrate): stable galloping merge of 8
-    # sorted runs vs the fused radix re-sort of their concatenation —
-    # both byte-identical by construction; recorded, not gated.
-    from tigerbeetle_tpu.lsm.store import KEY_DTYPE, sort_kv
-    from tigerbeetle_tpu.ops.merge import merge_host_kway
+    # Host k-way flush merge (lsm/store.merge_host_kway): stable galloping
+    # merge of 8 sorted runs vs the fused radix re-sort of their
+    # concatenation — byte-identical by construction; recorded, not gated.
+    from tigerbeetle_tpu.lsm.store import KEY_DTYPE, merge_host_kway, sort_kv
 
     runs = 8
     per = 1 << 15
+    rng = np.random.default_rng(6)
     parts_k, parts_v = [], []
     for r in range(runs):
         k = np.zeros(per, dtype=KEY_DTYPE)

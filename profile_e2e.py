@@ -185,13 +185,7 @@ def main(backend="numpy", batches=40, overlap=True, store_async=True,
 
     # Warmup: compile every kernel bucket outside the measured window.
     # The store stage is DRAINED before the compile baseline is snapped:
-    # its work trails the replies by up to a full queue, so a warmup
-    # flush's device fold (query-index pipeline) would otherwise compile
-    # asynchronously inside the measured window and fail the retrace
-    # assert on timing, not substance. Covering the fold shapes at all
-    # requires warmup to span a flush cycle (index_memtable_rows /
-    # (5·BATCH) ≈ 4 batches on the production config — pass warmup=8
-    # for device-merge runs).
+    # its work trails the replies by up to a full queue.
     n_warm = len(bus.replies)
     for m in msgs[:warmup]:
         replica.on_message(m)
@@ -364,14 +358,13 @@ def main(backend="numpy", batches=40, overlap=True, store_async=True,
     if not overlap:
         assert attributed <= total_ms * 1.05, (attributed, total_ms)
 
-    # Query-index pipeline decomposition: the sub-spans NEST inside the
-    # store.query row (host fallback) or ride the flush (device path), so
-    # they are reported as their own table and never added to the
-    # disjoint stage attribution above. `keys` is the per-commit key
-    # build (numpy block, or the fused device kernel's staging+dispatch);
-    # `sort`/`merge`/`build` are the flush phases (host radix vs k-way /
-    # device fold, then the grid table build); `prefetch` is the store
-    # worker's idle device→host pulls.
+    # Query-index decomposition: the sub-spans NEST inside the
+    # store.query row, so they are reported as their own table and never
+    # added to the disjoint stage attribution above. `keys` is the
+    # per-commit key build (the numpy block); `sort`/`merge`/`build` are
+    # the flush phases (radix vs k-way merge, then the grid table
+    # build); `prefetch` is the store worker's idle compaction
+    # read-ahead.
     query_rows = {
         "query.keys": ("sm.store.query.keys",),
         "query.sort": ("lsm.query_rows.flush.sort",),
@@ -380,8 +373,7 @@ def main(backend="numpy", batches=40, overlap=True, store_async=True,
         "query.prefetch": ("pipeline.store.prefetch",),
     }
     if any(span_ms(keys) for keys in query_rows.values()):
-        print("\nquery-index pipeline (inside store.query + flush; host or "
-              "device variant):")
+        print("\nquery index (inside store.query + flush):")
         print(f"  {'span':14s} {'ms/batch':>9s} {'p50_us':>9s} {'p99_us':>9s}")
         for stage, keys in query_rows.items():
             ms = span_ms(keys)

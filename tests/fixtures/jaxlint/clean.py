@@ -9,7 +9,7 @@ U32 = jnp.uint32
 
 
 @jax.jit
-def merge_kernel(x):  # tidy: range=x:0..0xFFFF — u16 payloads by contract
+def create_transfers_fast(x):  # tidy: range=x:0..0xFFFF — u16 payloads by contract
     bumped = jnp.where(x[0] > 0, x + 1, x)  # branchless select, no sync
     total = bumped.sum()  # stays on device
     return bumped, total
@@ -25,11 +25,11 @@ def pad_batch(events):
 
 def feed(events):
     padded = pad_batch(events)  # bucket-padded: compiles once per bucket
-    return merge_kernel(padded)
+    return create_transfers_fast(padded)
 
 
 def finish(handle):  # tidy: range=handle:0..0xFFFF — same u16 contract as the kernel
-    codes = merge_kernel(handle)
+    codes = create_transfers_fast(handle)
     # tidy: allow=host-sync — fixture seam: this IS the sanctioned finish point
     return np.asarray(codes)
 

@@ -18,11 +18,11 @@ def bad_kernel(x):
 
 
 def bad_dispatch(events):
-    out = merge_kernel(events)
+    out = create_transfers_fast(events)
     out.block_until_ready()  # unfenced-sync: outside the sanctioned seam
     return out
 
 
 def bad_materialize(events):
-    codes = merge_kernel(events)
+    codes = create_transfers_fast(events)
     return bool(codes)  # host-sync: device handle materialized off-seam

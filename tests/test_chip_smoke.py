@@ -5,9 +5,8 @@ is half of what the driver checks. The other half, that its phases still
 run end to end, is rehearsed here at a tiny size: this file, run as a
 script, calls chip_smoke.one_chip()/three_replicas() with the platform
 the rehearsal expects (the steering lives here, in the test; the script
-has no switch for it) and with the two existing route overrides that put
-an XLA-CPU server on the chip's side of every route — the device
-query-index build, the device run merge and a depth-4 commit window.
+has no switch for it) and with the one existing override that puts an
+XLA-CPU server on the chip's side of its route: a depth-4 commit window.
 
 Each rehearsal runs in a child process: chip_smoke's watchdog ends a
 failed run with os._exit, and it retunes the clients' class-wide
@@ -28,7 +27,6 @@ REPO = pathlib.Path(__file__).resolve().parents[1]
 # The CPU stands in for the chip: take the routes the chip takes.
 ROUTES_AS_ON_CHIP = {
     "JAX_PLATFORMS": "cpu",
-    "TIGERBEETLE_TPU_DEVICE_MERGE": "1",
     "TIGERBEETLE_TPU_COMMIT_DEPTH": "4",
 }
 

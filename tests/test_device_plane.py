@@ -100,26 +100,20 @@ def _transfer_batch(ids, amount=5):
 
 
 class TestDeviceMemLedger:
-    def test_set_adjust_release_and_high_water(self, clean_tracer):
+    def test_set_retire_and_high_water(self, clean_tracer):
         tracer.device_mem_set("balances", 1000)
-        tracer.device_mem_adjust("query_runs", 500)
+        tracer.device_mem_set("scratch.b512", 500)
         t = tracer.device_mem_totals()
-        assert t["owners"] == {"balances": 1000, "query_runs": 500}
+        assert t["owners"] == {"balances": 1000, "scratch.b512": 500}
         assert t["total_bytes"] == 1500 and t["high_water_bytes"] == 1500
-        # Release drops the owner AND its gauge; high-water persists.
-        tracer.device_mem_adjust("query_runs", -500)
-        tracer.device_mem_release("query_runs")
+        # Retiring drops the owner AND its gauge; high-water persists.
+        tracer.device_mem_retire_prefix("scratch.b512")
         t = tracer.device_mem_totals()
-        assert "query_runs" not in t["owners"]
+        assert "scratch.b512" not in t["owners"]
         assert t["total_bytes"] == 1000 and t["high_water_bytes"] == 1500
         g = tracer.gauges()
         assert g["device.mem.balances.bytes"] == 1000.0
-        assert "device.mem.query_runs.bytes" not in g
-
-    def test_adjust_clamps_at_zero(self, clean_tracer):
-        tracer.device_mem_adjust("query_runs", 100)
-        tracer.device_mem_adjust("query_runs", -500)
-        assert tracer.device_mem_totals()["owners"]["query_runs"] == 0
+        assert "device.mem.scratch.b512.bytes" not in g
 
     def test_retire_prefix_drops_owner_family(self, clean_tracer):
         tracer.device_mem_set("scratch.b256", 10)

@@ -414,12 +414,12 @@ def test_unfed_is_the_time_with_no_window_open(traced, case):
     first = tracer.device_dispatch(ENTRY)
     assert _unfed() == (0, 0.0)  # before the first window nothing was "unfed"
     if case == "overlapping":
-        second = tracer.device_dispatch("merge_kernel_tiled")  # another entry, same count
+        second = tracer.device_dispatch("create_transfers_exact")  # another entry, same count
         tracer.device_finish(ENTRY, first)
         time.sleep(GAP)  # `second` is still open: fed
         third = tracer.device_dispatch(ENTRY)
         assert _unfed() == (0, 0.0)
-        tracer.device_finish("merge_kernel_tiled", second)
+        tracer.device_finish("create_transfers_exact", second)
         tracer.device_finish(ENTRY, third)
     elif case == "nested":
         with tracer.device_step("read_balances"):  # a blocking entry inside a window

@@ -111,11 +111,11 @@ def test_retrace_fixture_exact_findings():
     )
     got = [(f.code, f.scope, f.subject) for f in findings]
     assert got == [
-        ("retrace-shape", "feed", "merge_kernel"),
-        ("retrace-shape", "feed", "merge_kernel"),
-        ("retrace-static-arg", "feed", "merge_kernel_tiled.tile"),
-        ("retrace-kwargs", "feed", "merge_kernel"),
-        ("retrace-shape", "feed_named", "merge_kernel"),
+        ("retrace-shape", "feed", "create_transfers_fast"),
+        ("retrace-shape", "feed", "create_transfers_fast"),
+        ("retrace-static-arg", "feed", "create_transfers_exact.max_sweeps"),
+        ("retrace-kwargs", "feed", "create_transfers_fast"),
+        ("retrace-shape", "feed_named", "create_transfers_fast"),
     ], findings
     # The named-temporary finding anchors at the CONSTRUCTION line (where
     # the padding fix — or a precise allow= — belongs), not the call.
@@ -125,29 +125,30 @@ def test_retrace_fixture_exact_findings():
     assert "np.zeros" in src[named.line - 1]
 
 
-def test_merge_entry_is_compile_gated():
-    """The device run merge is a registered jit entry: runtime-sized runs
-    reaching it are flagged (a retrace per run length, i.e. a fresh XLA
-    compile inside a beat on the store thread), while the sanctioned
-    _pad_pow2 pad helper's pow-2 buckets pass clean."""
+def test_commit_entry_is_compile_gated():
+    """The commit kernel is a registered jit entry: batch-sized arrays
+    reaching it are flagged (a retrace per batch length, i.e. a fresh XLA
+    compile inside a request on the commit thread), while a sanctioned
+    pad helper's pow-2 buckets pass clean."""
     from tigerbeetle_tpu.tidy import jaxlint, manifest
 
     # The real kernel + its gate are registered, not just the fixture's.
-    assert "merge_kernel_tiled" in manifest.JIT_ENTRIES
-    assert {"_pad_pow2", "to_device_run"} <= manifest.JAXLINT_PAD_HELPERS
+    assert "create_transfers_fast" in manifest.JIT_ENTRIES
+    assert {"_device_batch", "_pad_slots"} <= manifest.JAXLINT_PAD_HELPERS
     assert (
-        "tigerbeetle_tpu/ops/qindex.py", "materialize_fold"
+        "tigerbeetle_tpu/models/state_machine.py",
+        "StateMachine.create_transfers_finish",
     ) in manifest.JAXLINT_SYNC_SEAM
 
     findings = jaxlint.analyze_file(
-        FIXTURES / "retrace_merge_runs.py", REPO, passes=("retrace",)
+        FIXTURES / "retrace_commit_batch.py", REPO, passes=("retrace",)
     )
     got = [(f.code, f.scope, f.subject) for f in findings]
     assert got == [
-        ("retrace-shape", "merge_ungated", "merge_kernel_tiled"),
+        ("retrace-shape", "commit_ungated", "create_transfers_fast"),
     ] * 4, findings
-    # No finding in merge_gated: _pad_pow2's result is shape-stabilized.
-    assert all(f.scope != "merge_gated" for f in findings)
+    # No finding in commit_gated: _pad_slots' result is shape-stabilized.
+    assert all(f.scope != "commit_gated" for f in findings)
 
 
 # --- reduction pass ------------------------------------------------------
@@ -271,8 +272,7 @@ class TestCompileRegistry:
         # The repo's module-level jit entries all expose cache sizes.
         for name in ("create_transfers_fast", "register_accounts",
                      "write_balances", "read_balances",
-                     "create_transfers_exact", "merge_kernel",
-                     "merge_kernel_tiled"):
+                     "create_transfers_exact"):
             assert name in counts, counts
 
 

@@ -1,10 +1,10 @@
-"""LSM tier tests: grid/free set/EWAH, device-vs-host merge byte equality,
-durable tables + compaction, bounded-memory ingest, restart durability.
+"""LSM tier tests: grid/free set/EWAH, the host merge against a plain
+stable-sort statement, durable tables + compaction, bounded-memory
+ingest, restart durability.
 
 Reference strategy: per-component randomized tests against a model
 (fuzz_tests.zig registry: lsm_tree, vsr_free_set, ewah), plus the storage-
-determinism discipline (byte-identical device/host merges — the north-star
-acceptance bar for the compaction kernel).
+determinism discipline (the same inserts leave the same grid bytes).
 """
 
 import os
@@ -19,9 +19,8 @@ from tigerbeetle_tpu.io import ewah
 from tigerbeetle_tpu.io.grid import FreeSet, Grid, MemGrid
 from tigerbeetle_tpu.io.storage import FileStorage, MemStorage
 from tigerbeetle_tpu.lsm.log import DurableLog
-from tigerbeetle_tpu.lsm.store import NOT_FOUND, pack_keys
+from tigerbeetle_tpu.lsm.store import NOT_FOUND, merge_host_kway, pack_keys
 from tigerbeetle_tpu.lsm.tree import DurableIndex
-from tigerbeetle_tpu.ops import merge as merge_ops
 
 
 class TestEwah:
@@ -71,9 +70,27 @@ class TestFreeSet:
             g.read_block(b)
 
 
-class TestMergeKernel:
+def stable_merge_oracle(keys_a, vals_a, keys_b, vals_b):
+    """Two lo-major runs merged as a plain statement of the contract:
+    Python's stable sort of (lo, run, position) — A before B at equal lo,
+    each run's own order kept."""
+    rows = [(int(k["lo"]), 0, i) for i, k in enumerate(keys_a)]
+    rows += [(int(k["lo"]), 1, i) for i, k in enumerate(keys_b)]
+    rows.sort(key=lambda r: r[0])
+    keys = np.array(
+        [(keys_a, keys_b)[run][i] for _lo, run, i in rows], dtype=keys_a.dtype
+    )
+    vals = np.array(
+        [(vals_a, vals_b)[run][i] for _lo, run, i in rows], dtype=np.uint32
+    )
+    return keys, vals
+
+
+class TestTwoRunMerge:
+    """merge_host_kway on two runs, the memtable flush's smallest merge."""
+
     @pytest.mark.parametrize("seed", range(5))
-    def test_device_host_byte_equality(self, seed):
+    def test_matches_stable_merge_oracle(self, seed):
         from tigerbeetle_tpu.lsm.store import sort_lo_major
 
         rng = np.random.default_rng(seed)
@@ -87,25 +104,23 @@ class TestMergeKernel:
         va = rng.integers(0, 1 << 31, n).astype(np.uint32)
         vb = rng.integers(0, 1 << 31, m).astype(np.uint32)
 
-        hk, hv = merge_ops.merge_host(a_keys, va, b_keys, vb)
-        dk, dv = merge_ops.merge_device(a_keys, va, b_keys, vb)
-        assert hk.tobytes() == dk.tobytes()
-        assert hv.tobytes() == dv.tobytes()
+        hk, hv = merge_host_kway([a_keys, b_keys], [va, vb])
+        ok, ov = stable_merge_oracle(a_keys, va, b_keys, vb)
+        assert hk.tobytes() == ok.tobytes()
+        assert hv.tobytes() == ov.tobytes()
 
-    def test_lo_max_keys_not_confused_with_padding(self):
-        # A real key whose lo is all-ones must survive the padded device
-        # merge (the pad flag, not a sentinel key value, marks padding).
+    def test_lo_max_key_sorts_last_and_survives(self):
+        # A real key whose lo is all-ones is a key like any other.
         lo_max = np.uint64(0xFFFFFFFFFFFFFFFF)
         ka = pack_keys(np.array([5, lo_max], dtype=np.uint64),
                        np.array([0, 3], dtype=np.uint64))
         kb = pack_keys(np.array([7], dtype=np.uint64), np.array([0], dtype=np.uint64))
         va = np.array([1, 2], dtype=np.uint32)
         vb = np.array([10], dtype=np.uint32)
-        hk, hv = merge_ops.merge_host(ka, va, kb, vb)
-        dk, dv = merge_ops.merge_device(ka, va, kb, vb)
-        assert hk.tobytes() == dk.tobytes()
+        hk, hv = merge_host_kway([ka, kb], [va, vb])
         assert list(hv) == [1, 10, 2]
-        assert list(dv) == [1, 10, 2]
+        assert [int(x) for x in hk["lo"]] == [5, 7, int(lo_max)]
+        assert int(hk["hi"][2]) == 3
 
     def test_stability_duplicates_across_runs(self):
         # Equal keys: A-side (older) values must precede B-side values.
@@ -113,17 +128,15 @@ class TestMergeKernel:
         kb = pack_keys(np.array([5, 9, 9], dtype=np.uint64), np.zeros(3, dtype=np.uint64))
         va = np.array([1, 2, 3], dtype=np.uint32)
         vb = np.array([10, 20, 30], dtype=np.uint32)
-        hk, hv = merge_ops.merge_host(ka, va, kb, vb)
+        _hk, hv = merge_host_kway([ka, kb], [va, vb])
         assert list(hv) == [1, 2, 10, 3, 20, 30]
-        dk, dv = merge_ops.merge_device(ka, va, kb, vb)
-        assert list(dv) == [1, 2, 10, 3, 20, 30]
 
 
 class TestDurableIndex:
-    def _rand_index(self, backend="numpy", n=30_000, seed=7):
+    def _rand_index(self, n=30_000, seed=7):
         rng = np.random.default_rng(seed)
         grid = MemGrid(block_count=8192, block_size=4096)
-        idx = DurableIndex(grid, unique=True, memtable_max=512, growth=4, backend=backend)
+        idx = DurableIndex(grid, unique=True, memtable_max=512, growth=4)
         lo = rng.permutation(np.arange(1, n + 1, dtype=np.uint64))
         hi = rng.integers(0, 1 << 32, n).astype(np.uint64)
         vals = np.arange(n, dtype=np.uint32)
@@ -150,12 +163,11 @@ class TestDurableIndex:
         assert (idx2.lookup_batch(q) == vals[::17]).all()
         assert idx2.count == idx.count
 
-    def test_jax_and_numpy_backend_compaction_same_tables(self):
-        """The north-star bar: a jax-backend tree's compaction leaves
-        byte-identical table contents to the numpy backend's (both merge
-        their runs with the host C k-way merge)."""
-        _, idx_h, lo, hi, vals = self._rand_index(backend="numpy")
-        _, idx_d, _, _, _ = self._rand_index(backend="jax")
+    def test_compaction_same_tables_every_time(self):
+        """The same inserts compacted twice leave byte-identical table
+        contents: no merge depends on anything but its runs."""
+        _, idx_h, lo, hi, vals = self._rand_index()
+        _, idx_d, _, _, _ = self._rand_index()
 
         def dump(idx):
             parts = []
@@ -168,13 +180,13 @@ class TestDurableIndex:
 
         assert dump(idx_h) == dump(idx_d)
 
-    def _paced_storm(self, backend, step=None, quota=2048, drained=False):
+    def _paced_storm(self, step=None, quota=2048, drained=False):
         """A forced all-level major compaction in beats of `quota` entries;
         `step(idx, beat)` replaces the plain compact_step where given.
         `drained`: the level jobs run first, so the storm folds two long
         tables (and reads the grid inside its steps) instead of many
         short ones that fit its read-ahead."""
-        grid, idx, lo, hi, vals = self._rand_index(backend=backend)
+        grid, idx, lo, hi, vals = self._rand_index()
         if drained:
             idx.drain_compaction()
         assert idx.request_major() > 0
@@ -189,13 +201,12 @@ class TestDurableIndex:
         assert beats > 1  # actually incremental, not one mega-step
         return grid, idx, lo, hi, vals
 
-    def test_storm_jax_and_numpy_backends_identical(self):
+    def test_storm_same_grid_bytes_every_time(self):
         """Determinism guard for the streaming storm engine: a forced
-        all-level major compaction on the jax backend leaves
-        byte-identical state — manifest, fences, and raw grid bytes —
-        to the numpy backend."""
-        grid_h, idx_h, lo, hi, vals = self._paced_storm("numpy")
-        grid_d, idx_d, _, _, _ = self._paced_storm("jax")
+        all-level major compaction run twice over the same inserts leaves
+        byte-identical state — manifest, fences, and raw grid bytes."""
+        grid_h, idx_h, lo, hi, vals = self._paced_storm()
+        grid_d, idx_d, _, _, _ = self._paced_storm()
         assert idx_h.checkpoint().tobytes() == idx_d.checkpoint().tobytes()
         fh, ch = idx_h.checkpoint_fences()
         fd, cd = idx_d.checkpoint_fences()
@@ -208,24 +219,21 @@ class TestDurableIndex:
         assert (idx_d.lookup_batch(q) == vals[::13]).all()
 
     @pytest.mark.parametrize("shape", ["level_jobs", "paced_storm"])
-    def test_jax_backend_compaction_stays_off_the_device(self, shape, monkeypatch):
+    def test_compaction_stays_off_the_device(self, shape):
         """Compaction's runs come off the grid on the host and go back
-        to it on the host: even where the device merges pay
-        (TIGERBEETLE_TPU_DEVICE_MERGE=1 stands in for an accelerator
-        backend), level jobs and a paced storm on the jax backend ship
-        no byte either way and enter no device step."""
+        to it on the host: level jobs and a paced storm ship no byte
+        either way and enter no device step."""
         from tigerbeetle_tpu import tracer
 
-        monkeypatch.setenv("TIGERBEETLE_TPU_DEVICE_MERGE", "1")
         was = tracer.enabled()
         tracer.enable()
         tracer.reset()
         try:
             if shape == "level_jobs":
-                _, idx, *_ = self._rand_index(backend="jax")
+                _, idx, *_ = self._rand_index()
                 idx.drain_compaction()
             else:
-                _, idx, *_ = self._paced_storm("jax")
+                _, idx, *_ = self._paced_storm()
             snap = tracer.snapshot()
         finally:
             tracer.reset()
@@ -237,8 +245,8 @@ class TestDurableIndex:
         assert not [e for e in snap if e.startswith("device.step.")]
 
     def test_grid_read_fault_mid_step_retries_to_same_grid_bytes(self):
-        """A corrupt input block in the middle of a storm step on the jax
-        backend: the step's partial merges are dropped, the retried job
+        """A corrupt input block in the middle of a storm step: the
+        step's partial merges are dropped, the retried job
         re-merges from its owed position into the same reserved blocks,
         and the grid ends byte-identical to a run that never faulted."""
         from tigerbeetle_tpu.io.grid import GridReadFault
@@ -268,9 +276,8 @@ class TestDurableIndex:
                 del idx.grid.read_block
 
         grid_a, idx_a, lo, hi, vals = self._paced_storm(
-            "jax", quota=8192, drained=True)
-        grid_b, idx_b, _, _, _ = self._paced_storm(
-            "jax", step=step, drained=True)
+            quota=8192, drained=True)
+        grid_b, idx_b, _, _, _ = self._paced_storm(step=step, drained=True)
         assert faults == [1]
         assert idx_a.checkpoint().tobytes() == idx_b.checkpoint().tobytes()
         span = grid_a.block_count * grid_a.block_size
@@ -748,8 +755,8 @@ class TestWideKwayMerge:
     @pytest.mark.parametrize("k", [2, 9, 65])
     @pytest.mark.parametrize("crosses_table", [False, True])
     def test_compaction_combine_is_sort_kv_with_posthoc_blooms(self, k, crosses_table):
-        """_CompactionJob._combine, the one route a compaction chunk takes
-        on every backend: its rows are sort_kv's over the runs'
+        """_CompactionJob._combine, the one route a compaction chunk
+        takes: its rows are sort_kv's over the runs'
         concatenation (65 runs: past the shim's 64-run bound, so a
         pre-fold), and the output tables' Blooms are what a pass of
         _bloom_fill over the finished rows sets — also for a chunk that

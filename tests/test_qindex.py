@@ -1,44 +1,48 @@
-"""Device query-index pipeline tests (ops/qindex.py + the lsm/tree
-device-run tier): byte-equality of the fused fold56 key build against the
-host numpy block (including xor-fold edge cases at the 2^56 boundaries
-and full u128 inputs), flush/merge parity between lazy device runs and
-the host radix path, the k-way host merge oracle, the tiled-kernel
-guarantee for sub-tile runs, and the cluster-level determinism guard
-(host vs device query path: identical hash_log chains + trailer
-digests)."""
+"""Query-index tests: the key block `StateMachine._store_query_index`
+builds against a per-record statement of it in Python ints (random
+records and the fold56 boundaries), what the memtable flush makes of
+sorted and unsorted batches (same table bytes, same flush cadence), and
+the host k-way merge against the stable radix sort."""
 
 import numpy as np
 import pytest
 
 from tigerbeetle_tpu import types
+from tigerbeetle_tpu.constants import TEST_MIN
 from tigerbeetle_tpu.io.grid import MemGrid
 from tigerbeetle_tpu.lsm import scan
-from tigerbeetle_tpu.lsm.store import KEY_DTYPE, sort_kv
+from tigerbeetle_tpu.lsm.store import KEY_DTYPE, merge_host_kway, sort_kv
 from tigerbeetle_tpu.lsm.tree import DurableIndex
-from tigerbeetle_tpu.ops import merge as merge_ops
-from tigerbeetle_tpu.ops import qindex
+
+MASK56 = (1 << 56) - 1
 
 
-def host_query_keys(recs, rows):
-    """The host oracle: StateMachine._store_query_index's numpy block."""
-    tstamp = recs["timestamp"]
-    tags = (
-        (scan.TAG_UD128, scan.fold56(
-            recs["user_data_128_lo"], recs["user_data_128_hi"]
-        )),
-        (scan.TAG_UD64, scan.fold56(recs["user_data_64"])),
-        (scan.TAG_UD32, scan.fold56(recs["user_data_32"])),
-        (scan.TAG_LEDGER, scan.fold56(recs["ledger"])),
-        (scan.TAG_CODE, scan.fold56(recs["code"])),
-    )
-    n = len(recs)
-    keys = np.empty(len(tags) * n, dtype=scan.KEY_DTYPE)
-    for i, (tag, folded) in enumerate(tags):
-        keys["lo"][i * n : (i + 1) * n] = (
-            np.uint64(tag) << np.uint64(56)
-        ) | folded
-        keys["hi"][i * n : (i + 1) * n] = tstamp
-    return keys, np.tile(rows, len(tags))
+def fold56_int(lo: int, hi: int = 0) -> int:
+    """fold56 as arithmetic on Python ints: the identity below 2^56, the
+    bits above folded back in; a u128's hi word shifted by one."""
+    out = (lo & MASK56) ^ (lo >> 56)
+    out ^= (((hi & MASK56) << 1) & MASK56) ^ (hi >> 55)
+    return out & MASK56
+
+
+def stated_query_keys(recs, rows, tstamp):
+    """The statement: five blocks in tag order, and in each one entry a
+    record, key.lo = tag << 56 | fold56(field), key.hi = the timestamp,
+    value = the record's object-log row."""
+    lo, hi, vals = [], [], []
+    for tag, f_lo, f_hi in (
+        (5, "user_data_128_lo", "user_data_128_hi"), (6, "user_data_64", None),
+        (7, "user_data_32", None), (9, "ledger", None), (10, "code", None),
+    ):
+        for i in range(len(recs)):
+            field_hi = int(recs[f_hi][i]) if f_hi else 0
+            lo.append(tag << 56 | fold56_int(int(recs[f_lo][i]), field_hi))
+            hi.append(int(tstamp[i]))
+            vals.append(int(rows[i]))
+    keys = np.zeros(len(lo), dtype=KEY_DTYPE)
+    keys["lo"] = np.array(lo, dtype=np.uint64)
+    keys["hi"] = np.array(hi, dtype=np.uint64)
+    return keys, np.array(vals, dtype=np.uint32)
 
 
 def rand_recs(rng, n, constant=False):
@@ -57,32 +61,57 @@ def rand_recs(rng, n, constant=False):
     return recs
 
 
-class TestFusedKeyBuild:
-    """Property tests: the fused device kernel's key block must be
-    byte-identical to the host fold56 build, both variants."""
+class _Inserts:
+    """Stands where the query tree does and keeps what it is handed."""
+
+    def __init__(self):
+        self.calls = []
+
+    def insert_sorted(self, keys, vals):
+        self.calls.append(("sorted", keys, vals))
+
+    def insert_unsorted(self, keys, vals):
+        self.calls.append(("unsorted", keys, vals))
+
+
+@pytest.fixture(scope="module")
+def sm():
+    from tigerbeetle_tpu.models.state_machine import StateMachine
+
+    return StateMachine(TEST_MIN, backend="numpy")
+
+
+def built_by_state_machine(sm, recs, rows, ts=None):
+    real, sm.query_rows = sm.query_rows, _Inserts()
+    try:
+        sm._store_query_index(recs, rows, ts)
+        (call,) = sm.query_rows.calls
+    finally:
+        sm.query_rows = real
+    return call
+
+
+class TestKeyBlock:
+    """`_store_query_index`'s numpy block is the statement, byte for byte."""
 
     @pytest.mark.parametrize("seed", [1, 2, 3])
     @pytest.mark.parametrize("n", [1, 255, 1000])
-    def test_random_records_byte_identical(self, seed, n, monkeypatch):
+    def test_random_records_match_the_statement(self, sm, seed, n):
         rng = np.random.default_rng(seed)
         recs = rand_recs(rng, n)
         rows = rng.integers(0, 1 << 32, n).astype(np.uint32)
-        hk, hv = host_query_keys(recs, rows)
-        for force in ("0", "1"):
-            monkeypatch.setenv("TIGERBEETLE_TPU_DEVICE_MERGE", force)
-            run = qindex.build_run(recs, rows, recs["timestamp"])
-            dk, dv = run.materialize()
-            if force == "1":
-                # Device-sorted variant: compare against the stable host
-                # radix of the same block.
-                hk2, hv2 = sort_kv(hk, hv)
-            else:
-                hk2, hv2 = hk, hv
-            assert dk.tobytes() == hk2.tobytes()
-            assert np.array_equal(dv, hv2)
-            assert run.n == 5 * n
+        how, keys, vals = built_by_state_machine(sm, recs, rows)
+        want_k, want_v = stated_query_keys(recs, rows, recs["timestamp"])
+        assert keys.tobytes() == want_k.tobytes()
+        assert vals.dtype == np.uint32 and np.array_equal(vals, want_v)
+        # Handed over as a sorted run only when the blocks ARE one.
+        assert how == ("sorted" if n == 1 else "unsorted")
+        # The commit's own timestamps, where the caller passes them.
+        ts = rng.integers(1, 1 << 63, n, dtype=np.uint64)
+        _how, keys, _vals = built_by_state_machine(sm, recs, rows, ts)
+        assert keys.tobytes() == stated_query_keys(recs, rows, ts)[0].tobytes()
 
-    def test_fold56_boundary_values(self):
+    def test_fold56_boundary_values(self, sm):
         """xor-fold edge cases: values straddling 2^56 in every queryable
         field, u128 hi words at the 55/56-bit fold boundaries."""
         edges = np.array(
@@ -105,33 +134,13 @@ class TestFusedKeyBuild:
         recs["code"] = np.uint16((1 << 16) - 1)
         recs["timestamp"] = np.arange(1, n + 1, dtype=np.uint64)
         rows = np.arange(n, dtype=np.uint32)
-        hk, hv = host_query_keys(recs, rows)
-        run = qindex.build_run(recs, rows, recs["timestamp"])
-        dk, dv = run.materialize()
-        if run._device_sorted:
-            hk, hv = sort_kv(hk, hv)
-        assert dk.tobytes() == hk.tobytes()
-        assert np.array_equal(dv, hv)
-
-    def test_materialize_idempotent_and_threadsafe(self):
-        import threading
-
-        rng = np.random.default_rng(9)
-        recs = rand_recs(rng, 300)
-        rows = np.arange(300, dtype=np.uint32)
-        run = qindex.build_run(recs, rows, recs["timestamp"])
-        got = []
-        threads = [
-            threading.Thread(target=lambda: got.append(run.materialize()))
-            for _ in range(4)
-        ]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        # Every caller gets the SAME cached tuple (one materialization).
-        assert all(g is got[0] for g in got)
-        assert run.materialized
+        _how, keys, vals = built_by_state_machine(sm, recs, rows)
+        want_k, want_v = stated_query_keys(recs, rows, recs["timestamp"])
+        assert keys.tobytes() == want_k.tobytes()
+        assert np.array_equal(vals, want_v)
+        # What a query scans for is what the insert side stored.
+        assert int(keys["lo"][n + 3]) == scan.prefix(scan.TAG_UD64, 1 << 56)
+        assert fold56_int(1 << 56) == 1 and fold56_int((1 << 56) - 1) == MASK56
 
 
 def table_bytes(idx):
@@ -145,179 +154,69 @@ def table_bytes(idx):
     return b"".join(out)
 
 
-class TestDeviceRunTier:
-    """Lazy device runs through DurableIndex: flush cadence, table bytes,
-    and reads must match the host insert_unsorted path exactly."""
+def query_tree(memtable_max=1 << 30):
+    return DurableIndex(
+        MemGrid(block_count=8192, block_size=4096), unique=False,
+        memtable_max=memtable_max, merge_hint="dups",
+    )
 
-    def _drive_pair(self, force, batches=6, n=400, memtable_max=None):
+
+class TestMemtableFlush:
+    """A batch handed over as a sorted run (constant columns) and the same
+    batch handed over unsorted must leave the same tables, flushed at the
+    same batch boundaries: grid allocation order is checkpoint bytes."""
+
+    def _drive_pair(self, batches=6, n=400, memtable_max=None):
+        """`flagged` takes each batch as `_store_query_index` would hand
+        it over (every other one constant, so sorted); `plain` takes all
+        of them unsorted."""
         rng = np.random.default_rng(11)
-        host = DurableIndex(
-            MemGrid(block_count=8192, block_size=4096), unique=False,
-            memtable_max=memtable_max or 5 * n * batches // 2,
-            backend="numpy", merge_hint="dups",
-        )
-        dev = DurableIndex(
-            MemGrid(block_count=8192, block_size=4096), unique=False,
-            memtable_max=memtable_max or 5 * n * batches // 2,
-            backend="jax", merge_hint="dups",
-        )
+        memtable_max = memtable_max or 5 * n * batches // 2
+        flagged, plain = query_tree(memtable_max), query_tree(memtable_max)
         row0 = 0
         for b in range(batches):
             recs = rand_recs(rng, n, constant=(b % 2 == 0))
             rows = np.arange(row0, row0 + n, dtype=np.uint32)
             row0 += n
-            k, v = host_query_keys(recs, rows)
-            host.insert_unsorted(k, v)
-            dev.insert_run_lazy(
-                qindex.build_run(recs, rows, recs["timestamp"])
-            )
-        host.flush_memtable()
-        dev.flush_memtable()
-        return host, dev
+            k, v = stated_query_keys(recs, rows, recs["timestamp"])
+            if scan.query_columns_constant(recs):
+                flagged.insert_sorted(k, v)
+            else:
+                flagged.insert_unsorted(k, v)
+            plain.insert_unsorted(k.copy(), v.copy())
+        flagged.flush_memtable()
+        plain.flush_memtable()
+        return flagged, plain
 
-    @pytest.mark.parametrize("force", ["0", "1"])
-    def test_flush_tables_byte_identical(self, force, monkeypatch):
-        monkeypatch.setenv("TIGERBEETLE_TPU_DEVICE_MERGE", force)
-        host, dev = self._drive_pair(force)
-        assert table_bytes(host) == table_bytes(dev)
-        assert host.count == dev.count
+    def test_flush_tables_byte_identical(self):
+        flagged, plain = self._drive_pair()
+        assert table_bytes(flagged) == table_bytes(plain)
+        assert flagged.count == plain.count
 
-    def test_mid_run_flush_same_cadence(self, monkeypatch):
-        """memtable_max trips inside insert: the lazy path must flush at
-        the same batch boundaries (grid allocation order is checkpoint
-        bytes)."""
-        monkeypatch.setenv("TIGERBEETLE_TPU_DEVICE_MERGE", "1")
-        host, dev = self._drive_pair("1", batches=10, n=137,
-                                     memtable_max=137 * 5 * 3)
-        assert len(host.levels[0]) == len(dev.levels[0]) > 1
-        assert table_bytes(host) == table_bytes(dev)
-
-    def test_prefetch_pulls_transfers_without_changing_bytes(self, monkeypatch):
-        # Host-fallback lazy runs (device merge does NOT pay): the idle
-        # poll pulls each run's d2h transfer forward, one per call.
-        monkeypatch.setenv("TIGERBEETLE_TPU_DEVICE_MERGE", "0")
-        rng = np.random.default_rng(4)
-        dev = DurableIndex(
-            MemGrid(block_count=8192, block_size=4096), unique=False,
-            memtable_max=1 << 30, backend="jax", merge_hint="dups",
-        )
-        host = DurableIndex(
-            MemGrid(block_count=8192, block_size=4096), unique=False,
-            memtable_max=1 << 30, backend="numpy", merge_hint="dups",
-        )
-        for b in range(4):
-            recs = rand_recs(rng, 200)
-            rows = np.arange(b * 200, (b + 1) * 200, dtype=np.uint32)
-            k, v = host_query_keys(recs, rows)
-            host.insert_unsorted(k, v)
-            dev.insert_run_lazy(qindex.build_run(recs, rows, recs["timestamp"]))
-        # Idle-poll protocol: True while more remain, then False forever.
-        polls = 0
-        while dev.prefetch_lazy_one():
-            polls += 1
-        assert polls == 3  # 4 runs: True x3, then the last poll drains
-        assert not dev.prefetch_lazy_one()
-        assert all(m.materialized for m in dev._mem if not isinstance(m, tuple))
-        host.flush_memtable()
-        dev.flush_memtable()
-        assert table_bytes(host) == table_bytes(dev)
-
-    def test_prefetch_noop_when_device_merge_pays(self, monkeypatch):
-        """Device-fold mode keeps runs resident: the idle poll must not
-        steal them to the host (the fold's shapes — and the compile
-        gate — would become timing-dependent)."""
-        monkeypatch.setenv("TIGERBEETLE_TPU_DEVICE_MERGE", "1")
-        rng = np.random.default_rng(5)
-        dev = DurableIndex(
-            MemGrid(block_count=8192, block_size=4096), unique=False,
-            memtable_max=1 << 30, backend="jax", merge_hint="dups",
-        )
-        recs = rand_recs(rng, 100)
-        dev.insert_run_lazy(
-            qindex.build_run(recs, np.arange(100, dtype=np.uint32),
-                             recs["timestamp"])
-        )
-        assert not dev.prefetch_lazy_one()
-        assert not any(
-            m.materialized for m in dev._mem if not isinstance(m, tuple)
-        )
+    def test_mid_run_flush_same_cadence(self):
+        """memtable_max trips inside insert: both flush at the same
+        batch boundaries."""
+        flagged, plain = self._drive_pair(batches=10, n=137,
+                                          memtable_max=137 * 5 * 3)
+        assert len(flagged.levels[0]) == len(plain.levels[0]) > 1
+        assert table_bytes(flagged) == table_bytes(plain)
 
     def test_constant_column_sorted_insert_same_bytes(self):
-        """The host fast path: constant-column batches inserted as
-        SORTED runs (k-way merge flush) must build byte-identical
-        tables to the unsorted-insert radix flush."""
+        """Constant-column batches inserted as SORTED runs (k-way merge
+        flush) must build byte-identical tables to the unsorted-insert
+        radix flush."""
         rng = np.random.default_rng(13)
-        a = DurableIndex(
-            MemGrid(block_count=8192, block_size=4096), unique=False,
-            memtable_max=1 << 30, backend="numpy", merge_hint="dups",
-        )
-        b = DurableIndex(
-            MemGrid(block_count=8192, block_size=4096), unique=False,
-            memtable_max=1 << 30, backend="numpy", merge_hint="dups",
-        )
+        a, b = query_tree(), query_tree()
         for i in range(5):
             recs = rand_recs(rng, 300, constant=True)
             assert scan.query_columns_constant(recs)
             rows = np.arange(i * 300, (i + 1) * 300, dtype=np.uint32)
-            k, v = host_query_keys(recs, rows)
+            k, v = stated_query_keys(recs, rows, recs["timestamp"])
             a.insert_sorted(k, v)
             b.insert_unsorted(k.copy(), v.copy())
         a.flush_memtable()
         b.flush_memtable()
         assert table_bytes(a) == table_bytes(b)
-
-    def test_lookup_range_resolves_lazy_runs(self, monkeypatch):
-        monkeypatch.setenv("TIGERBEETLE_TPU_DEVICE_MERGE", "1")
-        rng = np.random.default_rng(8)
-        dev = DurableIndex(
-            MemGrid(block_count=8192, block_size=4096), unique=False,
-            memtable_max=1 << 30, backend="jax", merge_hint="dups",
-        )
-        recs = rand_recs(rng, 100, constant=True)
-        rows = np.arange(100, dtype=np.uint32)
-        dev.insert_run_lazy(qindex.build_run(recs, rows, recs["timestamp"]))
-        key = np.zeros(1, dtype=KEY_DTYPE)
-        key["lo"] = (np.uint64(scan.TAG_LEDGER) << np.uint64(56)) | np.uint64(1)
-        key["hi"] = recs["timestamp"][0]
-        got = dev.lookup_range(key[0])
-        assert len(got) >= 1  # the ledger=1 entry for that timestamp
-
-
-class TestTiledKernelAlways:
-    """Satellite: _pad_pow2 buckets are tile multiples, so merge_device
-    never falls back to the slow global-binary-search kernel — even for
-    sub-tile runs."""
-
-    def test_pad_pow2_is_tile_aligned(self):
-        for n in (1, 5, 15, 16, 17, 100, 255, 256, 257, 1000):
-            k = np.zeros((n, 3), dtype=np.uint32)
-            v = np.zeros((n, 3), dtype=np.uint32)
-            pk, _pv = merge_ops._pad_pow2(k, v)
-            assert len(pk) % merge_ops.MERGE_TILE == 0, (n, len(pk))
-            assert len(pk) >= n
-
-    @pytest.mark.parametrize("na,nb", [(5, 37), (1, 1), (255, 3), (300, 17)])
-    def test_sub_tile_runs_take_tiled_kernel(self, na, nb, monkeypatch):
-        def boom(*a, **k):
-            raise AssertionError(
-                "global-binary-search merge_kernel must not run"
-            )
-
-        monkeypatch.setattr(merge_ops, "merge_kernel", boom)
-        rng = np.random.default_rng(na * 1000 + nb)
-
-        def run(n):
-            k = np.zeros(n, dtype=KEY_DTYPE)
-            k["lo"] = np.sort(rng.integers(0, 1 << 40, n).astype(np.uint64))
-            k["hi"] = rng.integers(0, 1 << 40, n).astype(np.uint64)
-            return k, np.arange(n, dtype=np.uint32)
-
-        ka, va = run(na)
-        kb, vb = run(nb)
-        mk, mv = merge_ops.merge_device(ka, va, kb, vb)
-        hk, hv = merge_ops.merge_host(ka, va, kb, vb)
-        assert mk.tobytes() == hk.tobytes()
-        assert np.array_equal(mv, hv)
 
 
 class TestKwayHostMerge:
@@ -349,7 +248,7 @@ class TestKwayHostMerge:
     def test_matches_radix_sort(self, counts, dups):
         rng = np.random.default_rng(sum(counts) + len(counts))
         parts_k, parts_v = self._runs(rng, counts, dups)
-        mk, mv = merge_ops.merge_host_kway(parts_k, parts_v)
+        mk, mv = merge_host_kway(parts_k, parts_v)
         sk, sv = sort_kv(
             np.concatenate(parts_k), np.concatenate(parts_v)
         )
@@ -360,97 +259,8 @@ class TestKwayHostMerge:
         # Two runs, all-equal lo: run 0's values must all precede run 1's.
         k = np.zeros(4, dtype=KEY_DTYPE)
         k["lo"] = 7
-        mk, mv = merge_ops.merge_host_kway(
+        mk, mv = merge_host_kway(
             [k.copy(), k.copy()],
             [np.arange(4, dtype=np.uint32), np.arange(4, 8, dtype=np.uint32)],
         )
         assert list(mv) == list(range(8))
-
-
-class TestAbsintCoverage:
-    def test_qindex_limb_arithmetic_proven(self):
-        """The fused key build's limb math is in the absint domain and
-        proves clean (the same contract as ops/u128.py / lsm/scan.py)."""
-        import os
-
-        from tigerbeetle_tpu.tidy import absint
-
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        findings, checked = absint.prove_file(
-            os.path.join(repo, "tigerbeetle_tpu/ops/qindex.py"), repo, 32
-        )
-        assert findings == []
-        assert checked >= 5  # the interpreter actually visited the shifts
-
-
-class TestDeviceQueryPathDeterminism:
-    """TestAsyncStoreStage-style guard: the SAME workload through the
-    jax-backend pipeline with the HOST query path vs the DEVICE query
-    path must produce byte-identical hash_log commit chains and
-    checkpoint trailer digests."""
-
-    OPS = 24  # past one TEST_MIN checkpoint interval (16)
-
-    def _drive(self, device_query: bool, hash_log=None):
-        from tests.test_cluster import do_request, setup_client
-        from tigerbeetle_tpu.testing.cluster import (
-            Cluster, account_batch, transfer_batch,
-        )
-        from tigerbeetle_tpu.testing.hash_log import attach_to_cluster
-        from tigerbeetle_tpu.vsr.clock import Clock, DeterministicTime
-        from tigerbeetle_tpu.vsr.header import Operation
-
-        cl = Cluster(
-            replica_count=1, seed=9, store_async=True, sm_backend="jax",
-        )
-        for r in cl.replicas:
-            r.time = DeterministicTime(tick_ns=0)
-            r.clock = Clock(r.time, cl.replica_count, r.replica)
-        attach_to_cluster(cl, hash_log)
-        try:
-            assert all(
-                r.state_machine._qindex_device is device_query
-                for r in cl.replicas
-            )
-            c = setup_client(cl)
-            do_request(cl, c, Operation.CREATE_ACCOUNTS, account_batch([1, 2]))
-            for i in range(self.OPS):
-                do_request(cl, c, Operation.CREATE_TRANSFERS, transfer_batch([
-                    dict(id=1 + i * 4 + k, debit_account_id=1,
-                         credit_account_id=2, amount=1 + k, ledger=1,
-                         code=1, user_data_64=(k << 54) + i)
-                    for k in range(4)
-                ]))
-            cl.quiesce()
-            chains = [dict(r.commit_checksums) for r in cl.replicas]
-            return chains, dict(cl._checkpoint_history)
-        finally:
-            cl.close()
-
-    def test_host_vs_device_query_path_identical(self, tmp_path, monkeypatch):
-        from tigerbeetle_tpu.testing.hash_log import HashLog
-
-        path = str(tmp_path / "hash.log")
-        monkeypatch.setenv("TIGERBEETLE_TPU_DEVICE_MERGE", "0")
-        create = HashLog(path, "create")
-        host_chains, host_hist = self._drive(False, hash_log=create)
-        create.close()
-        monkeypatch.setenv("TIGERBEETLE_TPU_DEVICE_MERGE", "1")
-        check = HashLog(path, "check")
-        dev_chains, dev_hist = self._drive(True, hash_log=check)
-        check.close()
-        want = self.OPS + 2  # register + create_accounts + transfers
-        ref: dict = {}
-        for chains in (host_chains, dev_chains):
-            assert chains and max(chains[0]) >= want
-            for c in chains:
-                for op, v in c.items():
-                    assert ref.setdefault(op, v) == v, (
-                        f"divergent commit checksum at op {op}"
-                    )
-        common = set(host_hist) & set(dev_hist)
-        assert common and max(common) >= 16
-        for op in common:
-            assert host_hist[op] == dev_hist[op], (
-                f"checkpoint {op}: trailer bytes differ host vs device"
-            )

@@ -3,7 +3,7 @@ numpy oracles: intersect/union/probe properties over duplicate keys,
 empty predicates, and cross-run boundaries; plan determinism under
 predicate reordering; the probe pay-rule pins; the merge-stream cut
 regression (uint64 vs float64 searchsorted promotion); the object-log
-gather grouping; and the host-vs-device intersect determinism guard."""
+gather grouping; and the AND-merge against np.intersect1d."""
 
 import os
 
@@ -434,26 +434,31 @@ class TestPagingCursors:
         assert got.tobytes() == snapshot.tobytes()
 
 
-class TestDeviceHostDeterminism:
-    def test_intersect_device_matches_host(self):
-        """Byte-identical AND-merge across forced routes (the storage-
-        determinism bar applied to the read path)."""
-        jax = pytest.importorskip("jax")
-        del jax
+class TestIntersect:
+    """The scan engine's AND-merge (C gallop, or np.intersect1d under its
+    size gate) against numpy's own statement of a set intersection."""
+
+    @pytest.mark.parametrize("trial", range(10))
+    def test_intersect_matches_numpy(self, trial):
         from tigerbeetle_tpu.lsm.store import intersect_sorted_u32
-        from tigerbeetle_tpu.ops.scanops import intersect_sorted_device
 
-        rng = np.random.default_rng(12)
-        for trial in range(10):
-            a = np.unique(rng.integers(0, 5000, 800)).astype(np.uint32)
-            b = np.unique(rng.integers(0, 5000, 1200)).astype(np.uint32)
-            host = intersect_sorted_u32(a, b)
-            dev = intersect_sorted_device(a, b)
-            assert host.tobytes() == dev.tobytes()
+        rng = np.random.default_rng([12, trial])
+        # Trials 0-7: hundreds of rows a side (the C gallop); 8 and 9:
+        # a short side under the shim's 32-row gate, and a disjoint pair.
+        na, nb = ((800, 1200), (20, 1200))[trial == 8]
+        a = np.unique(rng.integers(0, 5000, na)).astype(np.uint32)
+        b = np.unique(rng.integers(0, 5000, nb)).astype(np.uint32)
+        if trial == 9:
+            b = b + np.uint32(5000)
+        want = np.intersect1d(a, b).astype(np.uint32)
+        got = intersect_sorted_u32(a, b)
+        assert got.dtype == np.uint32
+        assert got.tobytes() == want.tobytes()
+        assert scan.intersect_rows([b, a]).tobytes() == want.tobytes()
 
-    def test_engine_route_forced_device(self, monkeypatch):
-        pytest.importorskip("jax")
-        monkeypatch.setenv("TIGERBEETLE_TPU_DEVICE_MERGE", "1")
+    def test_intersect_rows_three_way_smallest_first(self):
         a = np.array([1, 5, 9, 1000], dtype=np.uint32)
         b = np.array([5, 9, 64], dtype=np.uint32)
-        assert scan.intersect_rows([a, b]).tolist() == [5, 9]
+        c = np.arange(0, 2000, dtype=np.uint32)
+        assert scan.intersect_rows([c, a, b]).tolist() == [5, 9]
+        assert scan.intersect_rows([a, np.zeros(0, np.uint32), c]).tolist() == []

@@ -1,8 +1,8 @@
 """Ask the TPU's compiler, without a TPU, whether it accepts the served
 path's kernels at production widths (`on-chip-measurement` guide,
-section 2, rehearsal 3): state tables for accounts_max = 2^20, the
-n = 8192 batch bucket an 8190-event message pads to, merge runs of
-2^15 rows. libtpu compiles for a v5e that is DESCRIBED, not attached.
+section 2, rehearsal 3): state tables for accounts_max = 2^20 and the
+n = 8192 batch bucket an 8190-event message pads to. libtpu compiles
+for a v5e that is DESCRIBED, not attached.
 
 A compile that passes is not a chip run — nothing executes, so nothing
 here says anything about results or times. What it catches, at no chip
@@ -25,10 +25,10 @@ from jax.sharding import SingleDeviceSharding
 
 from tigerbeetle_tpu.constants import PRODUCTION
 from tigerbeetle_tpu.ops import commit as commit_ops
-from tigerbeetle_tpu.ops import commit_exact, merge, qindex, scanops
+from tigerbeetle_tpu.ops import commit_exact
 
 A = PRODUCTION.accounts_max  # 2^20 account slots on the device
-N = merge.bucket_pow2(PRODUCTION.batch_max)  # 8190 events pad to 8192
+N = 8192  # the batch bucket: PRODUCTION.batch_max, 8190 events, pads to it
 HBM_BYTES = 16 * 10**9  # one v5e chip
 
 
@@ -129,32 +129,3 @@ def test_create_transfers_exact(one_chip, has_pv, has_chains):
     *_, bail, sweeps = compiled.out_info
     assert (bail.shape, bail.dtype) == ((), np.bool_)
     assert (sweeps.shape, sweeps.dtype) == ((), np.int32)
-
-
-@pytest.mark.parametrize("runs_folded", [1, 3], ids=["first_fold", "later_fold"])
-def test_merge_kernel_tiled(one_chip, runs_folded):
-    """The memtable fold of lazy key runs (qindex.fold_runs_device): run
-    B joins what the fold has gathered so far, so from the second step
-    on A is several runs long and no power of two."""
-    rows = 1 << 15
-    a = np.zeros((runs_folded * rows, 3), np.uint32)
-    b = np.zeros((rows, 3), np.uint32)
-    _compile(merge.merge_kernel_tiled, one_chip, a, a, b, b)
-
-
-def test_query_index_keys(one_chip):
-    """The key build alone. Its sorted sibling, query_index_keys_sorted,
-    is the served route on a chip and takes the chip's compiler minutes
-    at this width (CHANGES.md, PR 21) — too long for this file."""
-    _compile(
-        qindex.query_index_keys, one_chip,
-        np.zeros((N, 9), np.uint32), np.zeros((N, 2), np.uint32),
-        np.zeros(N, np.uint32), np.zeros(N, np.uint32),
-    )
-
-
-def test_scan_intersect_mask(one_chip):
-    _compile(
-        scanops.scan_intersect_mask, one_chip,
-        np.zeros(N, np.uint32), np.zeros(1 << 17, np.uint32),
-    )

@@ -16,10 +16,9 @@ The device-plane sibling of the round-19 cluster plane
     memory-bound roofline classification (static arithmetic intensity
     vs the backend balance point).
   - **Memory ledger.** tracer.device_mem_* owner-tagged gauges
-    (`device.mem.<owner>.bytes`): the dispatch scratch ring's buckets,
-    balance tables, lazy query runs, compaction fold chunks —
-    reconciled against `jax.local_devices()[0].memory_stats()` where
-    the backend reports it, with high-water tracking surfaced as the
+    (`device.mem.<owner>.bytes`): the dispatch scratch ring's buckets
+    and the balance tables — reconciled against
+    `jax.local_devices()[0].memory_stats()` where the backend reports it, with high-water tracking surfaced as the
     bench-gated `device_mem_high_water_bytes` lifecycle flat key.
   - **Transfer bandwidth.** The `device.xfer.{h2d,d2h}.gbps`
     histograms (stamped in tracer.device_finish, i.e. only inside the
@@ -60,11 +59,6 @@ _ENTRY_MODULES = {  # tidy: atomic — immutable constant table, never written a
     "write_balances": "tigerbeetle_tpu.ops.commit",
     "read_balances": "tigerbeetle_tpu.ops.commit",
     "create_transfers_exact": "tigerbeetle_tpu.ops.commit_exact",
-    "merge_kernel": "tigerbeetle_tpu.ops.merge",
-    "merge_kernel_tiled": "tigerbeetle_tpu.ops.merge",
-    "query_index_keys": "tigerbeetle_tpu.ops.qindex",
-    "query_index_keys_sorted": "tigerbeetle_tpu.ops.qindex",
-    "scan_intersect_mask": "tigerbeetle_tpu.ops.scanops",
 }
 
 # Roofline balance point (FLOPs per byte at which the machine is

@@ -56,11 +56,8 @@ class PostedGroove:
     grew with every two-phase transfer ever committed.
     """
 
-    def __init__(self, grid, *, memtable_max: int = 1 << 14,
-                 backend: str = "numpy") -> None:
-        self.index = DurableIndex(
-            grid, unique=True, memtable_max=memtable_max, backend=backend
-        )
+    def __init__(self, grid, *, memtable_max: int = 1 << 14) -> None:
+        self.index = DurableIndex(grid, unique=True, memtable_max=memtable_max)
 
     @property
     def count(self) -> int:
@@ -156,12 +153,9 @@ class HistoryGroove:
     join over a Python list (VERDICT r3 missing #4/#5, weak #6).
     """
 
-    def __init__(self, grid, *, memtable_max: int = 1 << 14,
-                 backend: str = "numpy") -> None:
+    def __init__(self, grid, *, memtable_max: int = 1 << 14) -> None:
         self.log = DurableLog(grid, HISTORY_DTYPE)
-        self.rows = DurableIndex(
-            grid, unique=False, memtable_max=memtable_max, backend=backend
-        )
+        self.rows = DurableIndex(grid, unique=False, memtable_max=memtable_max)
 
     @property
     def count(self) -> int:

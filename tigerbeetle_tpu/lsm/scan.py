@@ -52,9 +52,9 @@ TAG_CODE = 10
 
 # The QueryFilter-queryable fields, in INSERT ORDER — ascending by tag,
 # which makes the 5-block composite-key build block-ordered by key.lo
-# (tag is the top byte). Single source for the host key build
-# (state_machine._store_query_index) and the fused device kernel
-# (ops/qindex.py): (tag, lo-word field, hi-word field or None).
+# (tag is the top byte). Single source for the key build
+# (state_machine._store_query_index): (tag, lo-word field, hi-word
+# field or None).
 QUERY_TAG_FIELDS = (
     (TAG_UD128, "user_data_128_lo", "user_data_128_hi"),
     (TAG_UD64, "user_data_64", None),
@@ -117,43 +117,21 @@ def prefix(tag: int, value_lo: int, value_hi: int = 0) -> int:
     return (tag << 56) | f
 
 
-def _device_intersect_on() -> bool:
-    """Whether pairwise AND-merges route through the device kernel
-    (ops/scanops). Consulted per merge, but NEVER imports jax into a
-    process that has not already loaded it — the numpy-backend store
-    thread must stay jax-free (round-13 lesson), and `sys.modules` is a
-    read, not an import."""
-    import sys
-
-    if "jax" not in sys.modules:
-        return False
-    from tigerbeetle_tpu.ops.scanops import device_scan_pays
-
-    return device_scan_pays()
-
-
 def intersect_rows(parts: List[np.ndarray]) -> np.ndarray:
     """AND-merge of sorted row arrays (scan_merge.zig:252 intersection),
     smallest-first so the working set only shrinks. Pairwise merges run
-    the C gallop (store.intersect_sorted_u32) on the host route or the
-    device membership kernel (ops/scanops) where that policy pays —
-    value-identical either way (tests/test_query.py determinism guard)."""
+    the C gallop (store.intersect_sorted_u32; np.intersect1d where the
+    shim is absent or the lists are short)."""
     from tigerbeetle_tpu.lsm.store import intersect_sorted_u32
 
     if not parts:
         return np.zeros(0, dtype=np.uint32)
     parts = sorted(parts, key=len)
     out = np.asarray(parts[0], dtype=np.uint32)
-    device = _device_intersect_on()
-    if device:
-        from tigerbeetle_tpu.ops.scanops import intersect_sorted_device
     for p in parts[1:]:
         if len(out) == 0:
             break
-        if device:
-            out = intersect_sorted_device(out, p)
-        else:
-            out = intersect_sorted_u32(out, p)
+        out = intersect_sorted_u32(out, p)
     return out.astype(np.uint32, copy=False)
 
 

@@ -150,9 +150,8 @@ def merge_host_kway(parts_k, parts_v):
     within-run order preserved — byte-identical to sort_kv on the runs'
     concatenation, at merge cost instead of radix cost. C shim
     (hostops_merge_kv) with a sort_kv fallback; inputs beyond the shim's
-    64-run bound fold in groups. Jax-free on purpose: this is the
-    numpy-backend flush/compaction substrate (ops/merge.py re-exports
-    it for the device-pipeline callers)."""
+    64-run bound fold in groups. The memtable flush's merge on every
+    backend."""
     parts = [(k, v) for k, v in zip(parts_k, parts_v) if len(k)]
     if not parts:
         if not len(parts_k):

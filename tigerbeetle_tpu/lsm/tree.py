@@ -7,16 +7,18 @@ The TPU-first re-design of the reference's tree/table/compaction stack
     value) entries, all checksummed grid blocks (io/grid.py). The index
     block holds per-data-block key fences — the analog of table.zig's index
     block — so point lookups read exactly one data block.
-  - The *memtable* is unsorted appended batches (vectorized inserts only,
-    matching the prefetch-batch design, groove.zig:644-909); it flushes as a
-    sorted level-0 table.
+  - The *memtable* is a list of appended `(keys, vals)` host batches
+    (vectorized inserts only, matching the prefetch-batch design,
+    groove.zig:644-909); it flushes as a sorted level-0 table.
   - *Compaction* merges a full level into the next when it exceeds the
     growth factor, streamed in chunks through the host's stable k-way
-    merge (lsm/store.merge_host_kway_bloom, the C shim) on every
-    backend: the runs come off the grid and go back to it on the host.
-    Memory stays O(block), not
-    O(level): the streaming cursor logic here plays the role of the
+    merge (lsm/store.merge_host_kway_bloom, the C shim): the runs come
+    off the grid and go back to it on the host. Memory stays O(block),
+    not O(level): the streaming cursor logic here plays the role of the
     reference's k-way merge iterator pacing (k_way_merge.zig:8).
+
+The whole store lives on the host: nothing under lsm/ imports ops/ or
+jax, on any backend.
 
 Free-space discipline: replaced tables are released to the grid free set,
 which stages frees until the next checkpoint commits (write-once per
@@ -275,7 +277,6 @@ class DurableIndex:
         unique: bool = True,
         memtable_max: int = 1 << 16,
         growth: int = 8,
-        backend: str = "numpy",
         name: Optional[str] = None,
         merge_hint: Optional[str] = None,
     ) -> None:
@@ -287,7 +288,6 @@ class DurableIndex:
         self.name = name
         self.memtable_max = memtable_max
         self.growth = growth
-        self.backend = backend
         # merge_hint="dups": the tree's keys are known low-cardinality
         # (secondary indexes over ledger/code-class fields), where the
         # galloping k-way merge block-copies duplicate runs (~30x the
@@ -382,74 +382,6 @@ class DurableIndex:
         if self._mem_count >= self.memtable_max:
             self.flush_memtable()
 
-    def insert_run_lazy(self, run) -> None:
-        """Append a DISPATCHED device run (ops/qindex.QueryKeyRun): a
-        handle whose keys live on the device until `materialize()` — the
-        split-phase write path of the device query-index pipeline. The
-        run counts toward the flush threshold immediately (flush cadence,
-        hence grid allocation order, is identical to the host path); its
-        bytes are only demanded at flush, a read, or the store stage's
-        idle prefetch. Only ever used for store-barrier-synchronized
-        trees (query_rows) — never for the drain-free-read transfer-id
-        index, whose readers cannot tolerate in-place resolution."""
-        if run.n == 0:
-            return
-        self._mem_sorted.append(run.sorted)
-        self._mem.append(run)
-        self._mem_count += run.n
-        self.count += run.n
-        if self._mem_count >= self.memtable_max:
-            self.flush_memtable()
-
-    def _resolve_mem(self) -> None:
-        """Materialize any lazy device runs in place (tuples stay).
-        Mutation is store-context-owned like every memtable write; read
-        paths reach here only behind a store barrier."""
-        mem = self._mem
-        for i in range(len(mem)):
-            if not isinstance(mem[i], tuple):
-                mem[i] = mem[i].materialize()
-
-    def prefetch_lazy_one(self) -> bool:
-        """Materialize ONE pending device run (oldest first) — the store
-        stage's idle poll: the device→host transfer is pulled forward
-        into queue-idle gaps so the eventual flush never blocks on the
-        device. Content and flush timing are unchanged (materialize is
-        idempotent); True while more runs remain.
-
-        The poll pulls exactly when the flush's device fold will NOT
-        run (fold precondition: every batch an unmaterialized lazy run,
-        and the device merge pays). While the fold is intact, an early
-        per-run transfer would waste d2h bandwidth AND devolve the fold
-        to the host path, making its kernel shapes — hence the
-        compile-count gate — timing-dependent, so the poll keeps its
-        hands off. Once a read barrier has materialized ANY run
-        (lookup_range → _resolve_mem) the cycle is host-bound either
-        way and pulling the rest forward is pure win; barrier timing is
-        op-stream-driven (deterministic across replicas), so the
-        fold-vs-host routing stays deterministic too.
-
-        The pending scan runs FIRST so the numpy backend (never any
-        lazy runs) returns without touching ops.merge — importing it
-        pulls in jax (~1s), which must never happen on the store thread
-        of a numpy-backend server mid-load."""
-        pending = []
-        fold_intact = True
-        for m in self._mem:
-            if isinstance(m, tuple) or m.materialized:
-                fold_intact = False
-            else:
-                pending.append(m)
-        if not pending:
-            return False
-        if fold_intact:
-            from tigerbeetle_tpu.ops import merge as merge_ops
-
-            if merge_ops.device_merge_pays():
-                return False
-        pending[0].materialize()
-        return len(pending) > 1
-
     def _sort_mem_lazily(self) -> None:
         """Point-lookup prerequisite: every memtable batch lo-major sorted
         (unsorted ones arrive via insert_unsorted). Operates on local
@@ -460,7 +392,6 @@ class DurableIndex:
         mutation loop, and the drain-free concurrent reader cannot race
         the store thread's appends (unsorted-batch trees are only ever
         read behind a full store barrier)."""
-        self._resolve_mem()  # no-op unless lazy device runs are present
         flags = self._mem_sorted
         mem = self._mem
         if len(flags) >= len(mem) and all(flags):
@@ -509,60 +440,21 @@ class DurableIndex:
         Route by what the batches already are: when every batch is a
         sorted run, a stable k-way MERGE (oldest first — identical bytes
         to the radix sort of the concatenation, enforced by property
-        tests) replaces the full re-sort; all-device sorted runs fold
-        through the tiled merge kernel and materialize only here, at the
-        table-build boundary. Unsorted batches (insert_unsorted trees)
-        keep the fused C radix path.
-
-        ops.merge (which imports jax) is only touched on the lazy-run
-        branch — lazy runs exist only on the jax backend, so the numpy
-        flush stays jax-import-free."""
+        tests) replaces the full re-sort. Unsorted batches
+        (insert_unsorted trees) keep the fused C radix path."""
         mem = self._mem
         flags = self._mem_sorted
         all_sorted = len(flags) >= len(mem) and all(flags)
         if all_sorted and len(mem) == 1:
-            self._resolve_mem()
             return mem[0]
-        if all_sorted and len(mem) > 1:
-            lazy = [m for m in mem if not isinstance(m, tuple)]
-            if lazy and len(lazy) == len(mem):
-                from tigerbeetle_tpu.ops import merge as merge_ops
-
-                if (
-                    not any(r.materialized for r in lazy)
-                    and merge_ops.device_merge_pays()
-                ):
-                    # Device-resident fold: sorted device runs merge
-                    # on-chip; the one sync below is the sanctioned
-                    # table-build boundary (pads sort last, stripped by
-                    # the real count).
-                    from tigerbeetle_tpu.ops import qindex
-
-                    with self._flush_span("merge"):
-                        t_disp = tracer.device_dispatch("merge_kernel_tiled")
-                        kd, pd, n_real = qindex.fold_runs_device(lazy)
-                        keys, vals = qindex.materialize_fold(kd, pd, n_real)
-                        tracer.device_finish(
-                            "merge_kernel_tiled", t_disp,
-                            d2h_bytes=keys.nbytes + vals.nbytes,
-                        )
-                        # The fold consumed the runs on-chip: close each
-                        # run's key-build dispatch token here, at the one
-                        # sync, so device.step.<key-build entry> reports
-                        # on the primary path too.
-                        for r in lazy:
-                            r.finish_dispatch()
-                    return keys, vals
-            self._resolve_mem()
-            if self.merge_hint == "dups" or len(mem) <= 8:
-                with self._flush_span("merge"):
-                    return merge_host_kway(
-                        [k for k, _ in mem], [v for _, v in mem]
-                    )
-        self._resolve_mem()
+        if all_sorted and (self.merge_hint == "dups" or len(mem) <= 8):
+            with self._flush_span("merge"):
+                return merge_host_kway(
+                    [k for k, _ in mem], [v for _, v in mem]
+                )
         with self._flush_span("sort"):
-            keys = np.concatenate([k for k, _ in self._mem])
-            vals = np.concatenate([v for _, v in self._mem])
+            keys = np.concatenate([k for k, _ in mem])
+            vals = np.concatenate([v for _, v in mem])
             return sort_kv(keys, vals)  # fused C sort+gather
 
     def _publish_level_gauges(self) -> None:
@@ -1089,7 +981,6 @@ class DurableIndex:
     def lookup_range(self, key: np.void) -> np.ndarray:
         """All values stored under `key` (non-unique index), ascending."""
         assert not self.unique
-        self._resolve_mem()
         k_lo = key["lo"]
         k_hi = key["hi"]
         parts: List[np.ndarray] = []
@@ -1316,7 +1207,6 @@ class DurableIndex:
         runs per commit and merges only keep lo order) — _mark_seg
         gallops ascending segments and searchsorted-marks the rest."""
         assert not self.unique
-        self._resolve_mem()
         k_lo, k_hi = key["lo"], key["hi"]
         lo1 = np.asarray([k_lo], dtype=np.uint64)
         hi1 = np.asarray([k_hi], dtype=np.uint64)

@@ -248,12 +248,12 @@ class StateMachine:
         self.account_index = make_u128_index(config.accounts_max)
         self.transfer_index = DurableIndex(
             self.grid, unique=True,
-            memtable_max=config.index_memtable_rows, backend=backend,
+            memtable_max=config.index_memtable_rows,
             name="transfer_id",
         )
         self.account_rows = DurableIndex(
             self.grid, unique=False,
-            memtable_max=config.index_memtable_rows, backend=backend,
+            memtable_max=config.index_memtable_rows,
             name="account_rows",
         )
         # Combined secondary query index: (tag<<56 | fold56(field value),
@@ -265,22 +265,9 @@ class StateMachine:
         # is the galloping k-way merge's best case at flush.
         self.query_rows = DurableIndex(
             self.grid, unique=False,
-            memtable_max=config.index_memtable_rows, backend=backend,
+            memtable_max=config.index_memtable_rows,
             name="query_rows", merge_hint="dups",
         )
-        # Device query-index pipeline (ops/qindex.py): key build + the
-        # memtable's run merge on the device, lazy host materialization
-        # (compaction of the flushed tables merges on the host on every
-        # backend). Only where the device path pays (accelerator backends; TIGERBEETLE_TPU_DEVICE_MERGE
-        # forces either way) — the numpy/CPU fallback keeps the host block
-        # in _store_query_index, byte-identical by the qindex property
-        # tests.
-        if backend == "jax":
-            from tigerbeetle_tpu.ops.merge import device_merge_pays
-
-            self._qindex_device = device_merge_pays()
-        else:
-            self._qindex_device = False
         self.transfer_log = DurableLog(self.grid, types.TRANSFER_DTYPE)
         # Transfer-id membership pre-filter (no false negatives): keeps the
         # per-batch duplicate-id check O(batch) instead of O(tables).
@@ -292,11 +279,9 @@ class StateMachine:
 
         self.posted = PostedGroove(
             self.grid, memtable_max=config.index_memtable_rows // 8 or 512,
-            backend=backend,
         )
         self.history = HistoryGroove(
             self.grid, memtable_max=config.index_memtable_rows // 8 or 512,
-            backend=backend,
         )
 
         self.prepare_timestamp = 0
@@ -495,33 +480,15 @@ class StateMachine:
                 np.asarray(ts, dtype=np.uint64)
                 if ts is not None else recs["timestamp"]
             )
-            if self._qindex_device:
-                # Device pipeline: stage + dispatch the fused key-build
-                # kernel and hand the tree a LAZY run handle — no
-                # device→host sync here, so batch N+1's key build
-                # overlaps batch N's merge drain (split-phase, the
-                # commit kernel's discipline). Bytes are demanded at
-                # flush (device fold for sorted runs), a read barrier,
-                # or the store stage's idle prefetch.
-                from tigerbeetle_tpu.ops import qindex
-
-                with tracer.span("sm.store.query.keys"):
-                    run = qindex.build_run(recs, rows, tstamp)
-                self.query_rows.insert_run_lazy(run)
-                return
-            # Host fallback: one preallocated key block filled slice-wise
-            # (identical bytes to the old per-tag build + concatenate,
-            # minus the five temporaries and the 5n-row copy on the
-            # commit path).
+            # One preallocated key block filled slice-wise, one slice a
+            # tag of scan.QUERY_TAG_FIELDS: key.lo = tag << 56 |
+            # fold56(field), key.hi = timestamp, value = object-log row.
             with tracer.span("sm.store.query.keys"):
-                tags = (
-                    (scan.TAG_UD128, scan.fold56(
-                        recs["user_data_128_lo"], recs["user_data_128_hi"]
-                    )),
-                    (scan.TAG_UD64, scan.fold56(recs["user_data_64"])),
-                    (scan.TAG_UD32, scan.fold56(recs["user_data_32"])),
-                    (scan.TAG_LEDGER, scan.fold56(recs["ledger"])),
-                    (scan.TAG_CODE, scan.fold56(recs["code"])),
+                tags = tuple(
+                    (tag, scan.fold56(
+                        recs[f_lo], None if f_hi is None else recs[f_hi]
+                    ))
+                    for tag, f_lo, f_hi in scan.QUERY_TAG_FIELDS
                 )
                 n = len(recs)
                 keys = np.empty(len(tags) * n, dtype=scan.KEY_DTYPE)

@@ -985,14 +985,12 @@ class CompileRegistry:
 
     def track_default_entries(self) -> None:
         """Register the repo's module-level jit entry points."""
-        from tigerbeetle_tpu.ops import commit, commit_exact, merge, qindex
+        from tigerbeetle_tpu.ops import commit, commit_exact
 
         for mod, names in (
             (commit, ("create_transfers_fast", "register_accounts",
                       "write_balances", "read_balances")),
             (commit_exact, ("create_transfers_exact",)),
-            (merge, ("merge_kernel", "merge_kernel_tiled")),
-            (qindex, ("query_index_keys", "query_index_keys_sorted")),
         ):
             for n in names:
                 self.track(n, getattr(mod, n, None) or 0)

@@ -88,9 +88,6 @@ DETERMINISM_EXCLUDE = ("tigerbeetle_tpu/vsr/clock.py",)
 JAXLINT_MODULES = (
     "tigerbeetle_tpu/ops/commit.py",
     "tigerbeetle_tpu/ops/commit_exact.py",
-    "tigerbeetle_tpu/ops/merge.py",
-    "tigerbeetle_tpu/ops/qindex.py",
-    "tigerbeetle_tpu/ops/scanops.py",
     "tigerbeetle_tpu/models/state_machine.py",
     "tigerbeetle_tpu/parallel/sharding.py",
     "tigerbeetle_tpu/parallel/sharded_ops.py",
@@ -106,11 +103,6 @@ JIT_ENTRIES = {
     "register_accounts": (),
     "write_balances": (),
     "read_balances": (),
-    "merge_kernel": (),
-    "merge_kernel_tiled": ("tile",),
-    "query_index_keys": (),
-    "query_index_keys_sorted": (),
-    "scan_intersect_mask": (),
 }
 
 # (repo-relative file, qualified function) pairs forming the SANCTIONED
@@ -123,24 +115,12 @@ JAXLINT_SYNC_SEAM = frozenset((
     ("tigerbeetle_tpu/models/state_machine.py", "StateMachine.create_transfers_finish"),
     ("tigerbeetle_tpu/models/state_machine.py", "StateMachine._create_transfers_exact"),
     ("tigerbeetle_tpu/models/state_machine.py", "StateMachine._read_balances"),
-    ("tigerbeetle_tpu/ops/merge.py", "merge_device"),
-    ("tigerbeetle_tpu/ops/merge.py", "from_device_run"),
-    # The device query-index pipeline's ONLY sync points: a lazy run's
-    # materialization (flush/read/idle-prefetch) and the device fold's
-    # table-build boundary (lsm/tree._flush_sorted_kv).
-    ("tigerbeetle_tpu/ops/qindex.py", "QueryKeyRun.materialize"),
-    ("tigerbeetle_tpu/ops/qindex.py", "materialize_fold"),
-    # The device scan-intersect's only sync point: mask compression on
-    # the QUERY path (read-side, like store_barrier — never the commit
-    # path, which does not call into ops/scanops at all).
-    ("tigerbeetle_tpu/ops/scanops.py", "finish_intersect"),
 ))
 
 # Functions whose results count as shape-stabilized (bucket-padded):
 # jit-entry arguments produced by these escape the retrace-shape rule.
 JAXLINT_PAD_HELPERS = frozenset((
-    "_device_batch", "_pad_pow2", "_pad_slots", "pad1",
-    "p1", "stage_query_batch", "to_device_run", "_pad_sorted_u32",
+    "_device_batch", "_pad_slots", "pad1", "p1",
 ))
 
 # --- absint: limb-width abstract interpretation scope --------------------
@@ -152,10 +132,6 @@ JAXLINT_PAD_HELPERS = frozenset((
 ABSINT_TARGETS = {
     "tigerbeetle_tpu/ops/u128.py": 32,
     "tigerbeetle_tpu/lsm/scan.py": 64,
-    # The fused device key build re-expresses fold56 + tag<<56 over u32
-    # limbs: every shift/or must stay in-width from the declared tag/f1
-    # ranges (ops/qindex._key_block).
-    "tigerbeetle_tpu/ops/qindex.py": 32,
 }
 
 # --- nativecheck: C-boundary analysis scope ------------------------------

@@ -135,9 +135,8 @@ _generation = 0
 _gauges: Dict[str, float] = {}  # tidy: guarded-by=_registry_lock
 # Device-plane ledgers (ISSUE 18, docs/OBSERVABILITY.md "Device plane").
 # _device_mem: owner tag -> live device bytes (scratch ring buckets,
-# balance tables, lazy query runs, compaction fold chunks); each write
-# republishes the owner's `device.mem.<owner>.bytes` gauge and advances
-# the high-water total. _device_inflight: entry -> {dispatch token:
+# balance tables); each write republishes the owner's
+# `device.mem.<owner>.bytes` gauge and advances the high-water total. _device_inflight: entry -> {dispatch token:
 # h2d bytes} — open dispatch windows, popped at the sanctioned finish
 # seam (bounded per entry: an abandoned token is evicted, never leaked).
 # _device_pairs: bounded ring of closed (entry, t0, t1, h2d, d2h)
@@ -462,9 +461,8 @@ def gauges() -> Dict[str, float]:
 # --- device memory ledger (owner-tagged live device bytes) ---------------
 #
 # Who holds device memory right now, by owner tag: the dispatch scratch
-# ring's generation-keyed buckets (`scratch.<entry>.b<n_pad>`), the
-# resident balance tables (`balances`) and lazy query-key runs
-# (`query_runs`).
+# ring's generation-keyed buckets (`scratch.<entry>.b<n_pad>`) and the
+# resident balance tables (`balances`).
 # Byte counts are `.nbytes` shape metadata — never a device sync — and
 # every write republishes the owner's `device.mem.<owner>.bytes` gauge
 # so the ledger rides the ordinary scrape surface. The high-water mark
@@ -481,29 +479,6 @@ def device_mem_set(owner: str, nbytes: int) -> None:
         total = sum(_device_mem.values())
         if total > _device_mem_hw[0]:
             _device_mem_hw[0] = total
-
-
-def device_mem_adjust(owner: str, delta: int) -> None:
-    """Adjust an owner's live device bytes by a delta (clamped at 0 —
-    a release racing a reset must not publish negative residency)."""
-    if not _enabled:
-        return
-    with _registry_lock:
-        v = max(0, _device_mem.get(owner, 0) + int(delta))
-        _device_mem[owner] = v
-        _gauges[f"device.mem.{owner}.bytes"] = float(v)
-        total = sum(_device_mem.values())
-        if total > _device_mem_hw[0]:
-            _device_mem_hw[0] = total
-
-
-def device_mem_release(owner: str) -> None:
-    """Drop an owner whose device allocation died, gauge included."""
-    if not _enabled:
-        return
-    with _registry_lock:
-        _device_mem.pop(owner, None)
-        _gauges.pop(f"device.mem.{owner}.bytes", None)
 
 
 def device_mem_retire_prefix(prefix: str) -> None:

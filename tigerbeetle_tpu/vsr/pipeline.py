@@ -258,14 +258,12 @@ class StoreExecutor:
         self._post = post
         self._notify = notify if notify is not None else (lambda: None)
         self._depth_max = depth_max
-        # Optional queue-idle poll (device query-index pipeline and
-        # compaction read-ahead): called with the lock RELEASED while the
-        # queue is empty; returns True while it may have more to do. Must
-        # be content-neutral and idempotent — it only pulls deferred
-        # device→host transfers forward (QueryKeyRun.materialize) or
-        # warms upcoming compaction-input blocks into the grid cache
-        # (sm.compact_prefetch_one), never changes state bytes — so it
-        # needs no drain()/barrier coordination. This is the sanctioned
+        # Optional queue-idle poll (compaction read-ahead): called with
+        # the lock RELEASED while the queue is empty; returns True while
+        # it may have more to do. Must be content-neutral and idempotent
+        # — it only warms upcoming compaction-input blocks into the grid
+        # cache (sm.compact_prefetch_one), never changes state bytes — so
+        # it needs no drain()/barrier coordination. This is the sanctioned
         # place for TIMING-dependent acceleration: anything that would
         # alter bytes (like the compaction quota) must key off committed
         # state instead.
@@ -404,9 +402,9 @@ class StoreExecutor:
     def _run(self) -> None:
         tidy_runtime.stamp("store")
         # Idle work stays armed while the last poll reported more pending
-        # (or a job just ran, which may have queued new lazy runs); once
-        # it reports dry the worker blocks on the condition until the
-        # next submit — no spinning.
+        # (or a job just ran, which may have planned new compaction
+        # input); once it reports dry the worker blocks on the condition
+        # until the next submit — no spinning.
         idle_armed = self._idle_work is not None
         while True:
             with self._cond:

@@ -1622,21 +1622,14 @@ class Replica:
         self.state_machine.attach_store_stage(self.store_executor)
 
     def _store_idle_prefetch(self) -> bool:
-        """Queue-idle poll on the store worker: pull ONE pending device
-        query-index run's device→host transfer forward (lsm/tree
-        prefetch_lazy_one) so the eventual flush never blocks on the
-        device, or — when none is pending — warm one upcoming compaction
-        input block into the grid cache (sm.compact_prefetch_one; storm
-        jobs only), so a storm's merge beats read hot instead of from
-        storage. Both are
-        content-neutral and idempotent — materialization is the same
-        bytes whenever it happens, and the read-ahead only changes cache
-        temperature, never merge order; `self.state_machine` is read per
-        call so a state-sync install is picked up naturally."""
-        sm = self.state_machine
-        if sm.query_rows.prefetch_lazy_one():
-            return True
-        return sm.compact_prefetch_one()
+        """Queue-idle poll on the store worker: warm one upcoming
+        compaction input block into the grid cache
+        (sm.compact_prefetch_one; storm jobs only), so a storm's merge
+        beats read hot instead of from storage. Content-neutral and
+        idempotent — the read-ahead only changes cache temperature,
+        never merge order; `self.state_machine` is read per call so a
+        state-sync install is picked up naturally."""
+        return self.state_machine.compact_prefetch_one()
 
     def _store_process(self, job: dict) -> Optional[dict]:
         """Worker-thread side: apply one op's coalesced store job, then
