@@ -412,6 +412,195 @@ class TestBeatPacedCompaction:
         assert max(writes_per_flush) == min(writes_per_flush)
 
 
+class TestMirrorPastItsBudget:
+    """A tree several times over its decoded-mirror budget (lowered on the
+    instance; small blocks): the regime the id tree enters past 2^23 rows.
+    A probe of fewer keys than a table has blocks answers from the fences
+    and the blocks that can hold them; only a probe wide enough to pay for
+    reading every block builds the whole-table mirror. Both answer as a
+    dict does, beside a retire and through a read fault."""
+
+    PATHS = pytest.mark.parametrize("path", ["blocks", "mirror"])
+
+    def _tree(self, n=60_000, seed=31):
+        rng = np.random.default_rng(seed)
+        grid = MemGrid(block_count=8192, block_size=4096)
+        grid.defer_releases = True  # as the replica's grid: frees wait for a checkpoint
+        idx = DurableIndex(grid, unique=True, memtable_max=512, growth=3)
+        idx.DECODE_MIN_ROWS = 256
+        idx.DECODE_BUDGET_ROWS = 8192
+        lo = rng.permutation(np.arange(1, n + 1, dtype=np.uint64)) * np.uint64(7919)
+        hi = rng.integers(0, 1 << 32, n).astype(np.uint64)
+        vals = np.arange(n, dtype=np.uint32)
+        for i in range(0, n, 512):
+            idx.insert_batch(pack_keys(lo[i:i + 512], hi[i:i + 512]), vals[i:i + 512])
+            idx.compact_step(4096)
+        assert idx.count > 7 * idx.DECODE_BUDGET_ROWS and len(idx.levels) == 4
+        # Tables that can never be mirrored (over the budget alone) among them.
+        assert sum(t.count > idx.DECODE_BUDGET_ROWS for t in idx.levels[3]) == 2
+        model = {(int(a), int(b)): int(v) for a, b, v in zip(lo, hi, vals)}
+        return grid, idx, model, lo, hi
+
+    @staticmethod
+    def _deep_table(idx):
+        """The oldest table that still fits the budget alone: many blocks,
+        a fused Bloom, no mirror until a probe builds one."""
+        fits = [t for lvl in idx.levels[1:] for t in lvl
+                if idx.DECODE_MIN_ROWS <= t.count <= idx.DECODE_BUDGET_ROWS]
+        table = max(fits, key=lambda t: t.count)
+        assert table.bloom is not None and table._decoded is None
+        assert len(idx._table_fences(table)) >= 16
+        return table
+
+    @staticmethod
+    def _table_keys(idx, table):
+        return np.concatenate([
+            idx._read_data_block(int(f["block"]), int(f["count"]))[0]
+            for f in idx._table_fences(table)
+        ])
+
+    def _probe(self, idx, table, path, rng):
+        """Keys for one lookup_batch that reaches `table` by `path`: a few
+        of its keys (fewer than it has blocks), or as many as it has
+        blocks and more; misses its Bloom passes beside them."""
+        held = self._table_keys(idx, table)
+        blocks = len(idx._table_fences(table))
+        take = 3 if path == "blocks" else 4 * blocks
+        hits = held[rng.choice(len(held), take, replace=False)]
+        cand_lo = rng.integers(1 << 40, 1 << 50, 40_000).astype(np.uint64)
+        cand_hi = rng.integers(0, 1 << 32, 40_000).astype(np.uint64)
+        fp = np.nonzero(table.bloom.maybe(cand_lo, cand_hi))[0][:2]
+        assert len(fp) == 2  # two absent keys the table's own filter flags
+        return np.concatenate([hits, pack_keys(cand_lo[fp], cand_hi[fp])])
+
+    @staticmethod
+    def _model_answers(model, keys):
+        return np.array(
+            [model.get((int(k["lo"]), int(k["hi"])), NOT_FOUND) for k in keys],
+            dtype=np.uint32,
+        )
+
+    @staticmethod
+    def _mirror_builds():
+        from tigerbeetle_tpu import tracer
+
+        snap = tracer.snapshot()
+        return tuple(snap.get(e, {}).get("count", 0)
+                     for e in ("lsm.mirror.builds", "lsm.mirror.rows_built"))
+
+    @pytest.fixture
+    def traced(self):
+        from tigerbeetle_tpu import tracer
+
+        was = tracer.enabled()
+        tracer.enable()
+        tracer.reset()
+        yield
+        tracer.reset()
+        if not was:
+            tracer.disable()
+
+    @PATHS
+    def test_answers_as_a_dict_does(self, path):
+        """Every stored key and as many absent ones, three keys a call or
+        all at once: hits, misses and values as the model has them, at
+        every level, tables over the budget among them."""
+        _, idx, model, lo, hi = self._tree()
+        rng = np.random.default_rng(5)
+        miss = pack_keys(rng.integers(1 << 40, 1 << 50, 600).astype(np.uint64),
+                         rng.integers(0, 1 << 32, 600).astype(np.uint64))
+        keys = np.concatenate([pack_keys(lo, hi)[::17], miss])
+        keys = keys[rng.permutation(len(keys))]
+        want = self._model_answers(model, keys)
+        assert (want != NOT_FOUND).sum() > 1500 and (want == NOT_FOUND).sum() == 600
+        step = 3 if path == "blocks" else len(keys)
+        got = np.concatenate([
+            idx.lookup_batch(keys[at:at + step]) for at in range(0, len(keys), step)
+        ])
+        assert (got == want).all()
+        assert idx._decoded_rows <= idx.DECODE_BUDGET_ROWS
+        if path == "blocks":
+            # Three keys never pay for a table of 11 blocks or more.
+            assert all(t._decoded is None for lvl in idx.levels[1:] for t in lvl)
+
+    def test_narrow_probe_builds_no_mirror_and_a_wide_one_does(self, traced):
+        _, idx, model, _, _ = self._tree()
+        table = self._deep_table(idx)
+        rng = np.random.default_rng(6)
+        narrow = self._probe(idx, table, "blocks", rng)[-2:]  # absent, flagged
+        before = self._mirror_builds()
+        for _ in range(3):
+            assert (idx.lookup_batch(narrow) == NOT_FOUND).all()
+        assert self._mirror_builds() == before and table._decoded is None
+        wide = self._probe(idx, table, "mirror", rng)
+        assert (idx.lookup_batch(wide) == self._model_answers(model, wide)).all()
+        builds, rows = self._mirror_builds()
+        # The deep table's, behind those of the flush-fresh level-0 tables
+        # the walk met first (3 blocks each, no filter yet), which it evicts.
+        assert table._decoded is not None and idx._decoded_lru == [table]
+        fresh = builds - before[0] - 1
+        assert 0 <= fresh <= len(idx.levels[0])
+        assert rows - before[1] == table.count + 512 * fresh
+        # A live mirror is used, however narrow the probe: nothing is rebuilt.
+        assert (idx.lookup_batch(narrow) == NOT_FOUND).all()
+        assert self._mirror_builds() == (builds, rows)
+
+    @PATHS
+    def test_table_retired_under_a_probe_still_answers(self, path):
+        """The store thread's compaction installs and retires the table
+        between two block reads of a probe that holds it: the blocks are
+        staged, not freed, so the walk reads them intact; no mirror of the
+        dead table enters the budget."""
+        grid, idx, model, _, _ = self._tree()
+        table = self._deep_table(idx)
+        keys = self._probe(idx, table, path, np.random.default_rng(7))
+        data_blocks = {int(f["block"]) for f in idx._table_fences(table)}
+        real = grid.read_block
+        retired = []
+
+        def read_block(index):
+            if not retired and index in data_blocks:
+                retired.append(index)
+                idx.compact_all()  # merges every table into one, retires all
+                assert table._released
+            return real(index)
+
+        grid.read_block = read_block
+        try:
+            got = idx.lookup_batch(keys)
+        finally:
+            del grid.read_block
+        assert retired
+        assert (got == self._model_answers(model, keys)).all()
+        assert table._decoded is None and table not in idx._decoded_lru
+        assert (idx.lookup_batch(keys) == got).all()  # and from the merged run
+
+    @PATHS
+    def test_read_fault_surfaces_and_mutates_nothing(self, path):
+        from tigerbeetle_tpu.io.grid import GridReadFault
+
+        grid, idx, model, _, _ = self._tree()
+        table = self._deep_table(idx)
+        keys = self._probe(idx, table, path, np.random.default_rng(8))
+        data_blocks = {int(f["block"]) for f in idx._table_fences(table)}
+        real = grid.read_block
+
+        def read_block(index):
+            if index in data_blocks:
+                raise GridReadFault(index, None)
+            return real(index)
+
+        grid.read_block = read_block
+        try:
+            with pytest.raises(GridReadFault) as fault:
+                idx.lookup_batch(keys)
+        finally:
+            del grid.read_block
+        assert fault.value.index in data_blocks
+        assert table._decoded is None and idx._decoded_rows <= idx.DECODE_BUDGET_ROWS
+        assert (idx.lookup_batch(keys) == self._model_answers(model, keys)).all()
+
+
 class TestDurableLog:
     def test_append_gather_scan(self):
         grid = MemGrid(block_count=2048, block_size=4096)

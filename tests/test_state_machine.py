@@ -1080,3 +1080,128 @@ class TestExactKernelChainsAndPostVoid:
             == [(i, r) for i, r in expected]
         assert sm.stats["exact_batches"] >= 1, sm.stats
         check_equal(sm, orc)
+
+
+class TestDuplicateIdDeepInTheIdTree:
+    """An id stored long ago, sent again once the id tree is several times
+    over its decoded-mirror budget (lowered on the instance, as is the
+    growth factor, so that a few thousand transfers reach the shape the
+    production tree has past 2^23 rows). The duplicate-id confirm reaches
+    it through the fences and one data block of a table two levels down,
+    builds no mirror on the way, and the answers are the oracle's: `exists`
+    for the stored id, `ok` for the fresh ids beside it, the global
+    filter's false positives among them."""
+
+    CFG = Config(
+        name="deep", accounts_max=1 << 10, transfers_max=1 << 16,
+        lsm_block_size=1 << 12, grid_block_count=1 << 13,
+        grid_cache_blocks=64, index_memtable_rows=512,
+    )
+    BATCHES, N = 48, 512
+
+    @staticmethod
+    def _batch(ids, rng):
+        ev = np.zeros(len(ids), dtype=types.TRANSFER_DTYPE)
+        ev["id_lo"] = ids
+        dr = rng.integers(1, 17, len(ids))
+        ev["debit_account_id_lo"] = dr
+        ev["credit_account_id_lo"] = 1 + (dr + rng.integers(0, 15, len(ids))) % 16
+        ev["amount_lo"] = rng.integers(1, 1000, len(ids))
+        ev["ledger"] = 1
+        ev["code"] = 7
+        return ev
+
+    @staticmethod
+    def _expected(orc, batch):
+        ts = orc.prepare("create_transfers", len(batch))
+        return orc.create_transfers([transfer_from_numpy(r) for r in batch], ts)
+
+    @staticmethod
+    def _pairs(got):
+        return [(int(i), int(r)) for i, r in zip(got["index"], got["result"])]
+
+    def _stored(self):
+        sm, orc = StateMachine(self.CFG, backend="jax"), Oracle()
+        tree = sm.transfer_index
+        tree.growth, tree.DECODE_MIN_ROWS, tree.DECODE_BUDGET_ROWS = 3, 256, 8192
+        accounts = simple_accounts(16)
+        orc.create_accounts(
+            [account_from_numpy(r) for r in accounts], orc.prepare("create_accounts", 16))
+        assert len(sm.create_accounts(accounts)) == 0
+        rng = np.random.default_rng(61)
+        first = None
+        for b in range(self.BATCHES):
+            batch = self._batch(1000 + b * self.N + np.arange(self.N), rng)
+            first = batch if first is None else first
+            assert self._expected(orc, batch) == [] and len(sm.create_transfers(batch)) == 0
+            sm.compact_beat()
+        assert tree.count > 2 * tree.DECODE_BUDGET_ROWS and len(tree.levels) >= 3
+        return sm, orc, first, rng
+
+    @staticmethod
+    def _level_of(tree, id_lo):
+        for depth, level in enumerate(tree.levels):
+            for t in level:
+                for f in tree._table_fences(t):
+                    keys, _ = tree._read_data_block(int(f["block"]), int(f["count"]))
+                    if id_lo in keys["lo"]:
+                        return depth
+        return None
+
+    def _fresh(self, sm, rng, n, linked):
+        """n ids never stored, eight of them flagged by the global filter
+        (its false positives: the confirm has to clear them); with
+        `linked`, a chain of three at the front, which routes exact."""
+        cand = 10_000_000 + np.arange(200_000, dtype=np.uint64)
+        flagged = sm.transfer_seen.maybe(cand, np.zeros(len(cand), dtype=np.uint64))
+        assert flagged.sum() >= 8
+        ids = np.concatenate([cand[~flagged][:n - 8], cand[flagged][:8]])
+        batch = self._batch(ids[rng.permutation(n)], rng)
+        if linked:
+            batch["flags"][:2] = int(TransferFlags.LINKED)
+        return batch
+
+    def _send(self, sm, batch, path):
+        """Through the dispatch-ahead where the fast path has one (at its
+        turn where that refuses), else single-phase."""
+        if path == "exact":
+            return sm.create_transfers(batch)
+        ts = sm.prepare("create_transfers", len(batch))
+        handle = sm.create_transfers_dispatch(batch, ts)
+        if handle is None:
+            return sm.create_transfers(batch, timestamp=ts)
+        return sm.create_transfers_finish(handle)
+
+    @pytest.mark.parametrize("path", ["fast", "exact"])
+    def test_exists_two_levels_down_and_ok_beside_it(self, path):
+        sm, orc, first, rng = self._stored()
+        tree = sm.transfer_index
+        deep = first[17]
+        assert self._level_of(tree, int(deep["id_lo"])) >= 2
+        mirrored = {t for lvl in tree.levels for t in lvl if t._decoded is not None}
+
+        # Fresh ids alone: the confirm clears the filter's false positives
+        # and the batch keeps its route.
+        batch = self._fresh(sm, rng, 64, linked=path == "exact")
+        before = dict(sm.stats)
+        expected = self._expected(orc, batch)
+        assert self._pairs(self._send(sm, batch, path)) == expected == []
+        assert sm.stats[f"{path}_batches"] == before[f"{path}_batches"] + 1
+        assert sm.stats["serial_batches"] == before["serial_batches"]
+        sm.compact_beat()
+
+        # The stored id among fresh ones: found, so the batch goes serial.
+        batch = self._fresh(sm, rng, 64, linked=path == "exact")
+        batch["id_lo"] += 1_000_000
+        batch[40] = deep
+        before = dict(sm.stats)
+        expected = self._expected(orc, batch)
+        assert expected == [(40, int(TR.EXISTS))]
+        assert self._pairs(self._send(sm, batch, path)) == expected
+        assert sm.stats["serial_batches"] == before["serial_batches"] + 1
+        sm.compact_beat()
+
+        # Neither confirm built a mirror of a table below level 0.
+        assert {t for lvl in tree.levels[1:] for t in lvl
+                if t._decoded is not None} <= mirrored
+        check_equal(sm, orc)

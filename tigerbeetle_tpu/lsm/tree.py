@@ -811,11 +811,28 @@ class DurableIndex:
     # tables churn too fast.
     DECODE_MIN_ROWS = 1 << 16
 
-    def _decode_table(self, table: TableInfo) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+    def _scan_pays(self, table: TableInfo, n_keys: int) -> bool:
+        """Is a probe of `n_keys` keys wide enough to pay for a pass over
+        every block of `table` (a mirror build, a streamed Bloom)? A key
+        costs the block path (_lookup_table) one block read; the pass
+        costs all of them, and a mirror it installs evicts the one the
+        next table of a newest-first walk needs: past the budget, every
+        duplicate-id confirm rebuilt every table for a handful of Bloom
+        false positives (LRU under a cyclic scan)."""
+        return n_keys >= len(self._table_fences(table))
+
+    def _decode_table(
+        self, table: TableInfo, probe_keys: Optional[int] = None
+    ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
         """Concatenated (keys, vals) mirror of an immutable table, LRU
         budgeted tree-wide. Block reads and the mirror build run outside
         the LRU lock; only the bookkeeping is serialized against the
-        store thread's _release_table."""
+        store thread's _release_table.
+
+        `probe_keys` is how many keys the caller would look up in it. A
+        live mirror is always used; one that is not live is built only
+        for a probe wide enough to pay for it (_scan_pays). None from a
+        narrow probe means "take the block path"."""
         with self._lru_lock:
             decoded = table._decoded
             if decoded is not None:
@@ -828,12 +845,16 @@ class DurableIndex:
                 return decoded
         if table.count < self.DECODE_MIN_ROWS or table.count > self.DECODE_BUDGET_ROWS:
             return None
+        if probe_keys is not None and not self._scan_pays(table, probe_keys):
+            return None
         parts_k, parts_v = [], []
         for f in self._table_fences(table):
             bk, bv = self._read_data_block(int(f["block"]), int(f["count"]))
             parts_k.append(bk)
             parts_v.append(bv)
         decoded = (np.concatenate(parts_k), np.concatenate(parts_v))
+        tracer.count("lsm.mirror.builds")
+        tracer.count("lsm.mirror.rows_built", table.count)
         # The mirror build is the first time the table's keys are in RAM
         # — bloom them now so later miss-heavy lookups can skip the run
         # without touching it at all.
@@ -886,17 +907,25 @@ class DurableIndex:
         if not pending.any():
             return out
         for table in self._tables_newest_first():
-            if not pending.any():
+            n_pending = int(np.count_nonzero(pending))
+            if not n_pending:
                 break
             # Per-run bloom gate: probe the table only for keys it might
             # hold — a miss-heavy batch (dup-check of fresh ids) skips
-            # cold runs without a single block read. Blooms materialize
-            # on a run's FIRST probe (never during ingest): piggybacked
-            # on the decoded mirror, or one streaming pass when the
-            # table exceeds the mirror budget.
+            # cold runs without a single block read. Compaction fuses
+            # the filter into its output; a flush-fresh or restored
+            # table gets one on its first WIDE probe (never during
+            # ingest): piggybacked on the decoded mirror, or one
+            # streaming pass when the table exceeds the mirror budget.
+            # A probe narrower than the table's block count pays for
+            # neither pass over every block: it goes unfiltered.
             bloom = table.bloom
             decoded = None
-            if bloom is None and table.count >= self.DECODE_MIN_ROWS:
+            if (
+                bloom is None
+                and table.count >= self.DECODE_MIN_ROWS
+                and self._scan_pays(table, n_pending)
+            ):
                 decoded = self._decode_table(table)
                 bloom = table.bloom  # built with the mirror (when installed)
                 if decoded is None and bloom is None:
@@ -904,7 +933,7 @@ class DurableIndex:
             if bloom is not None:
                 traced = tracer.enabled()
                 if traced:
-                    tracer.count("lsm.bloom.probes", int(pending.sum()))
+                    tracer.count("lsm.bloom.probes", n_pending)
                 flagged = pending & bloom.maybe(keys["lo"], keys["hi"])
                 if not flagged.any():
                     continue
@@ -914,12 +943,7 @@ class DurableIndex:
                 ix = np.nonzero(flagged)[0]
                 sub_out = out[ix]
                 sub_pending = np.ones(len(ix), dtype=bool)
-                if decoded is None:
-                    decoded = self._decode_table(table)
-                if decoded is not None:
-                    search_run(decoded[0], decoded[1], keys[ix], sub_out, sub_pending)
-                else:
-                    self._lookup_table(table, keys[ix], sub_out, sub_pending)
+                self._probe_table(table, decoded, keys[ix], sub_out, sub_pending)
                 resolved = ix[~sub_pending]
                 if traced:
                     # A flagged key the table does not hold is a bloom
@@ -932,13 +956,35 @@ class DurableIndex:
                 out[resolved] = sub_out[~sub_pending]
                 pending[resolved] = False
                 continue
-            if decoded is None:
-                decoded = self._decode_table(table)
-            if decoded is not None:
-                search_run(decoded[0], decoded[1], keys, out, pending)
-            else:
-                self._lookup_table(table, keys, out, pending)
+            self._probe_table(table, decoded, keys, out, pending)
         return out
+
+    def _probe_table(self, table, decoded, keys, out, pending) -> None:
+        """Resolve `keys` against one table: one vectorized search over
+        its mirror (the caller's, a live one, or one built because the
+        probe is as wide as the table is long: _decode_table's rule), else
+        the fences and only the data blocks that can hold a key.
+
+        Both routes read the same immutable blocks through
+        grid.read_block and are equally safe beside the store thread's
+        compaction, for the same reason: install publishes the merged
+        output before the inputs leave their level, so the newest-first
+        walk holds every entry in at least one table it visits; a
+        retired table's TableInfo and fences stay with the walk that
+        captured them, and its blocks are only STAGED for release
+        (Grid.defer_releases, the replica's grid): they are freed for
+        reuse at the checkpoint's commit_releases, which runs on the
+        commit side behind a drained store stage, never during a
+        commit-thread confirm. The block path installs nothing, so it
+        needs no `_released` check (that one keeps a dead table's mirror
+        out of the LRU budget). A GridReadFault leaves either route from
+        the same read_block call, before any state is touched."""
+        if decoded is None:
+            decoded = self._decode_table(table, int(np.count_nonzero(pending)))
+        if decoded is not None:
+            search_run(decoded[0], decoded[1], keys, out, pending)
+        else:
+            self._lookup_table(table, keys, out, pending)
 
     def _lookup_table(self, table, keys, out, pending) -> None:
         fences = self._table_fences(table)

@@ -1206,10 +1206,12 @@ class StateMachine:
             # + durable index (drain-free — reads the LSM, so a
             # GridReadFault here aborts the dispatch cleanly; nothing
             # was mutated).
-            m = maybe_u8.astype(bool)
-            if self._confirm_maybe_ids(
-                pack_keys(events["id_lo"][m], events["id_hi"][m])
-            ):
+            with tracer.span("sm.ct.dupcheck"):
+                m = maybe_u8.astype(bool)
+                hard = self._confirm_maybe_ids(
+                    pack_keys(events["id_lo"][m], events["id_hi"][m])
+                )
+            if hard:
                 return None
         ts = np.uint64(timestamp) - np.uint64(n) + 1 + np.arange(n, dtype=np.uint64)
         b, host_code_p = self._device_batch(events, ts, dr_slots, cr_slots, host_code)
