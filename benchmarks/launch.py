@@ -1,12 +1,14 @@
-"""Starting, watching and stopping the server a run drives.
+"""Starting, watching and stopping the servers a run drives: as many as
+the configuration's `replica_count` says, one chip each.
 
 Copies of `chip_smoke.py`'s launcher pieces (`Servers`, `free_ports`,
-`require_tpu`, `format_file`), changed in one way: the child is
-`benchmarks/serve.py`, which wraps `cli.py start` so that the process
-holding the chip can be asked for its trace, its compile count and its
-memory (`cli.spawn_replica` hard-codes `-m tigerbeetle_tpu.cli`). The
-parent never imports JAX: the device is the one the child names on its
-`listening` line.
+`require_tpu`, `format_file`, and for a cluster `CHIP_ENV`, `chips_held`,
+`probe_devices`), changed in one way: the child is `benchmarks/serve.py`,
+which wraps `cli.py start` so that the process holding the chip can be
+asked for its trace, its compile count and its memory
+(`cli.spawn_replica` hard-codes `-m tigerbeetle_tpu.cli`). The parent
+never imports JAX: a device is the one a child names on its `listening`
+line, and the chips of a cluster are told apart from outside.
 """
 
 from __future__ import annotations
@@ -44,6 +46,77 @@ def free_ports(n: int) -> list:
     return ports
 
 
+# Each replica of a cluster gets ONE chip from outside, through libtpu's
+# process-visibility environment (the program has no device option). Left
+# alone, the first process claims all four chips and the next one fails with
+# "The TPU is already in use"; TPU_VISIBLE_CHIPS alone is not enough on
+# libtpu 0.0.34 (chip_smoke.py, PR 21).
+CHIP_ENV = {"TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1", "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
+def format_args(path: str, config: str, replica: int, replica_count: int) -> list:
+    return [sys.executable, "-m", "tigerbeetle_tpu.cli", "format", f"--config={config}",
+            f"--replica={replica}", f"--replica-count={replica_count}", path]
+
+
+def start_args(ports: list, replica: int, start: dict, metrics_port: int, path: str) -> list:
+    """What `cli.py start` is given: every replica's address, which of them
+    this one is, and the metrics port in the traced run only (0: the tracer
+    stays off)."""
+    args = ["--addresses=" + ",".join(f"127.0.0.1:{p}" for p in ports),
+            f"--replica={replica}", f"--config={start['config']}",
+            f"--backend={start['backend']}"]
+    if metrics_port:
+        args.append(f"--metrics-port={metrics_port}")
+    return [*args, path]
+
+
+def chip_env(replica: int, replica_count: int) -> dict:
+    """Added to a child's environment: nothing for the one replica of a
+    one-chip cell (it takes the chip it finds), chip `replica` and no other
+    for a replica of a cluster."""
+    if replica_count == 1:
+        return {}
+    return {**CHIP_ENV, "TPU_VISIBLE_CHIPS": str(replica)}
+
+
+def chips_held(pid: int) -> set:
+    """The /dev/vfio/<n> chips a process has open. From inside, every
+    one-chip process sees the same device (id 0, coords 0,0,0): only the
+    device node tells the chips apart."""
+    held = set()
+    for fd in os.listdir(f"/proc/{pid}/fd"):
+        try:
+            target = os.readlink(f"/proc/{pid}/fd/{fd}")
+        except OSError:
+            continue  # closed while we looked
+        if target.startswith("/dev/vfio/") and target != "/dev/vfio/vfio":
+            held.add(target)
+    return held
+
+
+def probe_devices() -> dict:
+    """What a fresh process sees when nothing narrows its view: run after
+    every replica has let go of its chip (this process stays off JAX, and
+    a chip belongs to one process at a time)."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax, json; d = jax.devices(); print(json.dumps({"
+         "'platform': d[0].platform, 'device_kind': d[0].device_kind, "
+         "'device_count': len(d)}))"],
+        capture_output=True, text=True, cwd=REPO)
+    if out.returncode != 0:
+        raise Failure("the host could not be asked for its devices: " + out.stderr[-1000:])
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def require_distinct_chips(held: list) -> None:
+    """`held`: per replica, the set `chips_held` found."""
+    if any(len(h) != 1 for h in held) or len(set().union(*held)) != len(held):
+        raise Failure(f"the {len(held)} replicas do not hold {len(held)} distinct chips: "
+                      f"{[sorted(h) for h in held]}")
+
+
 def require_tpu(device: dict, chips: int) -> None:
     if device["platform"] != "tpu" or device["device_count"] != chips:
         raise Failure(
@@ -66,11 +139,9 @@ def load_shims() -> None:
         raise Failure(f"native shims did not build and load: {loaded}")
 
 
-def format_file(path: str, config: str) -> None:
-    subprocess.run(
-        [sys.executable, "-m", "tigerbeetle_tpu.cli", "format",
-         f"--config={config}", "--replica=0", "--replica-count=1", path],
-        check=True, cwd=REPO, stdout=subprocess.DEVNULL)
+def format_file(path: str, config: str, replica: int = 0, replica_count: int = 1) -> None:
+    subprocess.run(format_args(path, config, replica, replica_count),
+                   check=True, cwd=REPO, stdout=subprocess.DEVNULL)
 
 
 def durable_mode(path: str) -> str:
@@ -83,24 +154,65 @@ def durable_mode(path: str) -> str:
         return "buffered write + fdatasync (the file system refuses O_DIRECT)"
 
 
-class Server:
-    """The child, its stdout as a queue of lines, and a watchdog: a child
-    that dies while the run needs it, or a run that outlives its
-    deadline, ends the run AT ONCE with the child's stderr."""
+class Watchdog:
+    """One watch over every child of a run: a child that dies while the
+    run needs it, or a run that outlives its deadline, ends the run AT ONCE
+    with every child's stderr."""
 
-    def __init__(self, workdir: str, deadline_s: float, child: str = SERVE):
+    def __init__(self, workdir: str, deadline_s: float):
         self.workdir = workdir
+        self.servers = []
+        self._deadline = time.monotonic() + deadline_s
+        threading.Thread(target=self._watch, daemon=True).start()
+
+    def disarm_if_all_stopped(self) -> None:
+        if not any(s.expected_alive for s in self.servers):
+            self._deadline = float("inf")  # the watchdog has nothing left to end
+
+    def _watch(self) -> None:
+        while True:
+            time.sleep(0.25)
+            servers = list(self.servers)
+            dead = [s for s in servers if s.expected_alive and s.proc is not None
+                    and s.proc.poll() is not None]
+            late = time.monotonic() > self._deadline
+            if not dead and not late:
+                continue
+            for s in dead:
+                say(f"FAIL: {s.name} exited with code {s.proc.returncode} "
+                    "while the run needed it")
+            if not dead:
+                say("FAIL: the run outlived its deadline")
+            for s in servers:
+                say(f"--- {s.name}'s stderr (its end):\n{s.stderr_tail()}")
+            for s in servers:
+                if s.proc is not None and s.proc.poll() is None:
+                    s.proc.kill()
+                    s.proc.wait(timeout=60)
+            shutil.rmtree(self.workdir, ignore_errors=True)
+            os._exit(1)
+
+
+class Server:
+    """One child under the run's watchdog, its stdout as a queue of lines."""
+
+    def __init__(self, watchdog: Watchdog, child: str = SERVE, name: str = "the server",
+                 stderr_name: str = "server.stderr"):
+        self.workdir = workdir = watchdog.workdir
         self.child = child
+        self.name = name
         self.proc = None
         self.expected_alive = False
         self.answers = {}  # request word -> queue of its answers
         self._answers_lock = threading.Lock()
-        self.stderr_path = os.path.join(workdir, "server.stderr")
-        self._deadline = time.monotonic() + deadline_s
+        self.stderr_path = os.path.join(workdir, stderr_name)
         self._listening: "queue.Queue[str]" = queue.Queue()
-        threading.Thread(target=self._watch, daemon=True).start()
+        self.watchdog = watchdog
+        watchdog.servers.append(self)
 
-    def start(self, args: list) -> dict:
+    def start(self, args: list, chip: dict = None) -> dict:
+        """`chip`: what `chip_env` gives this replica (nothing for the one
+        replica of a one-chip cell)."""
         from tigerbeetle_tpu import cli
 
         # Cache every program, also those that compile in under a second
@@ -109,7 +221,7 @@ class Server:
         # What the program drops in the temporary directory (flight-recorder
         # dumps) goes where the run removes it.
         env = {"JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
-               **os.environ, "TMPDIR": self.workdir}
+               **os.environ, "TMPDIR": self.workdir, **(chip or {})}
         with open(self.stderr_path, "ab") as err:
             self.proc = subprocess.Popen(
                 [sys.executable, self.child, *args], cwd=REPO, env=env,
@@ -138,9 +250,9 @@ class Server:
         try:
             answer = self._answers_of(request.split()[0]).get(timeout=timeout)
         except queue.Empty:
-            raise Failure(f"the server did not answer '{request}'") from None
+            raise Failure(f"{self.name} did not answer '{request}'") from None
         if "error" in answer:
-            raise Failure(f"the server could not '{request}': {answer['error']}")
+            raise Failure(f"{self.name} could not '{request}': {answer['error']}")
         return answer
 
     def stderr_tail(self, nbytes: int = 3000) -> str:
@@ -152,33 +264,14 @@ class Server:
         except OSError:
             return ""
 
-    def stop(self) -> None:
+    def stop(self, kill: bool = False) -> None:
+        """`kill`: SIGKILL, what a crashed machine looks like to its peers."""
         self.expected_alive = False
-        self._deadline = float("inf")  # the watchdog has nothing left to end
+        self.watchdog.disarm_if_all_stopped()
         if self.proc is not None and self.proc.poll() is None:
-            self.proc.terminate()
+            self.proc.kill() if kill else self.proc.terminate()
             try:
                 self.proc.wait(timeout=60)
             except subprocess.TimeoutExpired:
                 self.proc.kill()
                 self.proc.wait(timeout=60)
-
-    def _watch(self) -> None:
-        while True:
-            time.sleep(0.25)
-            dead = (self.expected_alive and self.proc is not None
-                    and self.proc.poll() is not None)
-            late = time.monotonic() > self._deadline
-            if not dead and not late:
-                continue
-            if dead:
-                say(f"FAIL: the server exited with code {self.proc.returncode} "
-                    "while the run needed it")
-            else:
-                say("FAIL: the run outlived its deadline")
-            say(f"--- the server's stderr (its end):\n{self.stderr_tail()}")
-            if self.proc is not None and self.proc.poll() is None:
-                self.proc.kill()
-                self.proc.wait(timeout=60)
-            shutil.rmtree(self.workdir, ignore_errors=True)
-            os._exit(1)

@@ -5,7 +5,11 @@ start, so only a difference is a window's number).
 A metric file with `"reader": "counters"` gives:
 
   count   the counters whose differences are added up
-  per     the counter whose difference is the denominator
+  per     the counter whose difference is the denominator; without it the
+          value is the sum itself, a count of what happened in the window
+  absent_is_zero  those of `count` that exist only once the thing has
+          happened (a view change, a link to a peer that is never dialled):
+          absent from the second page, such a one counts 0
   scale   multiplied in (default 1)
 
 A counter absent from the second page, or a denominator that did not move
@@ -22,8 +26,10 @@ FAMILY = "tbtpu_events_total"
 def read(spec: dict, ctx: dict):
     if "scrape_after" not in ctx:
         return None
+    zero = set(spec.get("absent_is_zero", ()))
     counts = [spans.delta(ctx, FAMILY, e) for e in spec["count"]]
-    per = spans.delta(ctx, FAMILY, spec["per"])
+    counts = [0.0 if c is None and e in zero else c for c, e in zip(counts, spec["count"])]
+    per = spans.delta(ctx, FAMILY, spec["per"]) if "per" in spec else 1.0
     if any(c is None for c in counts) or not per:
         return None
     return sum(counts) / per * float(spec.get("scale", 1.0))

@@ -12,6 +12,14 @@ sample of the window's transfers back; stop the server; replay every
 answered request through the plain reference (`benchmarks/reference.py`)
 and compare. The last line of stdout is the result.
 
+A configuration whose `replica_count` is n > 1 gets what it states: n data
+files, n servers in parallel, each on a chip of its own, sessions that
+find the primary. Its read-back has a second half: the primary is killed
+(SIGKILL) and every balance and the sample are read again from the
+replicas that are left, which must answer in a later view; those rows are
+the ones compared. The traced run scrapes every replica's `/metrics`; the
+device trace and the metrics that name no `page` are the primary's.
+
 With `--trace 0` the server runs without its tracer and the metrics are
 the end-to-end ones. With `--trace 1` the server serves `/metrics`, which
 is scraped at the window's two ends, the device is traced for some
@@ -41,6 +49,7 @@ import shutil  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+from concurrent.futures import ThreadPoolExecutor  # noqa: E402
 import urllib.request  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -58,6 +67,11 @@ TRACE_STOP_TIMEOUT_S = 240.0  # collecting and writing the trace
 PHASE_GAP_S = 0.5  # the line every run prints of its phase; the metric files have their own
 DRAIN_TIMEOUT_S = 300.0  # past the window's close, for the requests in flight: late is not wrong
 REQUEST_TIMEOUT_S = 900.0  # one replica never drops a request; a cold compile is long
+# (a cluster drops what a backup is sent: its configuration gives `client_timeout_s`)
+SURVIVOR_TIMEOUT_S = 10.0  # after the kill everything is compiled and the dead address refuses
+ELECTION_PROBE_S = 1.0  # a try at registering with a cluster: how long it had no primary, to the second
+ELECTION_TIMEOUT_S = 120.0  # no primary by then: the run fails
+PIPELINE_SLACK = 8  # prepares in flight at a scrape: a page is not a snapshot
 DEADLINE_S = 1150.0  # a first run in a checkout compiles; the watchdog ends anything longer
 
 
@@ -66,9 +80,13 @@ def load_json(*parts: str) -> dict:
         return json.load(f)
 
 
-def load_cell(workload: str) -> tuple:
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
+def load_cell(workload: str, manifest=None) -> tuple:
+    """`manifest`: BENCHMARK.json as a rehearsal or test under
+    benchmarks/tests/ hands it in (with a cell the file does not hold
+    yet); the command line has none."""
+    if manifest is None:
+        with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+            manifest = json.load(f)
     cells = {w["name"]: w for w in manifest["workloads"]}
     if workload not in cells:
         raise SystemExit(f"no workload {workload!r} in BENCHMARK.json: {sorted(cells)}")
@@ -87,30 +105,71 @@ def scrape(port: int) -> str:
         return r.read().decode()
 
 
+def scrape_all(mports: list) -> list:
+    """Every replica's page, parsed, in replica order."""
+    from benchmarks.readers import spans
+
+    return [spans.parse(scrape(p)) for p in mports]
+
+
+def cpu_seconds(servers: list) -> list:
+    """Processor time each server has had so far (all its threads; the
+    child's own `time.process_time()`), in replica order, and this
+    process's, the load generator's, last. (`/proc/stat` reads 0 on the
+    chip's machine, a sandbox: the processes are asked themselves.)"""
+    return [s.ask("cpu")["seconds"] for s in servers] + [time.process_time()]
+
+
+def read_metric(spec: dict, ctx: dict):
+    """One per-layer metric on the page its file names: `primary` (the
+    default: the replica whose chip is traced; the only one a one-replica
+    cell has), `backups_max` (the larger of the other replicas' readings)
+    or `all_sum`."""
+    reader = importlib.import_module("benchmarks.readers." + spec["reader"])
+    page = spec.get("page", "primary")
+    if page == "primary":
+        return reader.read(spec, ctx)
+    pages = [(b, a) for i, (b, a) in enumerate(zip(ctx.get("pages_before", []),
+                                                   ctx.get("pages_after", [])))
+             if page == "all_sum" or i != ctx["primary"]]
+    values = [reader.read(spec, {**ctx, "scrape_before": b, "scrape_after": a})
+              for b, a in pages]
+    values = [v for v in values if v is not None]
+    if not values:
+        return None
+    return {"backups_max": max, "all_sum": sum}[page](values)
+
+
 # --- the load: prefill, window, drain ------------------------------------------
 
 
-async def drive(load, traffic: dict, seconds: float, trace_dir, server, mport, ctx: dict):
-    """Returns (t0, t1): the window's two ends on this process's clock."""
-    from benchmarks.readers import spans
-
+async def drive(load, traffic: dict, seconds: float, trace_dir, servers, mports, ctx: dict):
+    """Returns (t0, t1): the window's two ends on this process's clock.
+    `mports`: every replica's metrics port in the traced run, else none."""
     loop = asyncio.get_running_loop()
     await load.start()
     await load.until_completed(int(traffic["prefill_batches"]))
     if load.errors:
         return 0.0, 0.0
+    # The primary is the replica that answers the sessions (of one replica: that one).
+    ctx["primary"] = primary = load.primary()
+    server = servers[primary]
     # The sessions do not pause: the window is cut out of a running system.
     ctx["monitor_before"] = await loop.run_in_executor(None, server.ask, "compiles")
-    if mport:
-        ctx["scrape_before"] = spans.parse(await loop.run_in_executor(None, scrape, mport))
+    if mports:
+        ctx["pages_before"] = await loop.run_in_executor(None, scrape_all, mports)
+        ctx["scrape_before"] = ctx["pages_before"][primary]
+    ctx["cpu_before"] = await loop.run_in_executor(None, cpu_seconds, servers)
     t0 = time.perf_counter()
     tracing = asyncio.ensure_future(
         trace_slices(loop, server, trace_dir, traffic, seconds, t0, ctx))
     await asyncio.sleep(max(0.0, t0 + seconds - time.perf_counter()))
     t1 = t0 + seconds
+    ctx["cpu_after"] = await loop.run_in_executor(None, cpu_seconds, servers)
     ctx["monitor_after"] = await loop.run_in_executor(None, server.ask, "compiles")
-    if mport:
-        ctx["scrape_after"] = spans.parse(await loop.run_in_executor(None, scrape, mport))
+    if mports:
+        ctx["pages_after"] = await loop.run_in_executor(None, scrape_all, mports)
+        ctx["scrape_after"] = ctx["pages_after"][primary]
     # An answer that comes late is late, not wrong: wait for it, minutes if a
     # first run in a checkout compiles a store shape just then.
     await load.drain(timeout=DRAIN_TIMEOUT_S)
@@ -200,15 +259,109 @@ def compare(generator, ledger, records: list, sample: set, read_back: dict,
     }
 
 
+def cluster_problems(ctx: dict, replicas: int, tag: str) -> list:
+    """What a traced run can show of "a reply is sent only after its prepare
+    is durable in the WAL of a replication quorum": on the primary's page,
+    the quorum completions over all peers (its own self-ack among them) rise
+    by as many as the commits do, and `vsr.replication.lag` (one sample per
+    BACKUP's ack) takes at least as many samples: a completion is the second
+    of three possible acks, so at least one of the two is a backup's. And no
+    replica may bail out of a device kernel. A window that held a view
+    change has no one primary's page to read this from (the old primary's
+    open windows are closed unstamped, `peerstats.close_all`), so the
+    guarantee cannot be examined: that traced run fails, like one with a
+    bail batch."""
+    from benchmarks.launch import say
+    from benchmarks.readers import spans
+
+    problems = []
+    pages = [{"scrape_before": b, "scrape_after": a}
+             for b, a in zip(ctx["pages_before"], ctx["pages_after"])]
+    view_changes = sum(spans.delta(page, "tbtpu_events_total", f"vsr.view_change.{how}") or 0
+                       for page in pages for how in ("elected", "adopted"))
+    commits = spans.delta(ctx, "tbtpu_events_total", "vsr.commits") or 0
+    completions = sum(spans.delta(ctx, "tbtpu_events_total", f"vsr.peer.{r}.quorum_complete")
+                      or 0 for r in range(replicas))
+    acks = spans.delta(ctx, "tbtpu_span_seconds_count", "vsr.replication.lag") or 0
+    say(f"{tag} on the primary (replica {ctx['primary']}) in the window: {commits:.0f} commits, "
+        f"{completions:.0f} quorum completions, {acks:.0f} acks from backups")
+    if view_changes:
+        problems.append(f"a view change inside the traced window ({view_changes:.0f} elected or "
+                        "adopted): the quorum's counts cannot be held against each other")
+    elif not commits or abs(completions - commits) > PIPELINE_SLACK:
+        problems.append(f"{completions:.0f} quorum completions for {commits:.0f} commits")
+    elif acks < commits - PIPELINE_SLACK:
+        problems.append(f"{acks:.0f} acks from backups for {commits:.0f} commits")
+    for i, page in enumerate(pages):
+        bails = spans.delta(page, "tbtpu_events_total", "sm.route.bail_batches") or 0
+        backup_commits = spans.delta(page, "tbtpu_events_total", "vsr.commits") or 0
+        if i != ctx["primary"]:
+            say(f"{tag} replica {i} (a backup) committed {backup_commits:.0f} in the window")
+        if bails > 0:
+            problems.append(f"sm.route.bail_batches rose by {bails:.0f} on replica {i}")
+    return problems
+
+
 # --- one run ---------------------------------------------------------------------------
 
 
+def view_client(addresses: list):
+    """`tigerbeetle_tpu.client.Client` (it registers at once), remembering
+    which replica answered last and in which view: the reply's header says both."""
+    from tigerbeetle_tpu.client import Client
+
+    class ViewClient(Client):
+        view = replica = -1
+
+        def _roundtrip(self, operation, body):
+            reply = super()._roundtrip(operation, body)
+            self.view, self.replica = int(reply.header["view"]), int(reply.header["replica"])
+            return reply
+
+    return ViewClient(addresses)
+
+
+def first_reply(addresses: list, since: float, what: str):
+    """A new client registered with a cluster that may have no primary yet,
+    or not the one the client tries first (what a backup is sent is
+    forwarded and its answer lost): short tries walk it on to the primary,
+    and say to the second how long that took."""
+    from benchmarks.launch import Failure
+    from tigerbeetle_tpu.client import Client
+
+    Client.REQUEST_TIMEOUT = ELECTION_PROBE_S
+    while True:
+        try:
+            return view_client(addresses)
+        except Exception as e:  # noqa: BLE001 - ClientError: no primary yet
+            if time.perf_counter() - since > ELECTION_TIMEOUT_S:
+                raise Failure(f"no replica answered {ELECTION_TIMEOUT_S:g} s after "
+                              f"{what}: {e!r}") from None
+
+
+def read_back_from(client, generator, config: dict, sample: set) -> tuple:
+    """Every balance, and the sampled batches by id: (transfers by batch,
+    [(ids, accounts)], seconds the transfers took)."""
+    t = time.perf_counter()
+    transfers = {(s, k): client.lookup_transfers(generator.ids(s, k))
+                 for s, k in sorted(sample)}
+    transfers_s = time.perf_counter() - t
+    n_batch = int(config["batch"])
+    accounts = []
+    for start in range(1, int(config["accounts"]) + 1, n_batch):
+        ids = np.arange(start, min(start + n_batch, int(config["accounts"]) + 1),
+                        dtype=np.uint64)
+        accounts.append((ids, client.lookup_accounts([int(v) for v in ids])))
+    return transfers, accounts, transfers_s
+
+
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
-             expect=None, overrides=None, child=None, device_prefix="/device:TPU") -> int:
-    """`expect`, `overrides`, `child` and `device_prefix` are for the
-    rehearsals, controls and fault tests under benchmarks/tests/, which
-    have to drive this whole run on a CPU at a tiny size, or against a
-    server broken on purpose; the command line has no switch for them."""
+             expect=None, overrides=None, child=None, device_prefix="/device:TPU",
+             distinct=None) -> int:
+    """`expect`, `overrides`, `child`, `device_prefix` and `distinct` are
+    for the rehearsals, controls and fault tests under benchmarks/tests/,
+    which have to drive this whole run on a CPU at a tiny size, or against
+    a server broken on purpose; the command line has no switch for them."""
     from benchmarks import launch
     from benchmarks.launch import Failure, say
     from benchmarks.readers import spans
@@ -216,40 +369,92 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     from benchmarks.reference import Ledger
     from benchmarks.sessions import Load
 
-    manifest, cell, config, traffic = load_cell(workload)
+    manifest, cell, config, traffic = load_cell(workload, (overrides or {}).get("manifest"))
     config = {**config, **(overrides or {}).get("config", {})}
     traffic = {**traffic, **(overrides or {}).get("traffic", {})}
     expect = expect or launch.require_tpu
+    distinct = distinct or launch.chips_held
     peaks_table = load_json("peaks.json")
+    replicas = int(config.get("replica_count", 1))
+    if replicas > cell["chips"]:
+        raise SystemExit(f"{workload}: {replicas} replicas do not fit on {cell['chips']} chip(s)")
+    request_timeout_s = float(config.get("client_timeout_s", REQUEST_TIMEOUT_S))
 
     launch.load_shims()
+    shims_s = time.perf_counter() - T_PROCESS_START
     from tigerbeetle_tpu.client import Client
 
     workdir = tempfile.mkdtemp(prefix="tbtpu_bench_")
-    server = launch.Server(workdir, DEADLINE_S, child or launch.SERVE)
+    watchdog = launch.Watchdog(workdir, DEADLINE_S)
+    if replicas == 1:
+        servers = [launch.Server(watchdog, child or launch.SERVE)]
+    else:
+        servers = [launch.Server(watchdog, child or launch.SERVE, f"replica {i}",
+                                 f"server.{i}.stderr") for i in range(replicas)]
+
+    def stderr_tails() -> str:
+        return "\n".join(f"--- {s.name}'s stderr (its end):\n{s.stderr_tail()}"
+                         for s in servers)
+
     try:
-        path = os.path.join(workdir, "0.tigerbeetle")
-        launch.format_file(path, config["start"]["config"])
-        port, mport = launch.free_ports(2)
-        args = [f"--addresses=127.0.0.1:{port}", "--replica=0",
-                f"--config={config['start']['config']}",
-                f"--backend={config['start']['backend']}"]
-        if trace:
-            args.append(f"--metrics-port={mport}")  # the tracer is on only in the traced run
-        device = server.start([*args, path])
+        paths = [os.path.join(workdir, f"{i}.tigerbeetle") for i in range(replicas)]
+        for i, path in enumerate(paths):
+            launch.format_file(path, config["start"]["config"], i, replicas)
+        formatted_s = time.perf_counter() - T_PROCESS_START
+        ports = launch.free_ports(2 * replicas)
+        ports, mports = ports[:replicas], (ports[replicas:] if trace else [])
+        # (the tracer is on only in the traced run)
+        commands = [launch.start_args(ports, i, config["start"], mports[i] if trace else 0,
+                                      paths[i]) for i in range(replicas)]
+        with ThreadPoolExecutor(replicas) as pool:  # in parallel: each takes 15 s to reach its chip
+            devices = list(pool.map(
+                lambda i: servers[i].start(commands[i], launch.chip_env(i, replicas)),
+                range(replicas)))
+        device = devices[0]
         listening_s = time.perf_counter() - T_PROCESS_START
+        boot = servers[0].ask("compiles")
         tag = (f"[{device['platform']} {device['device_kind']!r} x{device['device_count']}]")
-        say(f"{tag} {workload} seed {seed}: listening after {listening_s:.1f} s; "
-            f"durable writes: {launch.durable_mode(path)}")
-        expect(device, cell["chips"])
+        say(f"{tag} {workload} seed {seed}: listening after {listening_s:.1f} s (imports and shims "
+            f"{shims_s:.1f} s, format {formatted_s - shims_s:.1f} s, the server's start "
+            f"{listening_s - formatted_s:.1f} s); durable writes: {launch.durable_mode(paths[0])}")
+        # Where the server's start went (replica 0's): its stages end when the process
+        # began, when it had imported JAX and the program, when its first and its last
+        # program of the start were ready (compiled or read from the cache), when it listened.
+        ready = [t for _name, t, _took, _how in boot["names"]] or [boot["imported"]]
+        marks = [formatted_s + T_PROCESS_START, boot["started"], boot["imported"],
+                 ready[0], ready[-1], listening_s + T_PROCESS_START]
+        say(f"{tag} the server's start, stage by stage: " + ", ".join(
+            f"{what} {b - a:.1f} s" for what, a, b in zip(
+                ("spawn", "imports", "to its first program (device init, storage, tables)",
+                 f"its {len(ready)} programs", "open and listen"), marks, marks[1:])))
+        held = []
+        if replicas == 1:
+            expect(device, cell["chips"])
+        else:
+            # One chip each, three chips in all: told apart from outside.
+            for i, d in enumerate(devices):
+                expect(d, 1)
+                held.append(distinct(servers[i].proc.pid))
+                say(f"{tag} replica {i} (pid {servers[i].proc.pid}): {d['platform']} "
+                    f"{d['device_kind']!r} x{d['device_count']}, holds {sorted(held[-1])}")
+            launch.require_distinct_chips(held)
+            if len({(d["platform"], d["device_kind"]) for d in devices}) != 1:
+                raise Failure(f"the replicas run on different devices: {devices}")
         if device["device_kind"] not in peaks_table and device["platform"] == "tpu":
             raise Failure(f"no peaks for device kind {device['device_kind']!r} "
                           "in benchmarks/peaks.json")
 
         generator = importlib.import_module(
             "benchmarks.generators." + traffic["generator"]).Generator(config, traffic, seed)
-        Client.REQUEST_TIMEOUT = REQUEST_TIMEOUT_S
-        client = Client([("127.0.0.1", port)])
+        addresses = [("127.0.0.1", p) for p in ports]
+        if replicas == 1:
+            Client.REQUEST_TIMEOUT = request_timeout_s
+            client = view_client(addresses)
+        else:
+            # A cluster started in parallel comes up through a view change, and the client's
+            # first try goes to replica 0: 30 s of set-up if it waits the time-out out.
+            client = first_reply(addresses, time.perf_counter(), "the cluster began to listen")
+            Client.REQUEST_TIMEOUT = request_timeout_s
         t = time.perf_counter()
         for acc in generator.account_batches():
             got = client.create_accounts(acc)
@@ -258,17 +463,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         say(f"{tag} {config['accounts']:,} accounts registered in "
             f"{time.perf_counter() - t:.1f} s")
 
-        load = Load(("127.0.0.1", port), int(traffic["sessions"]), generator.batch,
-                    REQUEST_TIMEOUT_S)
+        load = Load(addresses, int(traffic["sessions"]), generator.batch, request_timeout_s)
         ctx = {"config": config, "traffic": traffic,
                "peaks": peaks_table.get(device["device_kind"], {})}
         trace_dir = os.path.join(workdir, "trace") if trace else None
-        t0, t1 = asyncio.run(drive(load, traffic, seconds, trace_dir, server,
-                                   mport if trace else 0, ctx))
+        t0, t1 = asyncio.run(drive(load, traffic, seconds, trace_dir, servers, mports, ctx))
         if load.errors and t1 == 0.0:
             raise Failure("a session gave up during prefill: " + "; ".join(load.errors[:3]))
         setup_s = t0 - T_PROCESS_START
-        memory = server.ask("memory")
+        memory = max(int(s.ask("memory")["memory_peak_bytes"]) for s in servers)
 
         records = load.records
         answered = [r for r in records if r.reply is not None]
@@ -288,18 +491,46 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                                replace=False).tolist()) | ({len(by_done) - 1} if by_done else set())
         sample = {(by_done[i].session, by_done[i].seq) for i in picks}
         t = time.perf_counter()
-        read_back = {(s, k): client.lookup_transfers(generator.ids(s, k))
+        survivors_differ = old_view_answers = None
+        if replicas == 1:
+            read_back, accounts_got, transfers_s = read_back_from(
+                client, generator, config, sample)
+        else:
+            # The cluster first (the sample only), then a quorum WITHOUT the primary:
+            # what the survivors serve is what gets compared.
+            first = {(s, k): client.lookup_transfers(generator.ids(s, k))
                      for s, k in sorted(sample)}
-        transfers_s = time.perf_counter() - t
-        n_batch = int(config["batch"])
-        accounts_got = []
-        for start in range(1, int(config["accounts"]) + 1, n_batch):
-            ids = np.arange(start, min(start + n_batch, int(config["accounts"]) + 1),
-                            dtype=np.uint64)
-            accounts_got.append((ids, client.lookup_accounts([int(v) for v in ids])))
+            primary, view = client.replica, client.view
+            if primary != ctx["primary"]:
+                say(f"{tag} the primary was replica {ctx['primary']} at the window's start "
+                    f"and is replica {primary} now")
+            client.close()
+            say(f"{tag} killing the primary (replica {primary} in view {view}, SIGKILL)")
+            killed = time.perf_counter()
+            servers[primary].stop(kill=True)
+            # Everything is compiled by now and the dead address refuses connections.
+            client = first_reply(addresses, killed, "the primary was killed")
+            say(f"{tag} first reply from the remaining {replicas - 1} after "
+                f"{time.perf_counter() - killed:.1f} s (tries of {ELECTION_PROBE_S:g} s): "
+                f"replica {client.replica} in view {client.view}")
+            Client.REQUEST_TIMEOUT = SURVIVOR_TIMEOUT_S
+            read_back, accounts_got, transfers_s = read_back_from(
+                client, generator, config, sample)
+            old_view_answers = int(client.view <= view or client.replica == primary)
+            survivors_differ = sum(rows_differing(read_back[key], first[key]) for key in first)
         read_back_s = time.perf_counter() - t
         client.close()
-        server.stop()  # the chip is free from here on
+        for s in servers:
+            s.stop()  # the chips are free from here on
+        for s in servers:  # what a child broken on purpose says of itself (tests/broken_serve.py)
+            for line in s.stderr_tail(1 << 16).splitlines():
+                if line.startswith("NOTE: "):
+                    say(f"{tag} {s.name}: {line[6:]}")
+        host = None
+        if replicas > 1:
+            host = launch.probe_devices()
+            say(f"{tag} the host, once the replicas let go: {host}")
+            expect(host, cell["chips"])
 
         t = time.perf_counter()
         verdict = compare(generator, Ledger(int(config["accounts"])), records, sample,
@@ -331,7 +562,20 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             f"{len(window) - n} in {seconds - at:.1f} s; "
             + (f"the first {PHASE_GAP_S:g} s without a reply came after batch "
                f"{len(prefill) + n} of the run" if at < seconds else
-               f"no {PHASE_GAP_S:g} s without a reply up to batch {len(prefill) + n} of the run"))
+               f"no {PHASE_GAP_S:g} s without a reply up to batch {len(prefill) + n} of the run")
+            + (f"; views in the window's replies: {by_done[0].view} at its start, "
+               f"{by_done[-1].view} at its end"
+               + (" (A VIEW CHANGE INSIDE THE WINDOW: late is late, the numbers stand)"
+                  if by_done[0].view != by_done[-1].view else "")
+               + f"; {sum(x.steered for x in load.sessions)} sessions steered by a hello's "
+               f"answer, {sum(x.moves for x in load.sessions)} moves to the next address"
+               if replicas > 1 else ""))
+        cores = [(a - b) / seconds for a, b in zip(ctx["cpu_after"], ctx["cpu_before"])]
+        ctx["cores"] = os.cpu_count()
+        say(f"{tag} processor time over the window, in cores: "
+            + ", ".join(f"replica {i}{' (primary)' if i == ctx['primary'] and replicas > 1 else ''} "
+                        f"{c:.2f}" for i, c in enumerate(cores[:-1]))
+            + f", the load generator {cores[-1]:.2f}; the host has {ctx['cores']}")
         end_to_end = {
             "tx_per_s": sum(r.events for r in window) / seconds,
             "write_p50_ms": percentile(latencies, 0.50),
@@ -341,9 +585,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         ctx["window_records"] = window
         ctx["window"] = {"t0": t0, "seconds": seconds, "answered_before": len(prefill)}
 
+        # A cluster's device is the host's, as a fresh process finds it once the replicas
+        # have let go; the peak is the fullest replica's.
         device_out = {"platform": device["platform"], "kind": device["device_kind"],
-                      "count": device["device_count"],
-                      "memory_peak_bytes": int(memory["memory_peak_bytes"])}
+                      "count": (host or device)["device_count"], "memory_peak_bytes": memory}
+        if replicas > 1:
+            device_out.update(replicas=replicas, chips_held=sorted(set().union(*held)))
         result = {"correct": None, "attempted": attempted, "failed": failed}
         problems = []
         if trace:
@@ -363,13 +610,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             say(f"{tag} commit routes in the window: {routes}")
             if routes["bail"] > 0:
                 problems.append(f"sm.route.bail_batches rose by {routes['bail']} in the window")
+            if replicas > 1:
+                problems += cluster_problems(ctx, replicas, tag)
             metrics = {}
             for m in manifest["per_layer"]:
                 if workload not in m.get("workloads", [workload]):
                     continue
-                spec = load_json("layer_metrics", m["name"] + ".json")
-                value = importlib.import_module(
-                    "benchmarks.readers." + spec["reader"]).read(spec, ctx)
+                value = read_metric(load_json("layer_metrics", m["name"] + ".json"), ctx)
                 if value is not None:
                     metrics[m["name"]] = {"value": value, "unit": m["unit"]}
             if reduced:
@@ -389,6 +636,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             f"set-up ({before['cache_hits']} of them cache reads, {before['seconds']:.1f} s), "
             f"{after['compiles'] - before['compiles']} in the window {compiled_in_window}")
         say(f"{tag} end to end: " + ", ".join(f"{k} {v:.4f}" for k, v in end_to_end.items()))
+        if trace:  # said here too, so that a run that fails below still leaves its readings
+            say(f"{tag} per layer: " + ", ".join(f"{k} {v['value']:.6g}" for k, v in metrics.items()))
 
         # Each number compared, beside its limit (every comparison is exact: limit 0).
         compared = {
@@ -396,6 +645,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             "balance_mismatches": [verdict["balance_mismatches"], 0],
             "store_mismatches": [verdict["store_mismatches"], 0],
             "requests_never_answered": [len(lost) + len(load.errors), 0],
+            **({"survivor_rows_differing": [survivors_differ, 0],
+                "survivors_answered_in_old_view": [old_view_answers, 0]}
+               if replicas > 1 else {}),
             "code_events_compared": [verdict["code_events_compared"], None],
             "accounts_compared": [verdict["accounts_compared"], None],
             "transfers_read_back": [verdict["transfers_read_back"], None],
@@ -410,10 +662,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         return 0
     except Failure as e:
         say(f"FAIL: {e}")
-        say(f"--- the server's stderr (its end):\n{server.stderr_tail()}")
+        say(stderr_tails())
         return 1
     finally:
-        server.stop()
+        for s in servers:
+            s.stop()
         shutil.rmtree(workdir, ignore_errors=True)
 
 

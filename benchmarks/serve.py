@@ -16,8 +16,11 @@ on stdout by one line that starts with `BENCH ` and carries JSON:
 
     compiles            -> BENCH {"re": "compiles", "compiles": n, "cache_hits": m, "seconds": s,
                                   "names": [[program, when ready, seconds, "read"|"compiled"], ...
-                                            the last 256]}
+                                            the last 256],
+                                  "started": when this process began, "imported": when it had
+                                            imported JAX and the program}
     memory              -> BENCH {"re": "memory", "memory_peak_bytes": n}
+    cpu                 -> BENCH {"re": "cpu", "seconds": s}   processor time this process has had, all threads
     trace_start <dir>   -> BENCH {"re": "trace_start", "seconds": s}
     trace_stop          -> BENCH {"re": "trace_stop", "seconds": s}
 
@@ -34,6 +37,9 @@ import os
 import sys
 import threading
 import time
+
+T_STARTED = time.perf_counter()  # the machine's clock: the parent's too
+T_IMPORTED = [T_STARTED]  # when JAX and the program were imported
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -105,11 +111,14 @@ def control(monitor: Monitor) -> None:
                     answer("compiles", {"compiles": monitor.compiles,
                                         "cache_hits": monitor.cache_hits,
                                         "seconds": monitor.seconds,
-                                        "names": monitor.names[-256:]})
+                                        "names": monitor.names[-256:],
+                                        "started": T_STARTED, "imported": T_IMPORTED[0]})
             elif words[0] == "memory":
                 answer("memory", {"memory_peak_bytes": max(
                     (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                     for d in jax.local_devices())})
+            elif words[0] == "cpu":
+                answer("cpu", {"seconds": time.process_time()})
             elif words[0] == "trace_start":
                 # Device events and the runtime's own host spans only: the
                 # Python tracer would record every call of a busy server.
@@ -139,6 +148,7 @@ def main(argv) -> int:
     dispatch_log.addHandler(monitor)
     dispatch_log.setLevel(logging.DEBUG)
     dispatch_log.propagate = False
+    T_IMPORTED[0] = time.perf_counter()
     threading.Thread(target=control, args=(monitor,), daemon=True).start()
     return cli.main(["start", *argv])
 

@@ -1,8 +1,8 @@
 """Client sessions for the benchmark: real TCP, one request in flight each.
 
 A copy of `tigerbeetle_tpu/testing/loadgen.py`'s `_Session` and `LoadGen`
-cut to what a cell needs (one replica, no churn, no identity rotation)
-and mended where the original could not serve as a yardstick:
+cut to what a cell needs (no churn, no identity rotation) and mended
+where the original could not serve as a yardstick:
 
 - the traffic comes from a seeded generator handed in, never from a seed
   fixed here;
@@ -16,6 +16,16 @@ A closed loop: each session sends its next batch when the reply lands
 (the next batch is built while the reply is awaited, so a session's think
 time is the seal and the send). The original's open loop is not copied:
 no cell offers load at a fixed rate yet (PERF.md, Open questions).
+
+Against a cluster a session holds one connection, to the replica it
+believes primary: a reply only comes over a connection the PRIMARY holds
+for this client (a backup forwards the request and the answer is lost).
+The hello's PONG_CLIENT carries the replica's view, so one read steers a
+session to `view % replicas`; on a time-out or a lost connection it moves
+to the next address and resends the SAME request number, as
+`tigerbeetle_tpu.client.Client` does. A request's latency runs from its
+FIRST send. With one address none of this happens: the session resends to
+it for ever.
 """
 
 from __future__ import annotations
@@ -42,33 +52,44 @@ class Record:
     sent: float = 0.0  # 0.0: never sent
     done: float = 0.0  # 0.0: never answered
     reply: Optional[bytes] = None  # the reply's body (EVENT_RESULT pairs)
+    view: int = -1  # the view in the reply's header
 
     @property
     def latency(self) -> float:
         return self.done - self.sent
 
 
+class _Steered(Exception):
+    """A PONG_CLIENT named another replica as the view's primary."""
+
+
 class Session:
-    """One VSR client session on its own TCP connection."""
+    """One VSR client session on one TCP connection at a time."""
 
     CONNECT_RETRIES = 40
 
-    def __init__(self, address, request_timeout: float, cluster: int = 0):
-        self.address = address
+    def __init__(self, addresses: list, request_timeout: float, cluster: int = 0):
+        self.addresses = list(addresses)
+        self.target = 0  # the address this session believes primary
         self.request_timeout = request_timeout
         self.cluster = cluster
         self.client_id = secrets.randbits(127) | 1  # a session id, not traffic
         self.request = 0
         self.reader = self.writer = None
         self.resends = 0
+        self.moves = 0  # times the session went on to another address
+        self.steered = 0  # times a hello's answer named another replica as primary
         self.busy = 0
+        self.view = -1  # of the last reply
+        self.replica = -1  # that sent it
+        self._refused = -1  # the address that last refused a connection: never steered to
 
     async def connect(self) -> None:
         backoff, last = 0.05, None
         for _ in range(self.CONNECT_RETRIES):
             try:
                 self.reader, self.writer = await asyncio.open_connection(
-                    *self.address, limit=1 << 21)
+                    *self.addresses[self.target], limit=1 << 21)
                 hello = hdr.make_sealed(Command.PING_CLIENT, self.cluster,
                                         client=self.client_id)
                 self.writer.write(hello.to_bytes())
@@ -77,9 +98,16 @@ class Session:
             except OSError as e:
                 last = e
                 self.reader = self.writer = None
+                self._refused = self.target
+                self._move()  # a dead listener: the next address (the same, of one)
                 await asyncio.sleep(backoff)
                 backoff = min(backoff * 2, 1.0)
         raise ConnectionError(f"session could not connect: {last!r}")
+
+    def _move(self) -> None:
+        if len(self.addresses) > 1:
+            self.target = (self.target + 1) % len(self.addresses)
+            self.moves += 1
 
     def close(self) -> None:
         if self.writer is not None and self.writer.transport is not None:
@@ -94,15 +122,23 @@ class Session:
             h = msg.header
             if h["command"] == Command.EVICTION and h["client"] == self.client_id:
                 raise ConnectionError("session evicted")
+            if h["command"] == Command.PONG_CLIENT and h["client"] == self.client_id:
+                primary = int(h["view"]) % len(self.addresses)
+                if primary not in (self.target, self._refused):
+                    self.target = primary
+                    self.steered += 1
+                    raise _Steered
+                continue
             if h["client"] != self.client_id or h["request"] != request:
-                continue  # a pong, or a reply to an earlier send
+                continue  # a reply to an earlier send
             if h["command"] in (Command.REPLY, Command.BUSY):
                 return msg
 
     async def roundtrip(self, operation: int, body: bytes, on_sent=None):
         """Send, absorb BUSY with the client's own backoff, resend on a
         time-out or a lost connection (the same request number: the
-        primary answers a duplicate from its table)."""
+        primary answers a duplicate from its table), to the next address
+        where there is one."""
         self.request += 1
         request = self.request
         frame = hdr.make_sealed(
@@ -120,14 +156,21 @@ class Session:
                 await self.writer.drain()
                 reply = await asyncio.wait_for(self._read_reply(request),
                                                self.request_timeout)
+            except _Steered:
+                self.close()  # the hello's answer: no time was lost, nothing counted
+                continue
             except asyncio.TimeoutError:
                 self.resends += 1
                 if sends > 8:
                     raise
+                if len(self.addresses) > 1:
+                    self.close()
+                    self._move()
                 continue
             except (OSError, ConnectionResetError):
                 self.resends += 1
                 self.close()
+                self._move()
                 if sends > 8:
                     raise
                 continue
@@ -138,6 +181,8 @@ class Session:
                     raise TimeoutError("persistently BUSY")
                 await asyncio.sleep(busy_backoff_s(busy_retries))
                 continue
+            self.view, self.replica = int(reply.header["view"]), int(reply.header["replica"])
+            self._refused = -1
             return reply
 
     async def register(self) -> None:
@@ -150,9 +195,9 @@ class Load:
     `make(session, seq)` returns the events of that batch (a structured
     array); it is called in each session's own order."""
 
-    def __init__(self, address, sessions: int, make: Callable, request_timeout: float):
+    def __init__(self, addresses: list, sessions: int, make: Callable, request_timeout: float):
         self.make = make
-        self.sessions = [Session(address, request_timeout)
+        self.sessions = [Session(addresses, request_timeout)
                          for _ in range(sessions)]
         self.records: List[Record] = []
         self.completed = 0
@@ -188,6 +233,7 @@ class Load:
             return False
         rec.done = time.perf_counter()
         rec.reply = reply.body
+        rec.view = int(reply.header["view"])
         self.completed += 1
         self._progress.set()
         return True
@@ -202,6 +248,11 @@ class Load:
         await asyncio.gather(*[one(s) for s in self.sessions])
         self.tasks = [asyncio.ensure_future(self._closed(s))
                       for s in range(len(self.sessions))]
+
+    def primary(self) -> int:
+        """The replica that answered most sessions' last request."""
+        said = [s.replica for s in self.sessions if s.replica >= 0]
+        return max(set(said), key=said.count) if said else 0
 
     async def until_completed(self, batches: int) -> None:
         """Returns once `batches` requests have been answered (or a

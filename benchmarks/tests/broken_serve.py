@@ -13,6 +13,13 @@ upper reading of each compared number).
                    pending amounts it lost (a post or void underflows),
                    bails, and the host's serial path answers every batch
                    in its place, rightly and at a crawl.
+  backups_lossy_scatter  THE CONTROL of a cluster: `lossy_scatter` on every
+                   replica that is a BACKUP when it first traces its
+                   commit kernel; the primary is sound. Every code and every
+                   answer the primary gives is right; the balances the
+                   backups hold are not, and only a read-back from a quorum
+                   WITHOUT the primary can tell. Breaks "every replica
+                   commits every batch to the byte".
   chains_unlinked  THE CONTROL where batches carry linked chains: every
                    event is its own chain, so the links of a chain that
                    must roll back are applied, the shortcut that spares
@@ -46,7 +53,9 @@ def plant(fault: str) -> None:
     def mix(new, old, keep_new):
         return type(new)(*[jnp.where(keep_new(n), n, o) for n, o in zip(new, old)])
 
-    if fault == "lossy_scatter":
+    if fault in ("lossy_scatter", "backups_lossy_scatter"):
+        sound = u128.scatter_add
+
         def scatter_add(table, slots, values, mask):
             halves = u128.split_u16(values)
             halves = jnp.where(mask[:, None], halves, jnp.zeros_like(halves))
@@ -57,7 +66,31 @@ def plant(fault: str) -> None:
             new_table, over = u128.add(table, delta)
             return new_table, (over | delta_over)
 
-        u128.scatter_add = scatter_add
+        if fault == "lossy_scatter":
+            u128.scatter_add = scatter_add
+        else:
+            # Which scatter a replica's kernels get is settled when they are traced, at its
+            # first batch of transfers: by then the cluster has a primary.
+            from tigerbeetle_tpu.vsr import replica as vsr_replica
+
+            replicas, said, init = [], set(), vsr_replica.Replica.__init__
+
+            def remember(self, *args, **kw):
+                init(self, *args, **kw)
+                replicas.append(self)
+
+            def by_role(table, slots, values, mask):
+                me = replicas[-1]
+                lossy = not me.is_primary
+                note = (f"NOTE: backups_lossy_scatter: replica {me.replica} traces its kernel in "
+                        f"view {me.view} as {'a BACKUP: lossy' if lossy else 'the PRIMARY: sound'}")
+                if note not in said:  # (a kernel posts four times)
+                    said.add(note)
+                    print(note, file=sys.stderr, flush=True)
+                return (scatter_add if lossy else sound)(table, slots, values, mask)
+
+            vsr_replica.Replica.__init__ = remember
+            u128.scatter_add = by_role
     elif fault in ("state_unchanged", "half_left_out", "code_altered"):
         def keep(new_state, old_state):
             if fault == "state_unchanged":

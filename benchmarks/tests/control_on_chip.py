@@ -7,6 +7,11 @@ readings PERF.md quotes. The benchmark's own runs never run this.
 
     chiprun -- python3 benchmarks/tests/control_on_chip.py \
         --workload ledger_1m.transfers_sat --fault lossy_scatter --seeds 7,8,9
+
+A cell that BENCHMARK.json does not hold yet is found under `pending/`, as
+rehearse.py finds it; `--fault sound` with `--seconds 40` (and `--trace 1`)
+is then that cell's own run on the chip, which run.py's command line
+cannot make.
 """
 
 import argparse
@@ -23,15 +28,20 @@ def main() -> int:
     ap.add_argument("--fault", required=True)
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--seconds", type=float, default=5.0)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args()
     os.environ["BENCH_FAULT"] = args.fault
+    sys.path.insert(0, HERE)
     from benchmarks import run
+    from rehearse import manifest
 
     worst = 0
     for seed in args.seeds.split(","):
         print(f"=== {args.workload} fault={args.fault} seed={seed}", flush=True)
-        worst = max(worst, run.run_cell(args.workload, int(seed), args.seconds, False,
-                                        child=os.path.join(HERE, "broken_serve.py")))
+        worst = max(worst, run.run_cell(args.workload, int(seed), args.seconds, bool(args.trace),
+                                        overrides={"manifest": manifest(args.workload)},
+                                        child=None if args.fault == "sound" else
+                                        os.path.join(HERE, "broken_serve.py")))
     return worst
 
 

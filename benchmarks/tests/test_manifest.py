@@ -4,24 +4,33 @@ and against the files the harness will look for by name."""
 import json
 import os
 import re
+import sys
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import rehearse  # noqa: E402
+
+# BENCHMARK.json as it is, and as it would be with each cell kept under tests/pending/
+both = pytest.mark.parametrize(
+    "pending", [None] + sorted(f[:-5] for f in os.listdir(rehearse.PENDING)))
 
 
-def manifest() -> dict:
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        return json.load(f)
+def manifest(pending=None) -> dict:
+    return rehearse.manifest(pending or "")
 
 
 def line(text: str) -> bool:
     return 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
 
 
-def test_keys_names_units_and_lines():
-    m = manifest()
+@both
+def test_keys_names_units_and_lines(pending):
+    m = manifest(pending)
     assert set(m) == {"command", "paths", "run_seconds", "configs", "workloads",
                       "end_to_end", "per_layer"}
     assert isinstance(m["run_seconds"], int) and 1 <= m["run_seconds"] <= 51
@@ -55,8 +64,9 @@ def test_keys_names_units_and_lines():
     assert len(json.dumps(m)) < 64 * 1024
 
 
-def test_each_layer_metric_has_its_reader_and_moves_what_its_cells_report():
-    m = manifest()
+@both
+def test_each_layer_metric_has_its_reader_and_moves_what_its_cells_report(pending):
+    m = manifest(pending)
     cells = [w["name"] for w in m["workloads"]]
     reported = {e["name"]: set(e.get("workloads", cells)) for e in m["end_to_end"]}
     layers = set()
