@@ -1,8 +1,9 @@
 """Ask the TPU's compiler, without a TPU, whether it accepts the served
 path's kernels at production widths (`on-chip-measurement` guide,
-section 2, rehearsal 3): state tables for accounts_max = 2^20 and the
-n = 8192 batch bucket an 8190-event message pads to. libtpu compiles
-for a v5e that is DESCRIBED, not attached.
+section 2, rehearsal 3): state tables for accounts_max = 2^20
+(`production`) and 2^24 (`production_16m`), and the n = 8192 batch bucket
+an 8190-event message pads to. libtpu compiles for a v5e that is
+DESCRIBED, not attached.
 
 A compile that passes is not a chip run — nothing executes, so nothing
 here says anything about results or times. What it catches, at no chip
@@ -23,11 +24,14 @@ import pytest
 import jax
 from jax.sharding import SingleDeviceSharding
 
-from tigerbeetle_tpu.constants import PRODUCTION
+from tigerbeetle_tpu.constants import PRODUCTION, PRODUCTION_16M
 from tigerbeetle_tpu.ops import commit as commit_ops
 from tigerbeetle_tpu.ops import commit_exact
 
-A = PRODUCTION.accounts_max  # 2^20 account slots on the device
+# Account slots on the device, by preset: 2^20, and 2^24 (a 1.125 GiB state,
+# beside which a program's transient accumulators and un-donated copy must fit).
+TABLES = pytest.mark.parametrize(
+    "a", [PRODUCTION.accounts_max, PRODUCTION_16M.accounts_max], ids=["2^20", "2^24"])
 N = 8192  # the batch bucket: PRODUCTION.batch_max, 8190 events, pads to it
 HBM_BYTES = 16 * 10**9  # one v5e chip
 
@@ -78,8 +82,8 @@ def _compile(fn, one_chip, *args, **static):
     return compiled
 
 
-def _ledger_state():
-    return jax.eval_shape(lambda: commit_ops.init_state(A))
+def _ledger_state(a):
+    return jax.eval_shape(lambda: commit_ops.init_state(a))
 
 
 def _transfer_batch(n):
@@ -92,17 +96,19 @@ def _transfer_batch(n):
     )
 
 
-def test_create_transfers_fast(one_chip):
+@TABLES
+def test_create_transfers_fast(one_chip, a):
     _compile(
         commit_ops.create_transfers_fast, one_chip,
-        _ledger_state(), _transfer_batch(N), np.zeros(N, np.uint32),
+        _ledger_state(a), _transfer_batch(N), np.zeros(N, np.uint32),
     )
 
 
+@TABLES
 @pytest.mark.parametrize("has_pv,has_chains", [
     (True, True), (False, False), (False, True), (True, False),
 ], ids=["pv+chains", "plain", "chains", "pv"])
-def test_create_transfers_exact(one_chip, has_pv, has_chains):
+def test_create_transfers_exact(one_chip, has_pv, has_chains, a):
     """All four corners of the static-flag square the state machine
     compiles (has_pv / has_chains follow the batch's content): settlement
     batches carry both, TPC-B's chains alone."""
@@ -120,7 +126,7 @@ def test_create_transfers_exact(one_chip, has_pv, has_chains):
     )
     compiled = _compile(
         commit_exact.create_transfers_exact, one_chip,
-        _ledger_state(), _transfer_batch(N), np.zeros(N, np.uint32),
+        _ledger_state(a), _transfer_batch(N), np.zeros(N, np.uint32),
         pending, i32(N), plan,
         has_pv=has_pv, has_chains=has_chains,
     )
@@ -129,3 +135,21 @@ def test_create_transfers_exact(one_chip, has_pv, has_chains):
     *_, bail, sweeps = compiled.out_info
     assert (bail.shape, bail.dtype) == ((), np.bool_)
     assert (sweeps.shape, sweeps.dtype) == ((), np.int32)
+
+
+@TABLES
+def test_the_balance_access_entries(one_chip, a):
+    """`register_accounts` and `write_balances` at the batch bucket, and
+    `read_balances` at the bucket (a `lookup_accounts` request: the one
+    program the benchmark's `compiles_in_window` reads) and over the whole
+    table (the checkpoint's `snapshot.encode`: every slot gathered)."""
+    u32 = lambda *shape: np.zeros(shape, np.uint32)
+    slots = np.zeros(N, np.int32)
+    state = _ledger_state(a)
+    _compile(commit_ops.register_accounts, one_chip,
+             state, slots, u32(N), u32(N), np.zeros(N, bool))
+    _compile(commit_ops.write_balances, one_chip,
+             state, slots, u32(N, 4), u32(N, 4), u32(N, 4), u32(N, 4))
+    for k in (N, a):
+        compiled = _compile(commit_ops.read_balances, one_chip, state, np.zeros(k, np.int32))
+        assert [o.shape for o in compiled.out_info] == [(k, 4)] * 4

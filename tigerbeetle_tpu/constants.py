@@ -103,6 +103,22 @@ class Config:
 
 
 PRODUCTION = Config()
+# PRODUCTION with a balance table of 2^24 slots (1.125 GiB on the device:
+# 72 B a slot), for deployments whose accounts outnumber 2^20: TPC-B at
+# scale 160 is 16,001,920 of them. The checkpoint trailer carries every
+# account (128 B each, vsr/snapshot.py), and the previous trailer is only
+# released once the new one is durable, so the grid has to hold two: at
+# 16,001,920 accounts 2 x 7,815 blocks of 256 KiB. Beside them the store's
+# content, which PRODUCTION's 2^15 blocks hold with room (a run of 1,900
+# full batches peaks at 85% of them with two trailers of 515 blocks, PERF.md
+# section 4: some 26,800 blocks of content). 15,630 + 26,800 = 42,430 of
+# 2^16 blocks, 65%.
+PRODUCTION_16M = dataclasses.replace(
+    PRODUCTION,
+    name="production_16m",
+    accounts_max=1 << 24,
+    grid_block_count=1 << 16,  # x 256 KiB = 16 GiB
+)
 DEVELOPMENT = Config(
     name="development",
     accounts_max=1 << 18,
@@ -131,4 +147,4 @@ TEST_MIN = Config(
 
 
 def config_by_name(name: str) -> Config:
-    return {"production": PRODUCTION, "development": DEVELOPMENT, "test_min": TEST_MIN}[name]
+    return {c.name: c for c in (PRODUCTION, PRODUCTION_16M, DEVELOPMENT, TEST_MIN)}[name]

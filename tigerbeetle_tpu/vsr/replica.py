@@ -3443,36 +3443,41 @@ class Replica:
             return
         log.info("replica %d: checkpoint at op %d", self.replica, self.commit_min)
         tracer.count("replica.checkpoint")
-        # The trailer must capture every op ≤ commit_min's store and beat:
-        # drain the async store stage first. A job parked on a corrupt
-        # block re-raises its fault here so _checkpoint_guarded applies
-        # the identical gate/retry path as an inline checkpoint fault.
-        if self.store_executor is not None:
-            self.store_executor.drain()
-            if self.store_executor.parked:
-                raise self.store_executor.fault
-        if self.aof is not None:
-            self.aof.sync()
-        # Trailer write flushes LSM memtables into grid blocks and chunks
-        # the checkpoint blob into reserved blocks; everything must be
-        # durable before the superblock may reference it.
-        trailer_block = self._trailer_write()
-        self.storage.sync()
-        st = self.superblock.state
-        st.op_checkpoint = self.commit_min
-        st.commit_min = self.commit_min
-        st.commit_max = self.commit_max
-        st.view = self.view
-        st.log_view = self.log_view
-        st.prepare_timestamp = self.committed_timestamp_max
-        st.commit_timestamp = self.state_machine.commit_timestamp
-        st.config_epoch = self.config_epoch
-        st.trailer_block = trailer_block
-        self.superblock.checkpoint()
-        # The checkpoint is durable: staged grid frees (tables replaced by
-        # compaction since the last checkpoint, plus the previous trailer's
-        # blocks) may now be reused.
-        self.state_machine.grid.commit_releases()
+        # `vsr.checkpoint` and its four leaves (docs/OBSERVABILITY.md): the
+        # seconds the op stream stands still, and what they are made of.
+        with tracer.span("vsr.checkpoint"):
+            # The trailer must capture every op ≤ commit_min's store and beat:
+            # drain the async store stage first. A job parked on a corrupt
+            # block re-raises its fault here so _checkpoint_guarded applies
+            # the identical gate/retry path as an inline checkpoint fault.
+            with tracer.span("vsr.checkpoint.drain"):
+                if self.store_executor is not None:
+                    self.store_executor.drain()
+                    if self.store_executor.parked:
+                        raise self.store_executor.fault
+                if self.aof is not None:
+                    self.aof.sync()
+            # Trailer write flushes LSM memtables into grid blocks and chunks
+            # the checkpoint blob into reserved blocks; everything must be
+            # durable before the superblock may reference it.
+            trailer_block = self._trailer_write()
+            with tracer.span("vsr.checkpoint.sync"):
+                self.storage.sync()
+            st = self.superblock.state
+            st.op_checkpoint = self.commit_min
+            st.commit_min = self.commit_min
+            st.commit_max = self.commit_max
+            st.view = self.view
+            st.log_view = self.log_view
+            st.prepare_timestamp = self.committed_timestamp_max
+            st.commit_timestamp = self.state_machine.commit_timestamp
+            st.config_epoch = self.config_epoch
+            st.trailer_block = trailer_block
+            self.superblock.checkpoint()
+            # The checkpoint is durable: staged grid frees (tables replaced by
+            # compaction since the last checkpoint, plus the previous trailer's
+            # blocks) may now be reused.
+            self.state_machine.grid.commit_releases()
         self.on_event("checkpoint", self)
 
     def _save_snapshot(self) -> bytes:
@@ -3514,27 +3519,30 @@ class Replica:
         # placement history must never perturb the deterministic content
         # layout the storage checker byte-compares. The blob is therefore
         # independent of the reservation — one encode suffices.
-        blob = snapshot.encode(self)
-        need = -(-len(blob) // payload_max) + 1  # chunks + index block
-        assert need - 1 <= fences_max, "checkpoint trailer exceeds one index block"
-        reserved = [grid.free_set.acquire_high() for _ in range(need)]
-        index_block, chunks = reserved[0], reserved[1:]
-        for i, b in enumerate(chunks):
+        with tracer.span("vsr.checkpoint.encode"):
+            blob = snapshot.encode(self)
+        tracer.count("vsr.checkpoint.blob_bytes", len(blob))
+        with tracer.span("vsr.checkpoint.trailer"):
+            need = -(-len(blob) // payload_max) + 1  # chunks + index block
+            assert need - 1 <= fences_max, "checkpoint trailer exceeds one index block"
+            reserved = [grid.free_set.acquire_high() for _ in range(need)]
+            index_block, chunks = reserved[0], reserved[1:]
+            for i, b in enumerate(chunks):
+                grid.write_block_at(
+                    b, blob[i * payload_max : (i + 1) * payload_max],
+                    self.BLOCK_TYPE_TRAILER,
+                )
+            head = np.zeros((), dtype=self._TRAILER_HEAD)
+            head["count"] = len(chunks)
+            head["blob_len"] = len(blob)
+            c = hdr.checksum(blob)
+            head["cks_lo"] = c & ((1 << 64) - 1)
+            head["cks_hi"] = c >> 64
             grid.write_block_at(
-                b, blob[i * payload_max : (i + 1) * payload_max],
+                index_block,
+                head.tobytes() + np.array(chunks, dtype=np.uint32).tobytes(),
                 self.BLOCK_TYPE_TRAILER,
             )
-        head = np.zeros((), dtype=self._TRAILER_HEAD)
-        head["count"] = len(chunks)
-        head["blob_len"] = len(blob)
-        c = hdr.checksum(blob)
-        head["cks_lo"] = c & ((1 << 64) - 1)
-        head["cks_hi"] = c >> 64
-        grid.write_block_at(
-            index_block,
-            head.tobytes() + np.array(chunks, dtype=np.uint32).tobytes(),
-            self.BLOCK_TYPE_TRAILER,
-        )
         self._trailer_blocks = reserved
         return index_block
 

@@ -274,8 +274,14 @@ def encode(replica) -> bytes:
         dtype=np.uint8,
     )
 
-    buf = _io.BytesIO()
+    # Written over a buffer of the blob's size (the sections' bytes and,
+    # generously, a page of npy and zip headers each), so that a blob of
+    # 128 B an account is not copied again each time a BytesIO grows.
+    buf = _io.BytesIO(bytes(
+        sum(np.asarray(v).nbytes + 4096 for v in sections.values())
+    ))
     np.savez(buf, **sections)
+    buf.truncate()
     return buf.getvalue()
 
 
