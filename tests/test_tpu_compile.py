@@ -135,6 +135,15 @@ def test_create_transfers_exact(one_chip, has_pv, has_chains, a):
     *_, bail, sweeps = compiled.out_info
     assert (bail.shape, bail.dtype) == ((), np.bool_)
     assert (sweeps.shape, sweeps.dtype) == ((), np.int32)
+    # The kernel's work follows the batch, not the table: no transient the
+    # size of a balance table (the dense post built eleven of them, 5.96 GB
+    # at 2^24). What is table-sized is the un-donated state, in and out.
+    mem = compiled.memory_analysis()
+    if a == PRODUCTION_16M.accounts_max:
+        assert mem.temp_size_in_bytes < mem.argument_size_in_bytes, (
+            f"temp {mem.temp_size_in_bytes} B, arguments {mem.argument_size_in_bytes} B, "
+            f"outputs {mem.output_size_in_bytes} B"
+        )
 
 
 @TABLES

@@ -224,12 +224,16 @@ def apply_posting_streamed(
 ):
     """Post amounts via full-table streamed scatter-add (u128.scatter_add).
 
-    Work is O(A) per batch but purely streaming — measured faster on TPU
-    than the compact sort/unique alternative below (TPU sorts are slow,
-    HBM streams are fast). Per-side masks let the sharded path apply only
-    the sides its shard owns. Overflow semantics: per-slot u128 overflow
-    plus the combined pending+posted check (state_machine.zig:1308-1324),
-    monotone in batch totals.
+    Work is O(A) per batch: four table-sized accumulators and six passes
+    over the table, whatever the batch touches. The fast kernel has no
+    sort plan, so it has no cheaper way to a slot's total than the
+    on-device sort/unique of `apply_posting_compact` below; which of the
+    two costs less at which A has not been measured on a v5e (the exact
+    kernel, which holds a plan, posts row by row: commit_exact._apply).
+    Per-side masks let the sharded path apply only the sides its shard
+    owns. Overflow semantics: per-slot u128 overflow plus the combined
+    pending+posted check (state_machine.zig:1308-1324), monotone in batch
+    totals.
     """
     new_dp, o1 = u128.scatter_add(state.debits_pending, dr_slot, amount, dr_pend)
     new_cp, o2 = u128.scatter_add(state.credits_pending, cr_slot, amount, cr_pend)
@@ -255,9 +259,11 @@ def apply_posting_compact(
 ):
     """Post amounts touching only batch rows (sort/unique + row updates).
 
-    Work scales with the batch, not the table — but on-device sort/unique
-    measures slower than the streamed path on TPU for A ≤ 2^20. Kept for
-    large-table configs where O(A) streaming would dominate.
+    Work scales with the batch, not the table, at the price of an
+    on-device sort/unique. No served path calls it (tests/test_u128.py
+    holds it equal to the streamed post): it is the fast kernel's way to
+    posting that follows the rows touched, and neither its cost nor the
+    table size from which it would pay has been measured on a v5e.
     """
     a = state.debits_pending.shape[0]
     n = dr_slot.shape[0]

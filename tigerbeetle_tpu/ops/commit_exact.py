@@ -33,6 +33,12 @@ dependency into data-parallel sweeps:
      the fixed point is unique and equals the serial execution exactly. A
      batch that has not stabilized after `max_sweeps` raises `bail` and the
      host falls back to the serial oracle.
+  5. Post (`_apply`): the same sorted postings, masked by the FINAL
+     outcomes, are segment-summed once more; a slot's new row is its
+     pre-batch row (the `base` gather the sweeps already hold) plus its
+     segment's totals, written once per touched slot. The kernel's work
+     follows the 2n postings, never the table: the only table-sized
+     traffic is the un-donated copy of the four balance tables.
 
 Exactness: all balance arithmetic is u128 (or wider) via uint32 limbs;
 prefix sums run in u16 half-limb lanes (≤ 2^16 terms of < 2^16 each — no
@@ -547,6 +553,38 @@ def create_transfers_exact_impl(
     sub_grp_s = is_pv[sorted_rec_idx][:, None]
     p_amt_h_s = u128.split_u16(pending.amount)[sorted_rec_idx]  # (2n, 8)
 
+    # The lane groups of `posting_lanes`, debit side then credit side. With
+    # no post/void events the *_sub lanes are identically zero: statically
+    # dropped (16 fewer lanes in every cumsum).
+    if has_pv:
+        groups = ("dp_add", "dp_sub", "dpo_add", "cp_add", "cp_sub", "cpo_add")
+    else:
+        groups = ("dp_add", "dpo_add", "cp_add", "cpo_add")
+
+    def posting_lanes(amount):
+        """(2n, 48|32) u16 half-limb lanes of every posting, in the plan's
+        sorted order, unmasked: lanes 0-7 debits_pending_add, 8-15
+        debits_pending_sub (the PENDING's amount), 16-23 debits_posted_add,
+        24-31 credits_pending_add, 32-39 credits_pending_sub, 40-47
+        credits_posted_add (without the *_sub groups when has_pv is False).
+        dr-side records carry the debit lanes, cr-side records the credit
+        lanes, so a lane sums at most n values. The sweep's observation and
+        the final post both segment-sum this one tensor."""
+        amt_s = u128.split_u16(amount)[sorted_rec_idx]  # (2n, 8)
+        pend_add = jnp.where(pend_grp_s, amt_s, 0)
+        post_add = jnp.where(post_grp_s, amt_s, 0)
+        if has_pv:
+            pend_sub = jnp.where(sub_grp_s, p_amt_h_s, 0)
+            left = jnp.concatenate([pend_add, pend_sub, post_add], axis=1)
+        else:
+            left = jnp.concatenate([pend_add, post_add], axis=1)
+        zl = jnp.zeros_like(left)
+        return jnp.where(
+            sorted_is_dr,
+            jnp.concatenate([left, zl], axis=1),
+            jnp.concatenate([zl, left], axis=1),
+        )
+
     idxs = jnp.arange(n, dtype=I32)
     if has_chains:
         # Chain tails for contiguous chains: e_tail[i] = last index of i's
@@ -583,33 +621,12 @@ def create_transfers_exact_impl(
         *_pending fields and (post only) adds the resolved amount to the
         *_posted fields.
 
-        All six per-record streams ride ONE (2n, 48) sorted-space tensor so
-        the whole sweep costs one fused segmented-cumsum pass: lanes 0-7
-        debits_pending_add, 8-15 debits_pending_sub, 16-23
-        debits_posted_add, 24-31 credits_pending_add, 32-39
-        credits_pending_sub, 40-47 credits_posted_add. dr-side records
-        carry the debit lanes, cr-side records the credit lanes.
+        All six per-record streams ride ONE sorted-space tensor
+        (`posting_lanes`) so the whole sweep costs one fused
+        segmented-cumsum pass.
         """
         eff_s = (ok & chain_ok_ev)[sorted_rec_idx]
-        amt_s = u128.split_u16(amount)[sorted_rec_idx]  # (2n, 8)
-
-        pend_add = jnp.where(pend_grp_s, amt_s, 0)
-        post_add = jnp.where(post_grp_s, amt_s, 0)
-        if has_pv:
-            # With no post/void events the *_sub lanes are identically
-            # zero — statically dropped (16 fewer lanes in the cumsum).
-            pend_sub = jnp.where(sub_grp_s, p_amt_h_s, 0)
-            left = jnp.concatenate([pend_add, pend_sub, post_add], axis=1)
-            groups = ("dp_add", "dp_sub", "dpo_add", "cp_add", "cp_sub", "cpo_add")
-        else:
-            left = jnp.concatenate([pend_add, post_add], axis=1)
-            groups = ("dp_add", "dpo_add", "cp_add", "cpo_add")
-        zl = jnp.zeros_like(left)
-        stacked = jnp.where(
-            sorted_is_dr,
-            jnp.concatenate([left, zl], axis=1),
-            jnp.concatenate([zl, left], axis=1),
-        )  # (2n, 48|32), already in sorted order
+        stacked = posting_lanes(amount)
 
         if has_chains:
             own_s = (ok & ~chain_ok_ev)[sorted_rec_idx]
@@ -848,10 +865,19 @@ def create_transfers_exact_impl(
     amounts = masked(ok, amounts)
 
     with jax.named_scope("post"):
-        new_state, overflow = _apply(
-            state, b, pending, is_pv, is_post, pend, ok, amounts,
-            balance_apply=balance_apply,
-        )
+        if balance_apply is not None:
+            new_state, overflow = balance_apply(
+                state, eff_dr_slot, eff_cr_slot, amounts, pending.amount,
+                ok & pend & ~is_pv,
+                ok & ((~pend & ~is_pv) | (is_pv & is_post)),
+                ok & is_pv,
+            )
+        else:
+            new_state, overflow = _apply(
+                state, base, rec_slot[perm], perm, head_pos,
+                jnp.where(ok[sorted_rec_idx][:, None], posting_lanes(amounts), 0),
+                groups,
+            )
 
     # Post-event balances (observed + own delta) for history rows
     # (state_machine.zig:1342-1364 — regular events only; post/void writes
@@ -877,40 +903,74 @@ def create_transfers_exact_impl(
     return new_state, codes, amounts, dr_after, cr_after, bail, sweeps
 
 
-def _apply(state, b, pending, is_pv, is_post, pend, ok, amounts, balance_apply=None):
-    """Post the final outcomes: adds via exact scatter-add, pending
-    removals via exact scatter-sub (post/void)."""
-    eff_dr = jnp.where(is_pv, pending.dr_slot, b.dr_slot).astype(I32)
-    eff_cr = jnp.where(is_pv, pending.cr_slot, b.cr_slot).astype(I32)
+def _apply(state, base, slot_s, perm, head_pos, lanes, groups):  # tidy: static=groups — the lane groups' names, a trace-time tuple
+    """Post the final outcomes row by row: one segment total and one row
+    write per touched slot, from the sort plan.
 
-    add_pend = ok & pend & ~is_pv
-    add_post = ok & ((~pend & ~is_pv) | (is_pv & is_post))
-    sub_pend = ok & is_pv
+    slot_s, head_pos, lanes are in the plan's sorted order: slot_s (2n,)
+    each posting's slot (negative: none), head_pos its slot segment's
+    head, lanes (2n, 8·len(groups)) the `posting_lanes` of the FINAL ok
+    and amounts, zero where the event failed. base holds the four
+    pre-batch balances of every posting's slot in record order (perm maps
+    sorted position to record).
 
-    if balance_apply is not None:
-        return balance_apply(
-            state, eff_dr, eff_cr, amounts, pending.amount,
-            add_pend, add_post, sub_pend,
-        )
+    A slot's new row is its base row plus its segment's add totals, then
+    minus its sub totals (the pending removals of post/void): the order
+    and the u128 arithmetic of `u128.scatter_add` / `scatter_sub` over the
+    whole table, which this replaces, so the rows written are bit for bit
+    the dense post's and every other row is untouched. The inclusive
+    segment sum at a segment's LAST record is the segment's total, so
+    each touched slot is written once, from there; postings without a
+    slot and padding are dropped. Nothing table-sized is built but the
+    four output tables.
 
-    new_dp, o1 = u128.scatter_add(state.debits_pending, eff_dr, amounts, add_pend)
-    new_cp, o2 = u128.scatter_add(state.credits_pending, eff_cr, amounts, add_pend)
-    new_dpo, o3 = u128.scatter_add(state.debits_posted, eff_dr, amounts, add_post)
-    new_cpo, o4 = u128.scatter_add(state.credits_posted, eff_cr, amounts, add_post)
-    new_dp, u1 = u128.scatter_sub(new_dp, eff_dr, pending.amount, sub_pend)
-    new_cp, u2 = u128.scatter_sub(new_cp, eff_cr, pending.amount, sub_pend)
-    _, o5 = u128.add(new_dp, new_dpo)
-    _, o6 = u128.add(new_cp, new_cpo)
-    over = (
-        jnp.any(o1) | jnp.any(o2) | jnp.any(o3) | jnp.any(o4)
-        | jnp.any(o5) | jnp.any(o6) | jnp.any(u1) | jnp.any(u2)
-    )
-    return state._replace(
-        debits_pending=new_dp,
-        debits_posted=new_dpo,
-        credits_pending=new_cp,
-        credits_posted=new_cpo,
-    ), over
+    Returns (new_state, over). `over` is the dense post's bail condition,
+    read on the touched rows only: a field's adds overflow u128, its subs
+    underflow, or debits_pending + debits_posted / credits_pending +
+    credits_posted overflow. An untouched row keeps the value that passed
+    these checks when it was last written (by this kernel, the fast
+    kernel or the serial path's oracle, which hold the same rules), so
+    the touched rows decide `over` exactly as the whole table does.
+    """
+    m = lanes.shape[0]
+    a_count = state.ledger.shape[0]
+    # Exactness: a lane takes values from one side's records only, at
+    # most m/2 of <= 0xFFFF each, within combine_u16's range= contract
+    # (< 2^16 contributions) and far from wrapping u32.
+    assert m // 2 < (1 << 16), f"row-wise post exactness requires n < 2^16, got {m // 2}"
+    pos = jnp.arange(m, dtype=I32)
+    is_tail = jnp.concatenate([head_pos[1:] == pos[1:], jnp.ones((1,), dtype=bool)])
+    write = is_tail & (slot_s >= 0)
+    # Inclusive per-segment sums: at a tail, the segment's totals.
+    totals = _seg_exclusive_cumsum(lanes, head_pos) + lanes
+    delta = {
+        g: u128.combine_u16(totals[:, 8 * i : 8 * i + 8])
+        for i, g in enumerate(groups)
+    }
+
+    bad = jnp.zeros((m,), dtype=bool)
+    rows = {}
+    for f, add_g, sub_g in (
+        ("debits_pending", "dp_add", "dp_sub"),
+        ("debits_posted", "dpo_add", None),
+        ("credits_pending", "cp_add", "cp_sub"),
+        ("credits_posted", "cpo_add", None),
+    ):
+        plus, plus_over = delta[add_g]
+        row, over = u128.add(getattr(base, f)[perm], plus)
+        bad = bad | over | plus_over
+        if sub_g in groups:
+            minus, minus_over = delta[sub_g]
+            row, under = u128.sub(row, minus)
+            bad = bad | under | minus_over
+        rows[f] = row
+    bad = bad | u128.sum_overflows(rows["debits_pending"], rows["debits_posted"])
+    bad = bad | u128.sum_overflows(rows["credits_pending"], rows["credits_posted"])
+
+    at = jnp.where(write, slot_s, jnp.int32(a_count))  # out of range: dropped
+    return state._replace(**{
+        f: getattr(state, f).at[at].set(rows[f], mode="drop") for f in BAL_FIELDS
+    }), jnp.any(write & bad)
 
 
 create_transfers_exact = jax.jit(
