@@ -28,12 +28,25 @@ with `exceeds_credits`. The transactions (`code` of the stored transfer):
                        stored with the amount that moved, or is refused
                        where nothing is (which moves nothing, as SmallBank's
                        would)
-  (Balance, a read, is left out: the harness sends create_transfers only)
+  balance              a read: savings(c) and checking(c) by one
+                       `lookup_accounts`; sent where the traffic file's
+                       `weights` hold `balance`, else left out (the file
+                       then says so: `weights_left_out`)
 
 A batch holds the same count of each kind in every seed: the traffic
-file's `weights`, scaled to the batch's events (an amalgamate is two),
-what is left over going to send_payment; the transactions are shuffled
-through the batch as whole units.
+file's `weights`, scaled to the batch's events (an amalgamate is two;
+`balance` takes no part in this), what is left over going to
+send_payment; the transactions are shuffled through the batch as whole
+units.
+
+With `weights.balance` a session alternates: request 2k is write batch k,
+to the byte the batch a file without it gives, and request 2k + 1 is one
+`lookup_accounts` that holds the Balance transactions that go with that
+batch: floor(transactions of a batch x balance / the other five weights)
+customers, drawn by the hotspot rule from (seed, session, k), each as its
+savings id then its checking id. A hot customer is named several times in
+one read; a customer whose load has not been committed yet reads zeros.
+Without it request k is write batch k (`request` says which).
 
 The accounts, by id: the bank's accounts 1..B, one per
 `customers_per_bank_account` customers (at least one), then the savings
@@ -52,10 +65,11 @@ shorter than a batch. A session that is ahead of the others may send
 transactions on customers whose load has not been committed yet: they are
 refused, as the commit order has it.
 
-As in `ledger_mix`: batch (session, seq) is a pure function of (seed,
-session, seq) and of the parameters; it never reads a reply. The answers
-depend on the order of the events in a batch and on the order in which
-the server commits the sessions' batches (run.py replays in that order).
+As in `ledger_mix`: batch (session, seq), and so request (session, seq),
+is a pure function of (seed, session, seq) and of the parameters; it never
+reads a reply. The answers depend on the order of the events in a batch
+and on the order in which the server commits the sessions' requests, reads
+among them (run.py replays in that order).
 """
 
 from __future__ import annotations
@@ -63,7 +77,7 @@ from __future__ import annotations
 import numpy as np
 
 from benchmarks.generators import ledger_mix
-from benchmarks.reference import BALANCING_DEBIT, DEBITS_MUST_NOT_EXCEED_CREDITS, TRANSFER
+from benchmarks.reference import BALANCING_DEBIT, DEBITS_MUST_NOT_EXCEED_CREDITS, ID, TRANSFER
 
 KINDS = ("deposit_checking", "transact_savings", "write_check", "send_payment", "amalgamate")
 DEPOSIT, SAVINGS, CHECK, PAYMENT, AMALGAMATE = range(5)
@@ -92,6 +106,9 @@ class Generator(ledger_mix.Generator):
         unit = self.n / sum(w * e for w, e in zip(weights, EVENTS))
         self.count = [int(w * unit) for w in weights]
         self.count[PAYMENT] += self.n - sum(c * e for c, e in zip(self.count, EVENTS))
+        # Balance transactions to a batch: its weight against the five that write.
+        self.balances = int(sum(self.count) * float(traffic["weights"].get("balance", 0))
+                            / sum(weights))
 
     # accounts ------------------------------------------------------------
 
@@ -121,6 +138,23 @@ class Generator(ledger_mix.Generator):
         if not self.hot:
             return cold
         return np.where(rng.random(k) < self.hot_share, rng.integers(0, self.hot, k), cold)
+
+    def request(self, session: int, seq: int) -> tuple:
+        """Request `seq` of a session as (operation, body)."""
+        if not self.balances:
+            return "create_transfers", self.batch(session, seq)
+        k, read = divmod(seq, 2)
+        if read:
+            return "lookup_accounts", self.balance(session, k)
+        return "create_transfers", self.batch(session, k)
+
+    def balance(self, session: int, k: int) -> np.ndarray:
+        """The ids of the Balance transactions that go with batch (session, k)."""
+        c = self._customers(self.rng(3, session, k), self.balances)
+        ids = np.zeros(2 * self.balances, dtype=ID)
+        ids["lo"][0::2] = self.first_savings + c
+        ids["lo"][1::2] = self.first_checking + c
+        return ids
 
     def batch(self, session: int, seq: int) -> np.ndarray:
         n = self.n

@@ -45,8 +45,13 @@ judge what it does not model:
   and no two events of one batch name the same one;
 - the batch does not end inside a linked chain.
 
+A read (`lookup_accounts`) changes nothing and answers from the balances
+as the transfers applied before it left them: the caller asks at the
+read's own place in the commit order (run.py), and that place is what is
+judged.
+
 The wire layouts are the protocol's (128-byte Account and Transfer,
-8-byte result pairs), written out here again on purpose.
+8-byte result pairs, 16-byte ids), written out here again on purpose.
 """
 
 from __future__ import annotations
@@ -74,7 +79,8 @@ TRANSFER = np.dtype([
     ("ledger", "<u4"), ("code", "<u2"), ("flags", "<u2"), ("timestamp", "<u8"),
 ])
 RESULT = np.dtype([("index", "<u4"), ("result", "<u4")])
-assert ACCOUNT.itemsize == TRANSFER.itemsize == 128
+ID = np.dtype([("lo", "<u8"), ("hi", "<u8")])  # an event of lookup_accounts: one id
+assert ACCOUNT.itemsize == TRANSFER.itemsize == 128 and ID.itemsize == 16
 
 LINKED, PENDING, POST, VOID, BALANCING_DEBIT, BALANCING_CREDIT = 1, 2, 4, 8, 16, 32
 BALANCING = BALANCING_DEBIT | BALANCING_CREDIT
@@ -161,7 +167,13 @@ class Ledger:
         return np.zeros(0, dtype=RESULT)
 
     def lookup_accounts(self, ids: np.ndarray) -> np.ndarray:
-        """The stored accounts among `ids`, in that order, timestamps 0."""
+        """The stored accounts among `ids` (plain integers, or the `ID`
+        events of a request's body), in the request's order, timestamps 0:
+        an id named twice is answered twice, an id that names no account is
+        passed over (tigerbeetle `src/state_machine.zig`, `execute_lookup_accounts`)."""
+        if getattr(ids, "dtype", None) == ID:
+            _need(not ids["hi"].any(), "id_hi")
+            ids = ids["lo"]
         ids = np.asarray(ids, dtype=np.uint64)
         found = ids[(ids < len(self.exists)) & self.exists[
             np.minimum(ids, len(self.exists) - 1)]]

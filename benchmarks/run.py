@@ -14,6 +14,13 @@ in the order the server committed them (the `op` of each reply's header:
 where a balance check can fail, the answers depend on it), and compare.
 The last line of stdout is the result.
 
+A request is an operation and a body, as the cell's generator names them
+(`request(session, seq)`; a generator that has only `batch` sends
+`create_transfers` of its batches): writes, and since PR 37 reads
+(`lookup_accounts`) among them. A read is answered by the reference at its
+own place in the commit order and compared row for row. The rate and the
+write latencies are taken over the writes alone.
+
 A configuration whose `replica_count` is n > 1 gets what it states: n data
 files, n servers in parallel, each on a chip of its own, sessions that
 find the primary. Its read-back has a second half: the primary is killed
@@ -75,6 +82,7 @@ ELECTION_PROBE_S = 1.0  # a try at registering with a cluster: how long it had n
 ELECTION_TIMEOUT_S = 120.0  # no primary by then: the run fails
 PIPELINE_SLACK = 8  # prepares in flight at a scrape: a page is not a snapshot
 DEADLINE_S = 1150.0  # a first run in a checkout compiles; the watchdog ends anything longer
+WRITE, READ = "create_transfers", "lookup_accounts"  # what a generator may name (sessions.OPERATIONS)
 
 
 def load_json(*parts: str) -> dict:
@@ -208,6 +216,16 @@ async def trace_slices(loop, server, trace_dir, traffic: dict, seconds: float, t
 # --- correctness: the plain reference against what was served ---------------------
 
 
+def requests_of(generator) -> tuple:
+    """(request, transfer_ids) of a generator: `request(session, seq)` gives
+    that request as (operation, body); `transfer_ids(session, seq)` the ids
+    of a create_transfers request, for the read-back. A generator with no
+    `request` of its own sends its batches and nothing else."""
+    if hasattr(generator, "request"):
+        return generator.request, lambda s, seq: generator.request(s, seq)[1]["id_lo"].tolist()
+    return (lambda s, seq: (WRITE, generator.batch(s, seq))), generator.ids
+
+
 def no_timestamp(records: np.ndarray) -> np.ndarray:
     out = np.array(records)
     out["timestamp"] = 0
@@ -226,16 +244,20 @@ def compare(generator, ledger, records: list, sample: set, read_back: dict,
             accounts_got: list) -> dict:
     """Replay every answered request through the reference, in the order
     the server committed them (each reply's `op`), and count what differs
-    from what was served. Three numbers hold that order to what it must be
+    from what was served: a write's result codes (and, of the sample, its
+    stored rows), a read's rows, which the reference answers at the read's
+    own place in that order. Three numbers hold that order to what it must be
     (the op is the program's own word): no two answered requests share an
     op, every session's own order is its op order (one request in flight a
     session), and, across sessions, no request whose reply had arrived
     before another was first sent stands behind it in op order (strict
     serializability's real-time half, on this process's one clock)."""
-    from benchmarks.reference import RESULT
+    from benchmarks.reference import ACCOUNT, RESULT
 
     code_events = code_mismatches = stored_compared = store_mismatches = 0
+    read_rows = read_mismatches = 0
     mismatched_requests = {}
+    request, _ids = requests_of(generator)
     for accounts in generator.account_batches():
         if len(ledger.create_accounts(accounts)):
             raise ValueError("the generator's accounts are not all valid")
@@ -252,7 +274,15 @@ def compare(generator, ledger, records: list, sample: set, read_back: dict,
         realtime_order_violations += rec.done < latest_sent
         latest_sent = max(latest_sent, rec.sent)
     for rec in answered:
-        events = generator.batch(rec.session, rec.seq)
+        operation, events = request(rec.session, rec.seq)
+        if operation == READ:
+            want = ledger.lookup_accounts(events)
+            bad = rows_differing(no_timestamp(np.frombuffer(rec.reply, dtype=ACCOUNT)), want)
+            read_rows += len(want)
+            read_mismatches += bad
+            if bad:
+                mismatched_requests[(rec.session, rec.seq)] = bad
+            continue
         want, stored = ledger.create_transfers(events)
         got = np.frombuffer(rec.reply, dtype=RESULT)
         code_events += len(events)
@@ -277,6 +307,7 @@ def compare(generator, ledger, records: list, sample: set, read_back: dict,
         "replies_sharing_an_op": replies_sharing_an_op,
         "session_order_violations": session_order_violations,
         "realtime_order_violations": realtime_order_violations,
+        "read_mismatches": read_mismatches, "read_rows_compared": read_rows,
     }
 
 
@@ -364,7 +395,8 @@ def read_back_from(client, generator, config: dict, sample: set) -> tuple:
     """Every balance, and the sampled batches by id: (transfers by batch,
     [(ids, accounts)], seconds the transfers took)."""
     t = time.perf_counter()
-    transfers = {(s, k): client.lookup_transfers(generator.ids(s, k))
+    _request, transfer_ids = requests_of(generator)
+    transfers = {(s, k): client.lookup_transfers(transfer_ids(s, k))
                  for s, k in sorted(sample)}
     transfers_s = time.perf_counter() - t
     n_batch = int(config["batch"])
@@ -484,7 +516,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         say(f"{tag} {config['accounts']:,} accounts registered in "
             f"{time.perf_counter() - t:.1f} s")
 
-        load = Load(addresses, int(traffic["sessions"]), generator.batch, request_timeout_s)
+        load = Load(addresses, int(traffic["sessions"]), requests_of(generator)[0],
+                    request_timeout_s)
         ctx = {"config": config, "traffic": traffic,
                "peaks": peaks_table.get(device["device_kind"], {})}
         trace_dir = os.path.join(workdir, "trace") if trace else None
@@ -494,14 +527,23 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         setup_s = t0 - T_PROCESS_START
         memory = max(int(s.ask("memory")["memory_peak_bytes"]) for s in servers)
 
+        # The writes are what the rate and the write latencies are taken over; the reads
+        # (a mix that has them) are in the cost, and have a latency of their own.
         records = load.records
         answered = [r for r in records if r.reply is not None]
-        window = [r for r in answered if t0 <= r.done < t1]
-        lost = [r for r in records if r.reply is None and r.sent > 0.0]
-        prefill = [r for r in answered if r.done < t0]
+        lost_all = [r for r in records if r.reply is None and r.sent > 0.0]
+        window_all = [r for r in answered if t0 <= r.done < t1]
+        window = [r for r in window_all if r.operation == WRITE]
+        reads = [r for r in window_all if r.operation == READ]
+        lost = [r for r in lost_all if r.operation == WRITE]
+        prefill = [r for r in answered if r.done < t0 and r.operation == WRITE]
+        reads_before = sum(r.done < t0 and r.operation == READ for r in answered)
         say(f"{tag} prefill {len(prefill)} batches ({sum(r.events for r in prefill):,} "
-            f"transfers) in {t0 - min(r.sent for r in records):.1f} s; set-up {setup_s:.1f} s; "
-            f"window {seconds:g} s: {len(window)} requests answered, {len(lost)} never; "
+            f"transfers) in {t0 - min(r.sent for r in records):.1f} s"
+            + (f", {reads_before} reads among them" if reads_before else "")
+            + f"; set-up {setup_s:.1f} s; "
+            f"window {seconds:g} s: {len(window_all)} requests answered"
+            + (f" ({len(reads)} of them reads)" if reads else "") + f", {len(lost_all)} never; "
             f"{sum(x.resends for x in load.sessions)} resent, "
             f"{sum(x.busy for x in load.sessions)} answered BUSY")
 
@@ -557,25 +599,37 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         verdict = compare(generator, Ledger(int(config["accounts"])), records, sample,
                           read_back, accounts_got)
         reference_s = time.perf_counter() - t
-        transfers_issued = sum(r.events for r in records)
+        transfers_issued = sum(r.events for r in records if r.operation == WRITE)
         say(f"{tag} read back in {read_back_s:.1f} s ({transfers_s:.1f} s of it the "
             f"{len(sample)} batches of transfers), reference replay in {reference_s:.1f} s; "
             f"{transfers_issued:,} transfers issued in all "
             f"({100.0 * transfers_issued / int(config['transfers_max']):.0f}% of transfers_max)")
 
         # The end-to-end numbers, over all the work and all the time of the window.
-        in_window = {(r.session, r.seq) for r in window}
+        # (`attempted` and `failed` count transfers and looked-up ids)
+        in_window = {(r.session, r.seq) for r in window_all}
         bad_in_window = sum(n for key, n in verdict["mismatched_requests"].items()
                             if key in in_window)
-        attempted = sum(r.events for r in window) + sum(r.events for r in lost)
-        failed = bad_in_window + sum(r.events for r in lost)
+        attempted = sum(r.events for r in window_all) + sum(r.events for r in lost_all)
+        failed = bad_in_window + sum(r.events for r in lost_all)
         drained_at = max([r.done for r in answered] + [t1])
-        latencies = sorted([r.latency * 1e3 for r in window]
-                           + [(drained_at - r.sent) * 1e3 for r in lost])
+
+        def latencies_ms(done: list, never: list) -> list:
+            return sorted([r.latency * 1e3 for r in done]
+                          + [(drained_at - r.sent) * 1e3 for r in never])
+
+        latencies = latencies_ms(window, lost)
         say(f"{tag} write latency over {len(latencies)} requests of the window "
             f"(p95 has {len(latencies) - int(len(latencies) * 0.95)} beyond it)")
         if not window:
             raise Failure("no request was answered inside the window")
+        read_latencies = latencies_ms(reads, [r for r in lost_all if r.operation == READ])
+        if read_latencies:
+            say(f"{tag} read latency over {len(read_latencies)} lookup_accounts requests of the "
+                f"window ({sum(r.events for r in reads) / max(len(reads), 1):,.0f} ids each): "
+                f"p50 {percentile(read_latencies, 0.50):.1f} ms, "
+                f"p95 {percentile(read_latencies, 0.95):.1f} ms "
+                f"({len(read_latencies) - int(len(read_latencies) * 0.95)} beyond it)")
         # Which phase of the store's cycle the window held (PERF.md, section 4).
         at, n = onset([r.done - t0 for r in by_done], seconds, PHASE_GAP_S)
         burst_rate = sum(r.events for r in by_done[:n]) / max(at, 1e-9)
@@ -606,6 +660,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             "write_p50_ms": percentile(latencies, 0.50),
             "write_p95_ms": percentile(latencies, 0.95),
             "setup_s": setup_s,
+            **({"read_p50_ms": percentile(read_latencies, 0.50)} if read_latencies else {}),
         }
         ctx["window_records"] = window
         ctx["window"] = {"t0": t0, "seconds": seconds, "answered_before": len(prefill)}
@@ -651,9 +706,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                                        "idle_gaps": reduced["idle_gaps"]}
         else:
             # Those of the harness's end-to-end numbers that the manifest lists for this cell.
-            metrics = {m["name"]: {"value": end_to_end[m["name"]], "unit": m["unit"]}
-                       for m in manifest["end_to_end"]
-                       if workload in m.get("workloads", [workload])}
+            owed = [m for m in manifest["end_to_end"] if workload in m.get("workloads", [workload])]
+            missing = sorted({m["name"] for m in owed} - set(end_to_end))
+            if missing:
+                raise Failure(f"the run has no {missing}: the cell lists it and its traffic "
+                              "sent no such request")
+            metrics = {m["name"]: {"value": end_to_end[m["name"]], "unit": m["unit"]} for m in owed}
         before, after = ctx["monitor_before"], ctx["monitor_after"]
         compiled_in_window = [f"{name} {how} in {took:.1f} s at {t - t0:.1f} s"
                               for name, t, took, how in after["names"] if t0 <= t < t1]
@@ -669,16 +727,18 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             "code_mismatches": [verdict["code_mismatches"], 0],
             "balance_mismatches": [verdict["balance_mismatches"], 0],
             "store_mismatches": [verdict["store_mismatches"], 0],
-            "requests_never_answered": [len(lost) + len(load.errors), 0],
+            "requests_never_answered": [len(lost_all) + len(load.errors), 0],
             "replies_sharing_an_op": [verdict["replies_sharing_an_op"], 0],
             "session_order_violations": [verdict["session_order_violations"], 0],
             "realtime_order_violations": [verdict["realtime_order_violations"], 0],
+            "read_mismatches": [verdict["read_mismatches"], 0],
             **({"survivor_rows_differing": [survivors_differ, 0],
                 "survivors_answered_in_old_view": [old_view_answers, 0]}
                if replicas > 1 else {}),
             "code_events_compared": [verdict["code_events_compared"], None],
             "accounts_compared": [verdict["accounts_compared"], None],
             "transfers_read_back": [verdict["transfers_read_back"], None],
+            "read_rows_compared": [verdict["read_rows_compared"], None],
         }
         correct = all(v == limit for v, limit in compared.values() if limit is not None)
         if problems:

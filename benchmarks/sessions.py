@@ -6,17 +6,20 @@ where the original could not serve as a yardstick:
 
 - the traffic comes from a seeded generator handed in, never from a seed
   fixed here;
-- every request is kept as a record (sent, done, the reply's body, and
-  the `op` of its header: the place the server committed it at), so the
+- every request is kept as a record (its operation, sent, done, the
+  reply's body, and the `op` of its header: the place the server
+  committed it at), so the
   window is cut out afterwards by the times of the replies, warm-up never
   mixes into a latency list, and the replay can follow the server's order;
 - a reply is kept, not counted: what it says is judged later against the
   plain reference (`accepted_tx` in the original never reads a code).
 
-A closed loop: each session sends its next batch when the reply lands
-(the next batch is built while the reply is awaited, so a session's think
-time is the seal and the send). The original's open loop is not copied:
-no cell offers load at a fixed rate yet (PERF.md, Open questions).
+A closed loop: each session sends its next request when the reply lands
+(the next one is built while the reply is awaited, so a session's think
+time is the seal and the send). A request is an operation and a body:
+what the generator names, `create_transfers` or `lookup_accounts`
+(`OPERATIONS`). The original's open loop is not copied: no cell offers
+load at a fixed rate yet (PERF.md, Open questions).
 
 Against a cluster a session holds one connection, to the replica it
 believes primary: a reply only comes over a connection the PRIMARY holds
@@ -43,16 +46,22 @@ from tigerbeetle_tpu.vsr import header as hdr
 from tigerbeetle_tpu.vsr.header import Command, Operation
 
 
+# What a generator may name, and the protocol's number for it.
+OPERATIONS = {"create_transfers": Operation.CREATE_TRANSFERS,
+              "lookup_accounts": Operation.LOOKUP_ACCOUNTS}
+
+
 @dataclasses.dataclass
 class Record:
-    """One create_transfers request of one session."""
+    """One request of one session."""
 
     session: int
-    seq: int  # the session's own order: batch (session, seq) of the generator
-    events: int
+    seq: int  # the session's own order: request (session, seq) of the generator
+    operation: str  # a key of OPERATIONS
+    events: int  # the events of a create_transfers, the ids of a lookup_accounts
     sent: float = 0.0  # 0.0: never sent
     done: float = 0.0  # 0.0: never answered
-    reply: Optional[bytes] = None  # the reply's body (EVENT_RESULT pairs)
+    reply: Optional[bytes] = None  # the reply's body (EVENT_RESULT pairs, or ACCOUNT rows)
     view: int = -1  # the view in the reply's header
     # The op in the reply's header: the primary numbers the prepares in the one order it
     # commits them (a resend answered from the client table carries the same one), so
@@ -197,15 +206,16 @@ class Session:
 class Load:
     """The sessions of one run and every request they made.
 
-    `make(session, seq)` returns the events of that batch (a structured
-    array); it is called in each session's own order."""
+    `make(session, seq)` returns that request as (operation, body): a key
+    of OPERATIONS and a structured array, one element an event or an id; it
+    is called in each session's own order."""
 
     def __init__(self, addresses: list, sessions: int, make: Callable, request_timeout: float):
         self.make = make
         self.sessions = [Session(addresses, request_timeout)
                          for _ in range(sessions)]
         self.records: List[Record] = []
-        self.completed = 0
+        self.completed = 0  # create_transfers requests answered: what prefill counts
         self.stopping = False
         self.errors: List[str] = []
         self._progress = asyncio.Event()
@@ -213,16 +223,16 @@ class Load:
     async def _closed(self, s: int) -> None:
         sess = self.sessions[s]
         seq = 0
-        body = self.make(s, seq)
+        operation, body = self.make(s, seq)
         while not self.stopping:
-            rec = Record(s, seq, len(body))
+            rec = Record(s, seq, operation, len(body))
             self.records.append(rec)
             call = asyncio.ensure_future(sess.roundtrip(
-                Operation.CREATE_TRANSFERS, body.tobytes(),
+                OPERATIONS[operation], body.tobytes(),
                 on_sent=lambda r=rec: self._stamp(r)))
             await asyncio.sleep(0)  # let the frame go out first
             seq += 1
-            body = self.make(s, seq)  # built while the reply is awaited
+            operation, body = self.make(s, seq)  # built while the reply is awaited
             if not await self._finish(rec, call):
                 return
 
@@ -233,14 +243,14 @@ class Load:
         try:
             reply = await call
         except (OSError, ConnectionError, asyncio.TimeoutError, TimeoutError) as e:
-            self.errors.append(f"session {rec.session} batch {rec.seq}: {e!r}")
+            self.errors.append(f"session {rec.session} request {rec.seq}: {e!r}")
             self._progress.set()
             return False
         rec.done = time.perf_counter()
         rec.reply = reply.body
         rec.view = int(reply.header["view"])
         rec.op = int(reply.header["op"])
-        self.completed += 1
+        self.completed += rec.operation == "create_transfers"
         self._progress.set()
         return True
 
@@ -261,8 +271,8 @@ class Load:
         return max(set(said), key=said.count) if said else 0
 
     async def until_completed(self, batches: int) -> None:
-        """Returns once `batches` requests have been answered (or a
-        session has given up: the caller reads `errors`)."""
+        """Returns once `batches` create_transfers requests have been
+        answered (or a session has given up: the caller reads `errors`)."""
         while self.completed < batches and not self.errors:
             self._progress.clear()
             await self._progress.wait()

@@ -48,6 +48,18 @@ upper reading of each compared number).
                    one order, and in real-time order"; the ledger itself
                    is sound, so where no answer depends on the order only
                    `realtime_order_violations` can tell
+  stale_reads      THE CONTROL where the traffic holds reads: a
+                   `lookup_accounts` that stands directly behind a
+                   `create_transfers` batch in the commit order answers
+                   from the balances as they stood BEFORE that batch, the
+                   shortcut a read served beside the commit window would
+                   be tempted by (it does not wait for the batch in flight
+                   ahead of it). A read behind another read sees
+                   everything, so the read-back after the drain does too.
+                   Breaks "a lookup_accounts returns every balance as the
+                   transfers committed before it left it". Every code, the
+                   final balances and the stored rows are right: only
+                   `read_mismatches` can tell
 """
 
 import os
@@ -180,6 +192,34 @@ def plant(fault: str) -> None:
 
         hdr.make_sealed = relabelled
         hdr.ReplyBuilder.build_one = lambda self, spec: build_one(self, raised(spec))
+    elif fault == "stale_reads":
+        fast, exact = commit.create_transfers_fast, commit.create_transfers_exact
+        sm = state_machine.StateMachine
+        accounts, transfers = sm.lookup_accounts, sm.lookup_transfers
+        before = []  # the state the newest commit kernel was given, until a read has passed
+
+        def remembering(kernel):
+            def commit_kernel(state, *args, **kw):
+                before[:] = [state]
+                return kernel(state, *args, **kw)
+            return commit_kernel
+
+        def lookup_accounts(self, ids_lo, ids_hi):
+            if not before:
+                return accounts(self, ids_lo, ids_hi)
+            live, self.state = self.state, before.pop()
+            try:
+                return accounts(self, ids_lo, ids_hi)
+            finally:
+                self.state = live
+
+        def lookup_transfers(self, ids_lo, ids_hi):
+            before.clear()
+            return transfers(self, ids_lo, ids_hi)
+
+        commit.create_transfers_fast = remembering(fast)
+        commit.create_transfers_exact = remembering(exact)
+        sm.lookup_accounts, sm.lookup_transfers = lookup_accounts, lookup_transfers
     elif fault == "store_altered":
         real = state_machine.StateMachine.lookup_transfers
 

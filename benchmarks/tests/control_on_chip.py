@@ -7,6 +7,8 @@ readings PERF.md quotes. The benchmark's own runs never run this.
 
     chiprun -- python3 benchmarks/tests/control_on_chip.py \
         --workload ledger_1m.transfers_sat --fault lossy_scatter --seeds 7,8,9
+    chiprun -- python3 benchmarks/tests/control_on_chip.py \
+        --workload smallbank_1m.hotspot_balance_sat --fault stale_reads --seeds 7,8,9
 
 A cell that BENCHMARK.json does not hold yet is found under `pending/`, as
 rehearse.py finds it; `--fault sound` with `--seconds 40` (and `--trace 1`)

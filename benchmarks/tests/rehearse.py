@@ -8,7 +8,8 @@ tests/test_chip_smoke.py does (run.py itself has no switch for it).
 The sizes are `test_min`'s: 64-event batches, 3 sessions + the read-back client (its client
 table), 1,000 accounts. A cell whose configuration has three replicas gets three CPU
 processes. A cell that BENCHMARK.json does not hold yet is found under `pending/`, by its
-name. Nothing it prints is a measurement.
+name. A cell whose traffic holds reads (`smallbank_1m.hotspot_balance_sat`) sends 18 ids a
+read where the cell sends 2,456. Nothing it prints is a measurement.
 """
 
 import argparse

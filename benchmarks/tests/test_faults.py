@@ -18,6 +18,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 CELL_1, CELL_2 = "ledger_1m.transfers_sat", "settlement_1m.two_phase_sat"
 WALLETS = "wallets_1m.spend_sat"  # under pending/: a rehearsal, not a cell
 SMALLBANK = "smallbank_1m.hotspot_sat"
+BALANCE = "smallbank_1m.hotspot_balance_sat"  # the sibling with SmallBank's reads (PR 37)
 
 # fault -> the compared numbers of which at least one must leave its limit, and the cells that
 # can have it. Each cell has one control: under `lossy_scatter` the exact kernel sees its
@@ -30,12 +31,13 @@ SMALLBANK = "smallbank_1m.hotspot_sat"
 EXPECT = {
     "lossy_scatter": ({"balance_mismatches"}, [CELL_1]),
     "chains_unlinked": ({"code_mismatches"}, [CELL_2, WALLETS]),
-    "limit_flags_dropped": ({"code_mismatches"}, [WALLETS, SMALLBANK]),
-    "ops_relabelled": ({"realtime_order_violations"}, [CELL_1, SMALLBANK]),
-    "state_unchanged": ({"balance_mismatches"}, [CELL_1, CELL_2, WALLETS, SMALLBANK]),
-    "half_left_out": ({"balance_mismatches"}, [CELL_1, CELL_2, WALLETS, SMALLBANK]),
-    "code_altered": ({"code_mismatches"}, [CELL_1, CELL_2, WALLETS, SMALLBANK]),
-    "store_altered": ({"store_mismatches"}, [CELL_1, CELL_2, WALLETS, SMALLBANK]),
+    "limit_flags_dropped": ({"code_mismatches"}, [WALLETS, SMALLBANK, BALANCE]),
+    "ops_relabelled": ({"realtime_order_violations"}, [CELL_1, SMALLBANK, BALANCE]),
+    "stale_reads": ({"read_mismatches"}, [BALANCE]),
+    "state_unchanged": ({"balance_mismatches"}, [CELL_1, CELL_2, WALLETS, SMALLBANK, BALANCE]),
+    "half_left_out": ({"balance_mismatches"}, [CELL_1, CELL_2, WALLETS, SMALLBANK, BALANCE]),
+    "code_altered": ({"code_mismatches"}, [CELL_1, CELL_2, WALLETS, SMALLBANK, BALANCE]),
+    "store_altered": ({"store_mismatches"}, [CELL_1, CELL_2, WALLETS, SMALLBANK, BALANCE]),
 }
 
 
@@ -58,9 +60,13 @@ def test_a_broken_server_is_not_correct(workload, fault):
     off = {name for name, (value, limit) in result["compared"].items()
            if limit is not None and value != limit}
     assert off & EXPECT[fault][0], result["compared"]
+    if fault == "stale_reads":  # everything else is sound: nothing but the reads can tell
+        assert off == {"read_mismatches"}, result["compared"]
+    if workload == BALANCE and fault in ("state_unchanged", "half_left_out"):
+        assert "read_mismatches" in off  # a read under load sees the state the kernel left
 
 
-@pytest.mark.parametrize("workload", [CELL_1, CELL_2, WALLETS, SMALLBANK])
+@pytest.mark.parametrize("workload", [CELL_1, CELL_2, WALLETS, SMALLBANK, BALANCE])
 def test_the_sound_server_is_correct(workload):
     result = rehearse(workload)
     assert result["correct"] is True and result["failed"] == 0

@@ -28,6 +28,8 @@ tbtpu_span_seconds_sum{event="device.unfed"} 10.0
 tbtpu_span_seconds_count{event="device.unfed"} 99
 tbtpu_span_seconds_sum{event="op.service.execute"} 7.0
 tbtpu_span_seconds_count{event="op.service.execute"} 400
+tbtpu_span_seconds_sum{event="device.read_balances"} 2.0
+tbtpu_span_seconds_count{event="device.read_balances"} 410
 tbtpu_events_total{event="device.h2d_bytes"} 1000000
 tbtpu_events_total{event="vsr.commits"} 400
 tbtpu_events_total{event="sm.route.exact_batches"} 400
@@ -46,6 +48,8 @@ tbtpu_span_seconds_sum{event="sm.ct.stage"} 0.9
 tbtpu_span_seconds_count{event="sm.ct.stage"} 600
 tbtpu_span_seconds_sum{event="op.service.execute"} 47.0
 tbtpu_span_seconds_count{event="op.service.execute"} 700
+tbtpu_span_seconds_sum{event="device.read_balances"} 3.5
+tbtpu_span_seconds_count{event="device.read_balances"} 710
 tbtpu_events_total{event="device.h2d_bytes"} 301000000
 tbtpu_events_total{event="vsr.commits"} 700
 tbtpu_events_total{event="sm.exact.sweeps"} 1200
@@ -83,6 +87,17 @@ def test_the_metric_files_read_differences():
     # a span first seen in the window, per batch committed in the window
     assert close(spans.read(spec("commit_stage_ms_per_batch"), c), 3.0)
     assert spans.read(spec("commit_barrier_ms_per_batch"), c) == 0.0  # absent_is_zero
+    # 1.5 s in 300 calls of the window (the checkpoint's whole-table read one of them): not 3.5 / 710
+    assert close(spans.read(spec("read_balances_ms_per_read"), c), 5.0)
+
+
+def test_a_window_without_a_read_reports_no_read_time():
+    c = ctx()
+    c["scrape_after"]["tbtpu_span_seconds_count"]["device.read_balances"] = 410.0
+    assert spans.read(spec("read_balances_ms_per_read"), c) is None  # the set-up's reads: not 0 ms
+    for page in ("scrape_before", "scrape_after"):
+        del c[page]["tbtpu_span_seconds_sum"]["device.read_balances"]
+    assert spans.read(spec("read_balances_ms_per_read"), c) is None
 
 
 @pytest.mark.parametrize("name,reader", [

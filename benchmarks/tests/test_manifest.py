@@ -90,3 +90,22 @@ def test_each_layer_metric_has_its_reader_and_moves_what_its_cells_report(pendin
         perf = f.read()
     for layer in layers:  # PERF.md's list of layers has each by that name
         assert f"**{layer}**" in perf, layer
+
+
+@both
+def test_a_read_latency_is_owed_by_the_cells_that_send_reads_and_no_other(pending):
+    """`read_p50_ms` (PR 37) lists the cells whose traffic file weighs a read;
+    a cell without reads has no such latency to report, and `run.py` fails a
+    run whose manifest asks for one."""
+    m = manifest(pending)
+    sends_reads = set()
+    for w in m["workloads"]:
+        with open(os.path.join(REPO, "benchmarks", "traffic", w["traffic"] + ".json")) as f:
+            if "balance" in json.load(f).get("weights", {}):
+                sends_reads.add(w["name"])
+    assert sends_reads == {"smallbank_1m.hotspot_balance_sat"}
+    for metric in m["end_to_end"] + m["per_layer"]:
+        if metric["name"].startswith("read_") or metric.get("moves", "").startswith("read_"):
+            assert set(metric["workloads"]) == sends_reads, metric["name"]
+    assert {"read_p50_ms", "read_balances_ms_per_read"} <= {
+        x["name"] for x in m["end_to_end"] + m["per_layer"]}

@@ -48,7 +48,7 @@ def test_the_manifest_has_the_cell_as_the_issue_states_it():
     sibling = next(w for w in m["workloads"] if w["name"] == SIBLING)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "tpcb_16m", sibling["traffic"], 1)
-    assert not any(w["chips"] == 4 for w in m["workloads"]) and len(m["workloads"]) == 6
+    assert not any(w["chips"] == 4 for w in m["workloads"]) and len(m["workloads"]) == 7
     for p in m["per_layer"]:
         listed = p.get("workloads")
         if p["name"] in BROUGHT:
