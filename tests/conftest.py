@@ -26,3 +26,18 @@ import pytest  # noqa: E402
 @pytest.fixture
 def rng():
     return np.random.default_rng(0x7B9)
+
+
+@pytest.fixture
+def traced():
+    """The tracer on and empty for one test (yields the module); test
+    files with needs of their own keep their own fixture of this name."""
+    from tigerbeetle_tpu import tracer
+
+    was = tracer.enabled()
+    tracer.enable()
+    tracer.reset()
+    yield tracer
+    tracer.reset()
+    if not was:
+        tracer.disable()

@@ -135,10 +135,19 @@ def test_commit_entry_is_compile_gated():
     # The real kernel + its gate are registered, not just the fixture's.
     assert "create_transfers_fast" in manifest.JIT_ENTRIES
     assert {"_device_batch", "_pad_slots"} <= manifest.JAXLINT_PAD_HELPERS
-    assert (
-        "tigerbeetle_tpu/models/state_machine.py",
-        "StateMachine.create_transfers_finish",
-    ) in manifest.JAXLINT_SYNC_SEAM
+    sm = "tigerbeetle_tpu/models/state_machine.py"
+    assert (sm, "StateMachine.create_transfers_finish") in manifest.JAXLINT_SYNC_SEAM
+    # The exact kernel's results are taken back in its finish half, from
+    # the single-phase path and from create_transfers_finish alike; what
+    # runs before the device is done stays outside the seam.
+    assert (sm, "StateMachine._exact_finish") in manifest.JAXLINT_SYNC_SEAM
+    for outside in ("create_transfers_dispatch", "_exact_stage", "_exact_dispatch",
+                    "_create_transfers_exact", "_ct_dispatch_stage"):
+        assert (sm, f"StateMachine.{outside}") not in manifest.JAXLINT_SYNC_SEAM
+    from tigerbeetle_tpu.models.state_machine import StateMachine
+
+    for name in {q.split(".")[1] for f, q in manifest.JAXLINT_SYNC_SEAM if f == sm}:
+        assert callable(getattr(StateMachine, name)), name  # no stale entry
 
     findings = jaxlint.analyze_file(
         FIXTURES / "retrace_commit_batch.py", REPO, passes=("retrace",)

@@ -446,21 +446,25 @@ class TestParseHeaders:
         assert _parse_headers(b"") == []
 
 
+def _small_state_machine():
+    from tigerbeetle_tpu.constants import Config
+    from tigerbeetle_tpu.models.state_machine import StateMachine
+
+    config = Config(
+        name="t", accounts_max=1 << 10, transfers_max=1 << 12,
+        lsm_block_size=1 << 12, grid_block_count=1 << 10,
+        grid_cache_blocks=16, index_memtable_rows=512,
+    )
+    return StateMachine(config, backend="jax")
+
+
 class TestSplitPhaseDispatch:
     """create_transfers_dispatch/finish must be byte-identical to the
     single-phase path, including the bail→serial fallback and the
     id-overlap refusal."""
 
     def _sm(self):
-        from tigerbeetle_tpu.constants import Config
-        from tigerbeetle_tpu.models.state_machine import StateMachine
-
-        config = Config(
-            name="t", accounts_max=1 << 10, transfers_max=1 << 12,
-            lsm_block_size=1 << 12, grid_block_count=1 << 10,
-            grid_cache_blocks=16, index_memtable_rows=512,
-        )
-        sm = StateMachine(config, backend="jax")
+        sm = _small_state_machine()
         n = 16
         ev = np.zeros(n, dtype=types.ACCOUNT_DTYPE)
         ev["id_lo"] = np.arange(1, n + 1)
@@ -678,3 +682,238 @@ class TestDispatchWindow:
         la = sm.lookup_accounts(np.array([1], np.uint64), np.array([0], np.uint64))
         lb = ref.lookup_accounts(np.array([1], np.uint64), np.array([0], np.uint64))
         assert la.tobytes() == lb.tobytes()
+
+
+# --- exact-kernel handles in the window ----------------------------------
+
+_LINKED = 1
+_PENDING = 2
+_POST = 4
+_BALANCING_DEBIT = 16
+_LIMIT = 2  # AccountFlags.DEBITS_MUST_NOT_EXCEED_CREDITS
+_HISTORY = 8
+_EXCEEDS_CREDITS = 54
+_EXACT = "create_transfers_exact"
+_FAST = "create_transfers_fast"
+
+
+def _exact_sm():
+    """Accounts 1-8 plain, 9-12 may not be overdrawn, 13 keeps its history."""
+    sm = _small_state_machine()
+    acc = np.zeros(13, dtype=types.ACCOUNT_DTYPE)
+    acc["id_lo"] = np.arange(1, 14)
+    acc["ledger"] = 1
+    acc["code"] = 10
+    acc["flags"][8:12] = _LIMIT
+    acc["flags"][12] = _HISTORY
+    assert len(sm.create_accounts(acc, timestamp=13)) == 0
+    return sm
+
+
+def _transfers(first_id, rows):
+    """rows: (debit, credit, amount, flags) each."""
+    t = np.zeros(len(rows), dtype=types.TRANSFER_DTYPE)
+    t["id_lo"] = first_id + np.arange(len(rows))
+    t["ledger"] = 1
+    t["code"] = 7
+    for i, (dr, cr, amount, flags) in enumerate(rows):
+        t["debit_account_id_lo"][i] = dr
+        t["credit_account_id_lo"][i] = cr
+        t["amount_lo"][i] = amount
+        t["flags"][i] = flags
+    return t
+
+
+def _exact_batch(kind: str, k: int) -> np.ndarray:
+    """Batch k of its kind; every kind but `fast` takes the exact kernel
+    and none reads the store."""
+    first = 10_000 + 100 * k
+    if kind == "chains":  # chains of three; the second has a link that fails
+        rows = []
+        for c in range(3):
+            a = 1 + (k + c) % 8
+            b = 1 + (a % 8)
+            rows += [(a, b, 5 + k, _LINKED), (b, a, 0 if c == 1 else 3, _LINKED), (a, b, 2, 0)]
+        return _transfers(first, rows)
+    if kind == "limits":  # a deposit, then a line of debits that outruns it
+        return _transfers(first, [(1, 9, 100, 0)] + [(9, 2, 30 + k, 0)] * 6 + [(2, 9, 40, 0), (9, 3, 35, 0)])
+    if kind == "balancing":  # what account 10 holds, and not the amount asked
+        return _transfers(first, [(1, 10, 50 + k, 0), (10, 2, 1000, _BALANCING_DEBIT), (10, 3, 7, 0)])
+    if kind == "pendings":  # holds against a limit; nothing posts or voids them
+        return _transfers(first, [(1, 11, 60, 0), (11, 2, 25, _PENDING), (11, 3, 25, _PENDING), (11, 4, 25, _PENDING)])
+    assert kind == "fast"
+    return _transfers(first, [(1 + (k + i) % 8, 1 + (k + i + 1) % 8, 1 + k, 0) for i in range(4)])
+
+
+EXACT_KINDS = ("chains", "limits", "balancing", "pendings")
+
+
+def _same_ledger(sm, ref, batches) -> None:
+    ids = np.arange(1, 14, dtype=np.uint64)
+    zeros = np.zeros(len(ids), np.uint64)
+    assert sm.lookup_accounts(ids, zeros).tobytes() == ref.lookup_accounts(ids, zeros).tobytes()
+    tid = np.concatenate([b["id_lo"] for b in batches])
+    tz = np.zeros(len(tid), np.uint64)
+    assert sm.lookup_transfers(tid, tz).tobytes() == ref.lookup_transfers(tid, tz).tobytes()
+    assert sm.commit_timestamp == ref.commit_timestamp
+
+
+def _finish(sm, handle):
+    """A finish as the commit stage makes it: the batch's deferred rows are
+    stored before the next finish defers its own (replica._finish_commit)."""
+    out = sm.create_transfers_finish(handle)
+    sm.flush_deferred()
+    return out
+
+
+def _count(tracer, name: str) -> int:
+    return tracer.snapshot().get(name, {}).get("count", 0)
+
+
+class TestExactDispatch:
+    """The split-phase pair on the exact kernel: a batch of the deferring
+    kind (no post/void event, no history account) is dispatched ahead and
+    finished by its kind, byte for byte the single-phase path; every other
+    exact batch is refused, by a counter that says why."""
+
+    @pytest.mark.parametrize("kind", EXACT_KINDS)
+    def test_dispatch_finish_matches_single_phase(self, kind):
+        """Two batches of one kind out before the first finish."""
+        from tigerbeetle_tpu.models.state_machine import EXACT_DISPATCH_MAX
+
+        assert EXACT_DISPATCH_MAX >= 2
+        sm, ref = _exact_sm(), _exact_sm()
+        batches = [_exact_batch(kind, k) for k in range(4)]
+        outs = []
+        for i in (0, 2):
+            handles = [sm.create_transfers_dispatch(b, 1000 + 100 * (i + j))
+                       for j, b in enumerate(batches[i:i + 2])]
+            assert all(h is not None and h["kernel"] == _EXACT for h in handles)
+            assert sm.exact_window_full()
+            outs += [_finish(sm, h) for h in handles]
+        refs = [ref.create_transfers(b, timestamp=1000 + 100 * i) for i, b in enumerate(batches)]
+        assert [o.tobytes() for o in outs] == [r.tobytes() for r in refs]
+        assert sm.stats["exact_batches"] == ref.stats["exact_batches"] == 4
+        assert not sm.stats["bail_batches"] and not sm.stats["serial_batches"]
+        if kind == "limits":  # the balance check did refuse, on both paths alike
+            assert any((o["result"] == _EXCEEDS_CREDITS).any() for o in outs)
+        if kind == "chains":  # and a chain did roll back
+            assert all(len(o) == 3 for o in outs)
+        _same_ledger(sm, ref, batches)
+
+    def test_more_than_one_sweep_is_counted_at_the_finish(self, traced):
+        sm = _exact_sm()
+        h = sm.create_transfers_dispatch(_exact_batch("limits", 0), 1000)
+        assert _count(traced, "sm.exact.sweeps") == 0  # nothing taken back yet
+        _finish(sm, h)
+        assert _count(traced, "sm.exact.sweeps") > 1
+        assert _count(traced, "sm.exact.dispatched_ahead") == 1
+        assert _count(traced, "sm.exact.store_deferred") == 1
+
+    def test_window_of_fast_and_exact_handles_mixed(self):
+        sm, ref = _exact_sm(), _exact_sm()
+        kinds = ("fast", "limits", "fast", "chains", "fast")
+        batches = [_exact_batch(kind, k) for k, kind in enumerate(kinds)]
+        handles = [sm.create_transfers_dispatch(b, 1000 + 100 * i) for i, b in enumerate(batches)]
+        assert [h["kernel"] for h in handles] == [_FAST, _EXACT, _FAST, _EXACT, _FAST]
+        outs = [_finish(sm, h) for h in handles]
+        refs = [ref.create_transfers(b, timestamp=1000 + 100 * i) for i, b in enumerate(batches)]
+        assert [o.tobytes() for o in outs] == [r.tobytes() for r in refs]
+        assert sm.stats["fast_batches"] == 3 and sm.stats["exact_batches"] == 2
+        _same_ledger(sm, ref, batches)
+
+    @pytest.mark.parametrize("why", ["pv", "history", "overlap", "dup", "stored_id", "window_full"])
+    def test_refusal_is_counted_and_the_batch_stays_correct(self, traced, why):
+        """Each refused batch runs whole at its turn, behind a settled
+        window (the barrier, where it takes one, with nothing outstanding),
+        and answers as the single-phase path does."""
+        sm, ref = _exact_sm(), _exact_sm()
+        first, second = _exact_batch("pendings", 0), _exact_batch("limits", 1)
+        barrier = sm.store_barrier
+
+        def barrier_behind_a_settled_window():
+            assert not sm._ct_pending
+            barrier()
+
+        sm.store_barrier = barrier_behind_a_settled_window
+        if why == "pv":  # posts a pending of the batch in flight
+            refused = _exact_batch("chains", 2)
+            refused["flags"][8] = _POST
+            refused["pending_id_lo"][8] = first["id_lo"][1]
+            refused["amount_lo"][8] = 0
+        elif why == "history":
+            refused = _transfers(10_200, [(1, 13, 5, 0), (13, 2, 3, 0)])
+        elif why == "overlap":  # an id of the batch in flight
+            refused = _exact_batch("limits", 2)
+            refused[3] = second[3]
+        elif why == "dup":
+            refused = _exact_batch("chains", 2)
+            refused["id_lo"][4] = refused["id_lo"][0]
+        else:
+            refused = _exact_batch("chains", 2)
+        if why == "stored_id":  # an id that is stored by the time it is offered
+            ran = sm.create_transfers(first, timestamp=1000)
+            refused[2] = first[2]
+            handles = [sm.create_transfers_dispatch(second, 1100)]
+        else:
+            handles = [sm.create_transfers_dispatch(first, 1000),
+                       sm.create_transfers_dispatch(second, 1100)]
+        assert None not in handles
+        assert sm.create_transfers_dispatch(refused, 1200) is None
+        refusals = {k[len("sm.ct.dispatch_refused."):]: v["count"]
+                    for k, v in traced.snapshot().items() if k.startswith("sm.ct.dispatch_refused.")}
+        assert refusals == {why: 1}
+        assert _count(traced, "sm.exact.dispatched_ahead") == len(handles)
+        outs = [_finish(sm, h) for h in handles]
+        outs.append(sm.create_transfers(refused, timestamp=1200))
+        batches = [first, second, refused]
+        refs = [ref.create_transfers(b, timestamp=1000 + 100 * i) for i, b in enumerate(batches)]
+        if why == "stored_id":
+            outs.insert(0, ran)
+        assert [o.tobytes() for o in outs] == [r.tobytes() for r in refs]
+        _same_ledger(sm, ref, batches)
+
+    def test_exact_bail_mid_window_rolls_back_and_later_handles_refire(self, traced):
+        """The first of three batches is one line of 100 dependent events
+        (each balancing debit funded by the one before it: more than the
+        kernel's 64 sweeps): its kernel bails at the finish, the state
+        token goes back to the one it was given, the serial path answers,
+        and the two kernels that ran on the revoked token are thrown away
+        and refired by `gen`."""
+        sm, ref = _exact_sm(), _exact_sm()
+        line = _transfers(20_000, [(1, 9, 1000, 0)] + [
+            (9 + i % 4, 9 + (i + 1) % 4, 1000, _BALANCING_DEBIT) for i in range(100)
+        ])
+        batches = [line, _exact_batch("limits", 1), _exact_batch("fast", 2)]
+        handles = [sm.create_transfers_dispatch(b, 1000 + 100 * i) for i, b in enumerate(batches)]
+        assert [h["kernel"] for h in handles] == [_EXACT, _EXACT, _FAST]
+        gen, given = sm._state_gen, handles[0]["prev_state"]
+        out0 = _finish(sm, handles[0])
+        assert sm.stats["bail_batches"] == 1 and sm._state_gen == gen + 1
+        assert given is not sm.state  # the serial path wrote its own balances over it
+        outs = [out0] + [_finish(sm, h) for h in handles[1:]]
+        assert sm._state_gen == gen + 3 and not sm._ct_pending
+        # every device window opened was closed, each under its own kernel
+        for kernel, n in ((_EXACT, 3), (_FAST, 2)):  # dispatched ahead, then refired
+            assert _count(traced, f"device.{kernel}.dispatches") == n
+            assert _count(traced, f"device.step.{kernel}") == n
+        refs = [ref.create_transfers(b, timestamp=1000 + 100 * i) for i, b in enumerate(batches)]
+        assert [o.tobytes() for o in outs] == [r.tobytes() for r in refs]
+        assert len(out0) == 0  # every link of the line moved the 1000 on
+        _same_ledger(sm, ref, batches)
+
+    def test_abandon_all_closes_exact_windows_under_their_own_name(self, traced):
+        sm, ref = _exact_sm(), _exact_sm()
+        batches = [_exact_batch("chains", 0), _exact_batch("fast", 1), _exact_batch("limits", 2)]
+        before = np.asarray(sm.state.debits_posted).copy()
+        for i, b in enumerate(batches):
+            assert sm.create_transfers_dispatch(b, 1000 + 100 * i) is not None
+        sm.create_transfers_abandon_all()
+        assert not sm._ct_pending and not sm.exact_window_full()
+        assert np.array_equal(before, np.asarray(sm.state.debits_posted))
+        assert _count(traced, f"device.step.{_EXACT}") == 2
+        assert _count(traced, f"device.step.{_FAST}") == 1
+        outs = [sm.create_transfers(b, timestamp=1000 + 100 * i) for i, b in enumerate(batches)]
+        refs = [ref.create_transfers(b, timestamp=1000 + 100 * i) for i, b in enumerate(batches)]
+        assert [o.tobytes() for o in outs] == [r.tobytes() for r in refs]
+        _same_ledger(sm, ref, batches)

@@ -113,7 +113,7 @@ JIT_ENTRIES = {
 JAXLINT_SYNC_SEAM = frozenset((
     ("tigerbeetle_tpu/models/state_machine.py", "StateMachine._commit_fast_device"),
     ("tigerbeetle_tpu/models/state_machine.py", "StateMachine.create_transfers_finish"),
-    ("tigerbeetle_tpu/models/state_machine.py", "StateMachine._create_transfers_exact"),
+    ("tigerbeetle_tpu/models/state_machine.py", "StateMachine._exact_finish"),
     ("tigerbeetle_tpu/models/state_machine.py", "StateMachine._read_balances"),
 ))
 

@@ -1770,9 +1770,11 @@ class Replica:
     def _stage_dispatch(self, job: dict):
         """Double-buffered device dispatch: launch this batch's device
         kernel BEFORE the previous batch's device→host sync. Returns a
-        state-machine handle, or None when the op cannot be dispatched
-        ahead (non-transfer op, routing depends on the outstanding batch,
-        host-only backend)."""
+        state-machine handle (a fast-kernel or an exact-kernel one: the
+        stage treats both alike), or None when the op cannot be
+        dispatched ahead (non-transfer op, routing depends on the
+        outstanding batch, a batch that reads the store, host-only
+        backend)."""
         h = job["msg"].header
         if h["operation"] != Operation.CREATE_TRANSFERS:
             return None
@@ -1806,7 +1808,13 @@ class Replica:
             job["_handle"] = handle
             self._stage_window.append(job)
             self._stage_note_inflight(len(self._stage_window))
-            while len(self._stage_window) >= self.commit_depth:
+            # Settle down to the configured depth, and further while the
+            # state machine's bound on exact handles is reached (each
+            # keeps a balance table alive): the next batch then finds
+            # room, where a refusal would run it single-phase.
+            while len(self._stage_window) >= self.commit_depth or (
+                self._stage_window and self.state_machine.exact_window_full()
+            ):
                 head = self._stage_window.popleft()
                 publish, ok = self._stage_settle(head, self._stage_exec_held)
                 if not ok:
