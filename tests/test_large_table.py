@@ -6,11 +6,14 @@ fast and the exact commit kernel against the serial oracle with 2^16
 slots and 64-event batches whose slots come from the top of the table
 too, a hot cash slot among them; `create_accounts` over several batches
 to the very last slot, and one more; a checkpoint of more accounts than a
-batch registers, restored after a crash with every balance byte-exact, and
-the spans and the counter the checkpoint records.
+batch registers, restored after a crash with every account byte-exact (its
+immutable half from the account log, its balances from the blob), the
+spans and the counter the checkpoint records, and a blob that grows by the
+balances alone.
 """
 
 import dataclasses
+import io
 import json
 import os
 
@@ -50,8 +53,9 @@ def test_the_preset_is_production_with_a_larger_table_and_grid():
 
 def test_the_preset_holds_the_deployment_it_was_made_for():
     """Slots for every account; a grid for two live checkpoint trailers
-    (128 B an account and the manifests) beside the store's content of a
-    run of 1,900 full batches, under 85% of it."""
+    (64 B of balances an account and the manifests) and the account log
+    (64 B an account, once) beside the store's content of a run of 1,900
+    full batches, under 85% of it."""
     config, preset = deployment(), PRODUCTION_16M
     assert config["start"]["config"] == preset.name
     assert config["accounts"] <= preset.accounts_max == config["accounts_max"]
@@ -59,11 +63,12 @@ def test_the_preset_holds_the_deployment_it_was_made_for():
         config["accounts_per_branch"] + config["tellers_per_branch"] + 2)
     assert config["transfers_max"] == preset.transfers_max
     payload = preset.lsm_block_size - 64
-    trailer = -(-(config["accounts"] * 128 + (8 << 20)) // payload) + 1
+    trailer = -(-(config["accounts"] * 64 + (8 << 20)) // payload) + 1
+    account_log = -(-config["accounts"] // (payload // 64))
     # PRODUCTION's grid at its fullest (85% of 2^15 blocks, PERF.md section 4) held two
-    # trailers of 515 blocks: the rest was content.
+    # trailers of 515 blocks (128 B an account then): the rest was content.
     content = int(0.85 * PRODUCTION.grid_block_count) - 2 * 515
-    assert 2 * trailer + content <= 0.85 * preset.grid_block_count
+    assert 2 * trailer + account_log + content <= 0.85 * preset.grid_block_count
     # one trailer's chunks are listed in ONE index block (replica._trailer_write asserts it)
     assert trailer - 1 <= (payload - 32) // 4
 
@@ -176,37 +181,84 @@ def all_accounts(cluster, client, count: int) -> bytes:
     return out
 
 
-def test_a_checkpoint_of_many_batches_of_accounts_is_restored_to_the_byte():
-    """Five batches of accounts, transfers over all of them past the
-    checkpoint at op 16; crash, restart from the trailer, and every
-    account reads as it did. The checkpoint left its span, its four leaves
-    and its blob's length on the tracer."""
+def pay(cluster, client, rng, first_id: int, count: int) -> None:
+    """One batch of transfers between any of the first `count` accounts."""
+    t = np.zeros(N, dtype=types.TRANSFER_DTYPE)
+    t["id_lo"] = np.arange(first_id, first_id + N, dtype=np.uint64)
+    t["debit_account_id_lo"] = rng.integers(1, count + 1, N)
+    t["credit_account_id_lo"] = t["debit_account_id_lo"] % count + 1
+    t["amount_lo"] = rng.integers(1, 1_000_000, N)
+    t["ledger"], t["code"] = 1, 1
+    reply = request(cluster, client, Operation.CREATE_TRANSFERS, t.tobytes())
+    assert len(parse_results(reply)) == 0
+
+
+def create(cluster, client, first: int) -> None:
+    reply = request(cluster, client, Operation.CREATE_ACCOUNTS,
+                    accounts_batch(first, N).tobytes())
+    assert len(parse_results(reply)) == 0
+
+
+@pytest.mark.parametrize("store_async", [False, True], ids=["inline", "store_stage"])
+def test_a_checkpoint_of_many_batches_of_accounts_is_restored_to_the_byte(store_async):
+    """Five batches of accounts, transfers over them, a sixth batch as the
+    checkpoint's own op (its beat leaves its full account-log block
+    pending, so the checkpoint flushes it), and a seventh after the
+    checkpoint; crash, restart from the trailer and the WAL, and every
+    account's 128 bytes read as they did. The blob carries the balances,
+    the account log's blocks and its tail, and none of the immutable
+    columns. The checkpoint left its span, its four leaves and its blob's
+    length on the tracer."""
     was = tracer.enabled()
     tracer.enable()
     tracer.reset()
     try:
-        cluster = Cluster(replica_count=1, sm_backend="jax")
+        cluster = Cluster(replica_count=1, sm_backend="jax", store_async=store_async)
         client = cluster.clients[100]
         client.register()
         cluster.run_until(lambda: client.registered)
+        replica = cluster.replicas[0]
+        sm = replica.state_machine
+        interval = TEST_MIN.checkpoint_interval
         count = 5 * N
         for first in range(1, count + 1, N):
-            reply = request(cluster, client, Operation.CREATE_ACCOUNTS,
-                            accounts_batch(first, N).tobytes())
-            assert len(parse_results(reply)) == 0
+            create(cluster, client, first)
         rng = np.random.default_rng(16)
-        for k in range(14):
-            t = np.zeros(N, dtype=types.TRANSFER_DTYPE)
-            t["id_lo"] = np.arange(1 + k * N, 1 + (k + 1) * N, dtype=np.uint64)
-            t["debit_account_id_lo"] = rng.integers(1, count + 1, N)
-            t["credit_account_id_lo"] = t["debit_account_id_lo"] % count + 1
-            t["amount_lo"] = rng.integers(1, 1_000_000, N)
-            t["ledger"], t["code"] = 1, 1
-            reply = request(cluster, client, Operation.CREATE_TRANSFERS, t.tobytes())
-            assert len(parse_results(reply)) == 0
-        replica = cluster.replicas[0]
-        assert replica.superblock.state.op_checkpoint == TEST_MIN.checkpoint_interval
-        assert replica.state_machine.account_count == count > TEST_MIN.batch_max
+        next_id = 1
+        while replica.commit_min < interval - 1:
+            pay(cluster, client, rng, next_id, count)
+            next_id += N
+
+        pending = []
+
+        def beat_that_writes_nothing(max_blocks=8, flush=True):
+            type(sm).compact_beat(sm, 0, flush=flush)
+            pending.append(len(sm.account_log._pending_blocks))
+
+        sm.compact_beat = beat_that_writes_nothing
+        create(cluster, client, count + 1)
+        del sm.compact_beat
+        count += N
+        assert replica.superblock.state.op_checkpoint == interval
+        assert pending == [1]
+        rpb = sm.account_log.records_per_block
+        blob = replica._trailer_read(replica.superblock.state.trailer_block)
+        with np.load(io.BytesIO(blob)) as z:
+            assert int(z["version"]) == 8 and int(z["account_count"]) == count
+            assert not [k for k in z.files if k.startswith("acc_")]
+            assert len(z["alog_blocks"]) == count // rpb >= 2
+            assert len(z["alog_tail"]) == count % rpb > 0
+            assert z["bal_dpo"].shape == (count, 4)
+
+        for _ in range(3):
+            pay(cluster, client, rng, next_id, count)
+            next_id += N
+        create(cluster, client, count + 1)
+        count += N
+        for _ in range(2):
+            pay(cluster, client, rng, next_id, count)
+            next_id += N
+        assert sm.account_count == count > TEST_MIN.batch_max
         before = all_accounts(cluster, client, count)
         assert len(before) == count * 128
         snap = tracer.snapshot()
@@ -215,19 +267,45 @@ def test_a_checkpoint_of_many_batches_of_accounts_is_restored_to_the_byte():
         assert all(snap[f"vsr.checkpoint.{leaf}"]["count"] == 1 for leaf in leaves)
         assert (sum(snap[f"vsr.checkpoint.{leaf}"]["total_ms"] for leaf in leaves)
                 <= snap["vsr.checkpoint"]["total_ms"] + 0.01)  # (each rounded to 1 us)
-        assert snap["vsr.checkpoint.blob_bytes"]["count"] > count * 128
+        assert snap["vsr.checkpoint.encode.read"]["count"] == 1
+        assert (snap["vsr.checkpoint.encode.read"]["total_ms"]
+                <= snap["vsr.checkpoint.encode"]["total_ms"] + 0.001)
+        assert snap["vsr.checkpoint.blob_bytes"]["count"] == len(blob)
 
         cluster.storages[0].sync()
         cluster.crash_replica(0)
         cluster.restart_replica(0)
         cluster.run_until(lambda: cluster.replicas[0].status == "normal")
         restored = cluster.replicas[0].state_machine
-        assert restored.account_count == count
+        assert restored.account_count == restored.account_log.count == count
         ids = np.arange(1, count + 1, dtype=np.uint64)
         after = restored.lookup_accounts(ids, np.zeros(count, np.uint64))
         assert after.tobytes() == before
-        assert np.frombuffer(before, types.ACCOUNT_DTYPE)["debits_posted_lo"].sum() > 0
+        rows = np.frombuffer(before, types.ACCOUNT_DTYPE)
+        assert (rows["debits_posted_lo"][-N:] > 0).any()  # an account made after the checkpoint
+        assert (rows["credits_posted_lo"][: count - N] > 0).sum() > count // 2
     finally:
         tracer.reset()
         if not was:
             tracer.disable()
+
+
+def test_a_checkpoint_blob_grows_by_the_balances_alone():
+    """Each account adds its 64 bytes of balances to the blob, and the
+    account log's share of a block reference (28 bytes a block of 63
+    accounts at this size); the immutable 64 bytes are not in it. Beyond
+    that the blob holds a bounded overhead: headers, manifests, the free
+    set and the log's tail."""
+    cluster = Cluster(replica_count=1, sm_backend="jax")
+    client = cluster.clients[100]
+    client.register()
+    cluster.run_until(lambda: client.registered)
+    replica = cluster.replicas[0]
+    sizes = {}
+    for first in range(1, 15 * N + 1, N):
+        create(cluster, client, first)
+        sizes[replica.state_machine.account_count] = len(replica._save_snapshot())
+    (c0, b0), (c1, b1) = min(sizes.items()), max(sizes.items())
+    assert 64 <= (b1 - b0) / (c1 - c0) < 66
+    for c, b in sizes.items():
+        assert 64 * c < b < 64 * c + (24 << 10)

@@ -292,6 +292,50 @@ class TestChunkedSync:
         assert stats["missing"] == stats["wanted"] > 0, stats
         cl.check_state_convergence()
 
+    def test_synced_accounts_are_found_only_once_their_log_arrived(self, monkeypatch):
+        """A receiver with an empty grid installs the blob: it counts the
+        accounts, but finds none by id until block sync has brought the
+        account log's blocks and the accounts were rebuilt from them; then
+        every account's 128 bytes read as on the primary."""
+        from tigerbeetle_tpu.io.storage import MemStorage
+        from tigerbeetle_tpu.vsr.replica import Replica
+
+        cl, bi, c = self._lagging_backup_cluster()
+        cl.storages[bi] = MemStorage(cl.zone.total_size, seed=998)
+        Replica.format(cl.storages[bi], cl.zone, cl.cluster_id, bi, 3)
+        ids = np.arange(1, 121, dtype=np.uint64)
+        zeros = np.zeros(len(ids), dtype=np.uint64)
+        seen = []
+        finish = Replica._finish_block_sync
+
+        def finish_and_look(replica):
+            sm = replica.state_machine
+            log_blocks = list(sm.account_log.blocks)
+            seen.append((
+                sm.account_count, len(log_blocks),
+                all(sm.grid.local_checksum(b) == sm.grid.block_cks[b]
+                    for b in log_blocks),
+                len(sm.lookup_accounts(ids, zeros)),
+            ))
+            finish(replica)
+            seen.append(len(sm.lookup_accounts(ids, zeros)))
+
+        monkeypatch.setattr(Replica, "_finish_block_sync", finish_and_look)
+        cl.restart_replica(bi)
+        target = max(r.commit_min for r in cl.replicas if r is not None)
+        cl.run_until(
+            lambda: cl.replicas[bi].commit_min >= target, max_ticks=200_000
+        )
+        count, log_blocks, present, found = seen[0]
+        assert count == 120 and log_blocks >= 1 and present and found == 0
+        assert seen[1] == 120
+        primary = next(r for r in cl.replicas if r is not None and r.is_primary)
+        rb = cl.replicas[bi]
+        assert rb.commit_min == primary.commit_min
+        want = primary.state_machine.lookup_accounts(ids, zeros)
+        assert rb.state_machine.lookup_accounts(ids, zeros).tobytes() == want.tobytes()
+        assert types.u128_of(want[0], "debits_posted") > 0
+
 
 class TestStaleInstallAbandon:
     def test_install_abandons_when_drain_overtakes_blob(self):

@@ -4,8 +4,10 @@ The reference keeps EVERY state-machine collection in a groove (object
 tree + indexes, /root/reference/src/lsm/groove.zig:138; the state machine
 declares four — accounts, transfers, posted, account_history,
 state_machine.zig:167-303). This build keeps accounts device/RAM-resident
-(they are the flagship kernel's working set, bounded by accounts_max) and
-transfers in DurableLog + DurableIndex; this module adds the remaining
+(they are the flagship kernel's working set, bounded by accounts_max),
+their immutable half also in a DurableLog in creation order (the accounts
+groove's object tree), and transfers in DurableLog + DurableIndex; this
+module adds the remaining
 two grooves so NO state grows unbounded in Python structures:
 
   PostedGroove   — pending-transfer fulfillment (timestamp -> posted/
@@ -44,6 +46,17 @@ HISTORY_DTYPE = np.dtype(
         for half in ("lo", "hi")
     ]
 )
+
+# One account's immutable half, as the account log stores it: everything
+# create_accounts writes once. The balances are the other half; they change
+# with every transfer and stay on the device (and in the checkpoint blob).
+ACCOUNT_LOG_DTYPE = np.dtype([
+    ("id_lo", "<u8"), ("id_hi", "<u8"),
+    ("user_data_128_lo", "<u8"), ("user_data_128_hi", "<u8"),
+    ("user_data_64", "<u8"), ("timestamp", "<u8"),
+    ("user_data_32", "<u4"), ("ledger", "<u4"), ("code", "<u4"), ("flags", "<u4"),
+])
+assert ACCOUNT_LOG_DTYPE.itemsize == 64
 
 
 class PostedGroove:

@@ -283,6 +283,13 @@ class StateMachine:
             name="query_rows", merge_hint="dups",
         )
         self.transfer_log = DurableLog(self.grid, types.TRANSFER_DTYPE)
+        # The accounts' immutable half (the acc_* mirrors above), appended
+        # once at creation in slot order: the checkpoint references its
+        # blocks instead of re-encoding every account, and a restore
+        # rebuilds the mirrors from it (restore_accounts).
+        from tigerbeetle_tpu.lsm.groove import ACCOUNT_LOG_DTYPE
+
+        self.account_log = DurableLog(self.grid, ACCOUNT_LOG_DTYPE)
         # Transfer-id membership pre-filter (no false negatives): keeps the
         # per-batch duplicate-id check O(batch) instead of O(tables).
         # tidy: owner=commit|store — adds are commit-side only (the store job passes add_bloom=False); probes are commit-side
@@ -309,6 +316,10 @@ class StateMachine:
         # op order for determinism — inline, or as a StoreExecutor job
         # when the async store stage is attached).
         self._deferred_store = None  # tidy: owner=commit
+        # The same for the last op's new accounts: their account-log
+        # records, appended by the op's store job like its transfers, so
+        # the log's appends and its beat flushes stay on one thread.
+        self._deferred_accounts = None  # tidy: owner=commit
         # Optional async store stage (vsr/pipeline.StoreExecutor, attached
         # by the replica): queued jobs hold this state machine's pending
         # groove/index writes + beats; store_barrier drains it before any
@@ -403,6 +414,58 @@ class StateMachine:
             with tracer.span("sm.ct.store"):
                 # Bloom membership was already published at defer time.
                 self._store_new_transfers(recs, ts=ts, add_bloom=False)
+        accounts = self.take_deferred_accounts()
+        if accounts is not None:
+            self.account_log.append_batch(accounts)
+
+    def take_deferred_accounts(self):  # tidy: thread=commit
+        """Pop the last op's account-log records (None if it created no
+        account), for its store job or for flush_deferred."""
+        tidy_runtime.assert_role("commit", "loop")
+        d = self._deferred_accounts
+        self._deferred_accounts = None
+        return d
+
+    def _log_new_accounts(self, first: int, last: int) -> None:  # tidy: thread=commit
+        """Defer the account-log records of slots [first, last), just
+        written to the mirrors, to the op's store."""
+        s = slice(first, last)
+        recs = np.empty(last - first, dtype=self.account_log.dtype)
+        recs["id_lo"] = self.acc_key["lo"][s]
+        recs["id_hi"] = self.acc_key["hi"][s]
+        recs["user_data_128_lo"] = self.acc_user_data_128_lo[s]
+        recs["user_data_128_hi"] = self.acc_user_data_128_hi[s]
+        recs["user_data_64"] = self.acc_user_data_64[s]
+        recs["user_data_32"] = self.acc_user_data_32[s]
+        recs["ledger"] = self.acc_ledger[s]
+        recs["code"] = self.acc_code[s]
+        recs["flags"] = self.acc_flags[s]
+        recs["timestamp"] = self.acc_timestamp[s]
+        self._deferred_accounts = recs
+
+    def restore_accounts(self, count: int) -> None:
+        """Rebuild the account mirrors, the id → slot index and the
+        device's per-slot ledger and flags from the first `count` records
+        of the account log (a checkpoint restore; the log's blocks must all
+        be present). Balances are written separately."""
+        for base, recs in self.account_log.scan_range(0, count):
+            s = slice(base, base + len(recs))
+            self.acc_key["lo"][s] = recs["id_lo"]
+            self.acc_key["hi"][s] = recs["id_hi"]
+            self.acc_user_data_128_lo[s] = recs["user_data_128_lo"]
+            self.acc_user_data_128_hi[s] = recs["user_data_128_hi"]
+            self.acc_user_data_64[s] = recs["user_data_64"]
+            self.acc_user_data_32[s] = recs["user_data_32"]
+            self.acc_ledger[s] = recs["ledger"]
+            self.acc_code[s] = recs["code"]
+            self.acc_flags[s] = recs["flags"]
+            self.acc_timestamp[s] = recs["timestamp"]
+        slots = np.arange(count, dtype=np.int32)
+        self.account_index.insert_batch(self.acc_key[:count], slots.astype(np.uint32))
+        self._register_accounts(
+            slots, self.acc_ledger[:count], self.acc_flags[:count],
+            np.ones(count, dtype=bool),
+        )
 
     def _defer_store(self, recs: np.ndarray, ts=None) -> None:  # tidy: thread=commit
         """Schedule the batch's store work for _finish_commit (inline or
@@ -621,6 +684,7 @@ class StateMachine:
             tracer.gauge("sm.compact.quota", quota)
             stages = (
                 lambda: self.transfer_log.flush_pending(max_blocks),
+                lambda: self.account_log.flush_pending(max_blocks),
                 lambda: self.history.flush_pending(max_blocks),
                 lambda: self.transfer_index.compact_step(quota),
                 lambda: self.account_rows.compact_step(quota),
@@ -919,6 +983,7 @@ class StateMachine:
             self.acc_code[new_slots] = events["code"][ok]
             self.acc_flags[new_slots] = flags[ok]
             self.acc_timestamp[new_slots] = ts[ok]
+            self._log_new_accounts(self.account_count, self.account_count + k)
             self.account_count += k
             self.account_index.insert_batch(keys[ok], new_slots.astype(np.uint32))
             self._register_accounts(s_all, events["ledger"].astype(np.uint32), flags, ok)
@@ -2343,6 +2408,7 @@ class StateMachine:
                 aflags[j] = a.flags
             keys = pack_keys(lo, hi)
             self.acc_key[slots] = keys
+            self._log_new_accounts(self.account_count, self.account_count + k)
             self.account_count += k
             self.account_index.insert_batch(keys, slots.astype(np.uint32))
             self._register_accounts(

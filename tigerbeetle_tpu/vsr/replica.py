@@ -485,12 +485,13 @@ class Replica:
             if st.sync_pending:
                 # Crashed mid block-sync: the trailer's RAM state is valid
                 # but referenced content blocks may still be missing —
-                # resume fetching before any execution (the Bloom rebuild
-                # waits too: it scans log blocks).
+                # resume fetching before any execution (the accounts and
+                # the Bloom filter wait too: they are rebuilt from log
+                # blocks).
                 tracer.count("mark.state_sync_install")
                 resume_block_sync = snapshot.block_checksums(blob)
                 snapshot.install(
-                    self, blob, rebuild_bloom=False,
+                    self, blob, rebuild=False,
                     block_cks_map=resume_block_sync,
                 )
             else:
@@ -498,8 +499,8 @@ class Replica:
                     self._load_snapshot(blob)
                 except GridReadFault:
                     # A checkpoint-referenced block is corrupt on disk
-                    # (latent sector error found at boot — the bloom
-                    # rebuild scans every log block): install the RAM
+                    # (latent sector error found at boot — the rebuild
+                    # from the logs scans every log block): install the RAM
                     # state without the scan and fetch ONLY the bad
                     # blocks via block-level sync. (Blocks written after
                     # the checkpoint are deterministically rewritten by
@@ -514,7 +515,7 @@ class Replica:
                     )
                     resume_block_sync = snapshot.block_checksums(blob)
                     snapshot.install(
-                        self, blob, rebuild_bloom=False,
+                        self, blob, rebuild=False,
                         block_cks_map=resume_block_sync,
                     )
             # The encoded free set covers content blocks only; the
@@ -1645,10 +1646,14 @@ class Replica:
         try:
             with tracer.span("stage.store_async"):
                 store = job.get("store")
-                if store is not None and not job.get("stored"):
-                    recs, ts = store
-                    with tracer.span("sm.ct.store"):
-                        sm._store_new_transfers(recs, ts=ts, add_bloom=False)
+                accounts = job.get("accounts")
+                if not job.get("stored"):
+                    if store is not None:
+                        recs, ts = store
+                        with tracer.span("sm.ct.store"):
+                            sm._store_new_transfers(recs, ts=ts, add_bloom=False)
+                    if accounts is not None:
+                        sm.account_log.append_batch(accounts)
                     job["stored"] = True
                 # flush=False: this job's store was applied above; the
                 # live _deferred_store (if any) is the NEXT op's batch,
@@ -2388,9 +2393,9 @@ class Replica:
             tracer.count("mark.state_sync_install")
             # RAM state + manifests only; the free-set restore inside is
             # overwritten below (install_free governs until the flip), and
-            # the Bloom rebuild waits for the log blocks to arrive.
+            # the accounts and the Bloom filter wait for the log blocks.
             snapshot.install(
-                self, blob, rebuild_bloom=False, block_cks_map=wanted
+                self, blob, rebuild=False, block_cks_map=wanted
             )
         except Exception:
             # Residual failure: every block the old state references is
@@ -2693,7 +2698,7 @@ class Replica:
         if st.sync_pending:
             st.sync_pending = 0
             self.superblock.checkpoint()
-        snapshot.rebuild_transfer_bloom(self.state_machine)
+        snapshot.rebuild_from_logs(self.state_machine)
         tracer.count("mark.block_sync_done")
         tracer.count("recovery.sync_complete")
         log.info(
@@ -3203,6 +3208,7 @@ class Replica:
             self.store_executor.submit({
                 "op": getattr(self, "last_committed_op", 0),
                 "store": sm.take_deferred_store(),
+                "accounts": sm.take_deferred_accounts(),
                 "lc": lc,
             })
             return

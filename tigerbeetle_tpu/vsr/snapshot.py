@@ -10,7 +10,9 @@ never execute code (it previously could: object-dtype arrays forced
 could pass the body checksum).
 
 Sections:
-  accounts   — immutable per-account fields + exact u128 balances (lo/hi u64)
+  accounts   — the count and the exact u128 balances (u32 limbs); each
+               account's immutable fields live in the account log, whose
+               blocks the blob references like the transfer log's
   transfers  — wire-layout TRANSFER_DTYPE rows, commit order
   posted     — pending-transfer fulfillment map (timestamp → u8)
   history    — HISTORY_DTYPE rows (reference AccountHistoryGrooveValue,
@@ -26,11 +28,13 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
+from tigerbeetle_tpu import tracer
+
 U64_MAX = (1 << 64) - 1
 
 # One AccountHistoryGrooveValue row; u128 values as (lo, hi) u64 pairs.
 # (The durable history groove stores exactly this layout on disk.)
-from tigerbeetle_tpu.lsm.groove import HISTORY_DTYPE  # noqa: E402
+from tigerbeetle_tpu.lsm.groove import ACCOUNT_LOG_DTYPE, HISTORY_DTYPE  # noqa: E402
 
 CLIENT_ENTRY_DTYPE = np.dtype(
     [
@@ -116,7 +120,10 @@ def content_trees(sm):
 
 def content_logs(sm):
     """(prefix, DurableLog) for every object log the checkpoint persists."""
-    return (("log", sm.transfer_log), ("hlog", sm.history.log))
+    return (
+        ("log", sm.transfer_log), ("hlog", sm.history.log),
+        ("alog", sm.account_log),
+    )
 
 
 def referenced_blocks(sm, tree_fences) -> np.ndarray:
@@ -158,11 +165,11 @@ def _slot_epochs_array(replica) -> np.ndarray:
 
 def encode(replica) -> bytes:
     """Serialize the replica's replicated state at its current commit
-    point. Transfers stay in the grid; the blob carries the account
-    columns + balances, LSM manifests + fences, the log's block list +
-    tail, the referenced-block checksum list, and the EWAH free set —
-    O(accounts + tables), never O(history). The SAME blob serves local
-    recovery and state sync: a peer installs the RAM state and fetches
+    point. Transfers and the accounts' immutable fields stay in the grid;
+    the blob carries the balances, LSM manifests + fences, each log's
+    block list + tail, the referenced-block checksum list, and the EWAH
+    free set — O(accounts + tables), never O(history). The SAME blob
+    serves local recovery and state sync: a peer installs the RAM state and fetches
     whichever referenced blocks its own grid is missing (block-level
     sync, reference replica.zig:2289,2413). Every section is
     byte-deterministic across replicas (the storage checker compares all
@@ -172,7 +179,8 @@ def encode(replica) -> bytes:
     # A deferred (or async-queued) store must never miss a checkpoint.
     sm.store_barrier()
     count = sm.account_count
-    dp, dpo, cp, cpo = sm._read_balances(np.arange(count, dtype=np.int64))
+    with tracer.span("vsr.checkpoint.encode.read"):
+        dp, dpo, cp, cpo = sm._read_balances(np.arange(count, dtype=np.int64))
 
     client_rows = np.zeros(len(replica.clients), dtype=CLIENT_ENTRY_DTYPE)
     reply_blobs: List[bytes] = []
@@ -186,22 +194,17 @@ def encode(replica) -> bytes:
         reply_blobs.append(raw)
 
     sections = dict(
-        # v7: per-tree storm-request flags (queued-but-unplanned major
-        # compactions; a PLANNED storm persists through the job
-        # descriptor's sentinel level). v6: client_table gains last_op
-        # (front-door LRU eviction order, ISSUE 9). v5:
+        # v8: the eight immutable account columns leave the blob for the
+        # account log (alog_*). v7: per-tree storm-request flags
+        # (queued-but-unplanned major compactions; a PLANNED storm
+        # persists through the job descriptor's sentinel level). v6:
+        # client_table gains last_op (front-door LRU eviction order). v5:
         # config_epoch/slot_epochs (r5), qi query tree, per-tree
         # compaction-job descriptors. No migration path between versions
         # — data files are not carried across builds; the bump is
         # diagnostic.
-        version=np.uint32(7),
+        version=np.uint32(8),
         account_count=np.int64(count),
-        acc_key_hi=sm.acc_key["hi"][:count], acc_key_lo=sm.acc_key["lo"][:count],
-        acc_ud128_lo=sm.acc_user_data_128_lo[:count],
-        acc_ud128_hi=sm.acc_user_data_128_hi[:count],
-        acc_ud64=sm.acc_user_data_64[:count], acc_ud32=sm.acc_user_data_32[:count],
-        acc_ledger=sm.acc_ledger[:count], acc_code=sm.acc_code[:count],
-        acc_flags=sm.acc_flags[:count], acc_ts=sm.acc_timestamp[:count],
         bal_dp=dp, bal_dpo=dpo, bal_cp=cp, bal_cpo=cpo,
         prepare_timestamp=np.uint64(replica.committed_timestamp_max),
         commit_timestamp=np.uint64(sm.commit_timestamp),
@@ -298,13 +301,11 @@ def block_checksums(blob: bytes) -> dict:
 
 
 _TREE_PREFIXES = ("ti", "ai", "qi", "po", "hi")
-_LOG_PREFIXES = ("log", "hlog")
+_LOG_PREFIXES = ("log", "hlog", "alog")
+_BALANCES = ("bal_dp", "bal_dpo", "bal_cp", "bal_cpo")
 
 _LOCAL_REQUIRED = (
-    "account_count", "acc_key_hi", "acc_key_lo",
-    "acc_ud128_lo", "acc_ud128_hi", "acc_ud64", "acc_ud32",
-    "acc_ledger", "acc_code", "acc_flags", "acc_ts",
-    "bal_dp", "bal_dpo", "bal_cp", "bal_cpo",
+    "account_count", *_BALANCES,
     "prepare_timestamp", "commit_timestamp", "config_epoch",
     "slot_epochs", "client_table", "client_replies",
     *(f"{p}_{s}" for p in _TREE_PREFIXES
@@ -326,12 +327,11 @@ def validate(blob: bytes) -> bool:
         count = int(z["account_count"])
         if count < 0:
             return False
-        for k in _LOCAL_REQUIRED[1:11]:
-            if z[k].shape != (count,):
-                return False
-        for k in ("bal_dp", "bal_dpo", "bal_cp", "bal_cpo"):
+        for k in _BALANCES:
             if z[k].shape != (count, 4):
                 return False
+        if z["alog_tail"].dtype != ACCOUNT_LOG_DTYPE or len(z["alog_tail"]) > count:
+            return False
         if z["client_table"].dtype != CLIENT_ENTRY_DTYPE:
             return False
         if int(z["client_table"]["reply_len"].sum()) != len(z["client_replies"]):
@@ -359,28 +359,31 @@ def free_set_bytes(blob: bytes) -> bytes | None:
         return None
 
 
-def rebuild_transfer_bloom(sm) -> None:
-    """Rebuild the transfer-id Bloom pre-filter (RAM-only; no false
-    negatives allowed: every stored id must be re-added) by scanning the
-    restored object log. Requires every log block to be present."""
+def rebuild_from_logs(sm) -> None:
+    """The RAM state a restore derives from the restored object logs: the
+    account mirrors, id index and device registration from the account
+    log, then the transfer-id Bloom pre-filter (no false negatives
+    allowed: every stored id is re-added). Requires every log block to be
+    present, so a block-level sync receiver runs it once they arrived."""
+    sm.restore_accounts(sm.account_count)
     for _base, recs in sm.transfer_log.scan_range(0, sm.transfer_log.count):
         sm.transfer_seen.add(recs["id_lo"], recs["id_hi"])
 
 
-def install(replica, blob: bytes, rebuild_bloom: bool = True,
+def install(replica, blob: bytes, rebuild: bool = True,
             block_cks_map: dict | None = None) -> None:
     """Install a snapshot into a freshly reset replica state machine.
 
     Strictly ``allow_pickle=False``: a malformed blob raises (the caller
     treats that as a failed sync / corrupt checkpoint), it never executes.
 
-    rebuild_bloom=False defers the transfer-id Bloom rebuild (it scans the
-    object log's grid blocks, which a block-level sync receiver does not
-    hold yet) — the caller runs rebuild_bloom() once the blocks arrive.
+    rebuild=False defers rebuild_from_logs (it scans the object logs'
+    grid blocks, which a block-level sync receiver does not hold yet): the
+    accounts are then counted and their balances written, but none is
+    found by id until the caller runs it, once the blocks arrive.
     block_cks_map: pre-parsed block_checksums(blob), when the caller
     already computed it (avoids re-parsing the multi-MB blob).
     """
-    from tigerbeetle_tpu.lsm.store import pack_keys
     from tigerbeetle_tpu.vsr.header import Message
     from tigerbeetle_tpu.vsr.replica import ClientSession
 
@@ -388,21 +391,6 @@ def install(replica, blob: bytes, rebuild_bloom: bool = True,
     sm = replica.state_machine
     count = int(z["account_count"])
     sm.account_count = count
-    keys = pack_keys(z["acc_key_lo"], z["acc_key_hi"])
-    sm.acc_key[:count] = keys
-    sm.acc_user_data_128_lo[:count] = z["acc_ud128_lo"]
-    sm.acc_user_data_128_hi[:count] = z["acc_ud128_hi"]
-    sm.acc_user_data_64[:count] = z["acc_ud64"]
-    sm.acc_user_data_32[:count] = z["acc_ud32"]
-    sm.acc_ledger[:count] = z["acc_ledger"]
-    sm.acc_code[:count] = z["acc_code"]
-    sm.acc_flags[:count] = z["acc_flags"]
-    sm.acc_timestamp[:count] = z["acc_ts"]
-    sm.account_index.insert_batch(keys, np.arange(count, dtype=np.uint32))
-    sm._register_accounts(
-        np.arange(count, dtype=np.int32), z["acc_ledger"], z["acc_flags"],
-        np.ones(count, dtype=bool),
-    )
     sm._write_balances(
         np.arange(count, dtype=np.int32),
         z["bal_dp"], z["bal_dpo"], z["bal_cp"], z["bal_cpo"],
@@ -431,8 +419,13 @@ def install(replica, blob: bytes, rebuild_bloom: bool = True,
             )
     for name, dlog in content_logs(sm):
         dlog.restore(z[f"{name}_blocks"], z[f"{name}_tail"])
-    if rebuild_bloom:
-        rebuild_transfer_bloom(sm)
+    if sm.account_log.count != count:
+        raise ValueError(
+            f"account log holds {sm.account_log.count} accounts, "
+            f"the checkpoint {count}"
+        )
+    if rebuild:
+        rebuild_from_logs(sm)
     sm.prepare_timestamp = int(z["prepare_timestamp"])
     replica.committed_timestamp_max = int(z["prepare_timestamp"])
     sm.commit_timestamp = int(z["commit_timestamp"])
