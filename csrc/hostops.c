@@ -940,3 +940,76 @@ int hostops_post_u128(
     #undef STORE128
     return overflow;
 }
+
+/* The exact kernel's line walk (ops/commit_exact.py build_line_plan).
+ * reads[r]: record r (event r % n, debit side r < n) reads its account
+ * (a limit flag or a balancing flag on that side); seg[r]: its slot's
+ * segment in the batch's sort plan, -1 for none, < nseg. An event that
+ * reads a side is walked; its side is on a line where some record reads
+ * that segment. Its level is one more than the highest level already on
+ * either of its lines, so the events of one level share no line and
+ * every line meets its events in event order. Out: order[0..walked) the
+ * walked events by (level, event); at[e] the event's place there, -1 not
+ * walked; pred[2 * place + side] the place * 2 + side of the walked
+ * posting before it on its line, `none` where it is the first, -1 where
+ * the side is on no line; sizes[l - 1] the events of level l. Returns the
+ * deepest level, -1 where scratch could not be had. */
+int32_t hostops_line_plan(int64_t n, const uint8_t *reads, const int32_t *seg,
+                          int64_t nseg, int32_t none, int32_t *order, int32_t *at,
+                          int32_t *pred, int32_t *sizes) {
+    uint8_t *read_seg = (uint8_t *)calloc((size_t)nseg + 1, 1);
+    int32_t *last_level = (int32_t *)calloc((size_t)nseg + 1, sizeof(int32_t));
+    int32_t *last_rec = (int32_t *)malloc(((size_t)nseg + 1) * sizeof(int32_t));
+    int32_t *level = (int32_t *)calloc((size_t)n + 1, sizeof(int32_t));
+    int32_t *first = (int32_t *)calloc((size_t)n + 2, sizeof(int32_t));
+    int32_t *pred_rec = (int32_t *)malloc(((size_t)2 * n + 1) * sizeof(int32_t));
+    int32_t depth = -1;
+    if (!read_seg || !last_level || !last_rec || !level || !first || !pred_rec) goto done;
+    depth = 0;
+    for (int64_t r = 0; r < 2 * n; r++)
+        if (reads[r] && seg[r] >= 0) read_seg[seg[r]] = 1;
+    for (int64_t g = 0; g < nseg; g++) last_rec[g] = -1;
+    for (int64_t e = 0; e < n; e++) {
+        at[e] = -1;
+        if (!reads[e] && !reads[e + n]) continue;
+        int32_t l = 0;
+        for (int s = 0; s < 2; s++) {
+            int64_t r = e + s * n;
+            int32_t g = seg[r];
+            if (g >= 0 && read_seg[g]) {
+                pred_rec[r] = last_rec[g];  /* both sides read before either moves */
+                if (last_level[g] > l) l = last_level[g];
+            } else {
+                pred_rec[r] = -2;
+            }
+        }
+        l += 1;
+        for (int s = 0; s < 2; s++) {
+            int64_t r = e + s * n;
+            if (pred_rec[r] != -2) {
+                last_level[seg[r]] = l;
+                last_rec[seg[r]] = (int32_t)r;
+            }
+        }
+        level[e] = l;
+        first[l + 1] += 1;
+        if (l > depth) depth = l;
+    }
+    for (int32_t l = 1; l <= depth; l++) {
+        sizes[l - 1] = first[l + 1];
+        first[l + 1] += first[l];
+    }
+    for (int64_t e = 0; e < n; e++)
+        if (level[e]) { at[e] = first[level[e]]; order[first[level[e]]++] = (int32_t)e; }
+    for (int64_t e = 0; e < n; e++) {
+        if (!level[e]) continue;
+        for (int s = 0; s < 2; s++) {
+            int32_t p = pred_rec[e + s * n];
+            pred[2 * (int64_t)at[e] + s] =
+                p == -2 ? -1 : p == -1 ? none : 2 * at[p % n] + (p >= n);
+        }
+    }
+done:
+    free(read_seg); free(last_level); free(last_rec); free(level); free(first); free(pred_rec);
+    return depth;
+}

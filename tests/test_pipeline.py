@@ -802,13 +802,34 @@ class TestExactDispatch:
         _same_ledger(sm, ref, batches)
 
     def test_more_than_one_sweep_is_counted_at_the_finish(self, traced):
+        """The limits line, seven debits deep: shallower than the sweeps'
+        budget, so the line walk is not taken and the sweeps settle it,
+        more than one of them."""
         sm = _exact_sm()
         h = sm.create_transfers_dispatch(_exact_batch("limits", 0), 1000)
         assert _count(traced, "sm.exact.sweeps") == 0  # nothing taken back yet
         _finish(sm, h)
         assert _count(traced, "sm.exact.sweeps") > 1
+        assert _count(traced, "sm.exact.line_steps") == 0
         assert _count(traced, "sm.exact.dispatched_ahead") == 1
         assert _count(traced, "sm.exact.store_deferred") == 1
+
+    def test_the_line_walk_is_counted_at_the_finish(self, traced):
+        """One line of 100 balancing debits, each funded by the one before
+        it (a level each: deeper than the sweeps' budget): one walk of its
+        100 levels settles it, and one sweep confirms it."""
+        sm, ref = _exact_sm(), _exact_sm()
+        line = _transfers(20_000, [(1, 9, 1000, 0)] + [
+            (9 + i % 4, 9 + (i + 1) % 4, 1000, _BALANCING_DEBIT) for i in range(100)
+        ])
+        h = sm.create_transfers_dispatch(line, 1000)
+        assert _count(traced, "sm.exact.line_steps") == 0  # nothing taken back yet
+        out = _finish(sm, h)
+        assert _count(traced, "sm.exact.sweeps") == 1
+        assert _count(traced, "sm.exact.line_steps") == 100
+        assert sm.stats.get("bail_batches", 0) == 0
+        assert out.tobytes() == ref.create_transfers(line, timestamp=1000).tobytes()
+        _same_ledger(sm, ref, [line])
 
     def test_window_of_fast_and_exact_handles_mixed(self):
         sm, ref = _exact_sm(), _exact_sm()
@@ -875,13 +896,14 @@ class TestExactDispatch:
 
     def test_exact_bail_mid_window_rolls_back_and_later_handles_refire(self, traced):
         """The first of three batches is one line of 100 dependent events
-        (each balancing debit funded by the one before it: more than the
-        kernel's 64 sweeps): its kernel bails at the finish, the state
+        (each balancing debit funded by the one before it) behind a linked
+        pair, so the line walk does not take it and the sweeps alone need
+        more than their 64: its kernel bails at the finish, the state
         token goes back to the one it was given, the serial path answers,
         and the two kernels that ran on the revoked token are thrown away
         and refired by `gen`."""
         sm, ref = _exact_sm(), _exact_sm()
-        line = _transfers(20_000, [(1, 9, 1000, 0)] + [
+        line = _transfers(20_000, [(2, 3, 5, _LINKED), (3, 2, 5, 0), (1, 9, 1000, 0)] + [
             (9 + i % 4, 9 + (i + 1) % 4, 1000, _BALANCING_DEBIT) for i in range(100)
         ])
         batches = [line, _exact_batch("limits", 1), _exact_batch("fast", 2)]
@@ -900,6 +922,28 @@ class TestExactDispatch:
         refs = [ref.create_transfers(b, timestamp=1000 + 100 * i) for i, b in enumerate(batches)]
         assert [o.tobytes() for o in outs] == [r.tobytes() for r in refs]
         assert len(out0) == 0  # every link of the line moved the 1000 on
+        _same_ledger(sm, ref, batches)
+
+    def test_no_dispatch_runs_ahead_of_a_handle_a_bail_revoked(self, traced):
+        """The line behind a linked pair bails at the first finish while the
+        next batch is still out on the revoked token: a batch offered then
+        is refused (`behind_bail`), the handle refires at its finish and the
+        refused batch runs after it, byte for byte the single-phase path.
+        Dispatched on top of the refire, it would have run before it."""
+        sm, ref = _exact_sm(), _exact_sm()
+        line = _transfers(20_000, [(2, 3, 5, _LINKED), (3, 2, 5, 0), (1, 9, 1000, 0)] + [
+            (9 + i % 4, 9 + (i + 1) % 4, 1000, _BALANCING_DEBIT) for i in range(100)
+        ])
+        batches = [line, _exact_batch("limits", 1), _exact_batch("limits", 2)]
+        handles = [sm.create_transfers_dispatch(b, 1000 + 100 * i)
+                   for i, b in enumerate(batches[:2])]
+        outs = [_finish(sm, handles[0])]
+        assert sm.stats["bail_batches"] == 1
+        assert sm.create_transfers_dispatch(batches[2], 1200) is None
+        assert _count(traced, "sm.ct.dispatch_refused.behind_bail") == 1
+        outs += [_finish(sm, handles[1]), sm.create_transfers(batches[2], timestamp=1200)]
+        refs = [ref.create_transfers(b, timestamp=1000 + 100 * i) for i, b in enumerate(batches)]
+        assert [o.tobytes() for o in outs] == [r.tobytes() for r in refs]
         _same_ledger(sm, ref, batches)
 
     def test_abandon_all_closes_exact_windows_under_their_own_name(self, traced):

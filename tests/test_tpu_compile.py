@@ -105,13 +105,16 @@ def test_create_transfers_fast(one_chip, a):
 
 
 @TABLES
-@pytest.mark.parametrize("has_pv,has_chains", [
-    (True, True), (False, False), (False, True), (True, False),
-], ids=["pv+chains", "plain", "chains", "pv"])
-def test_create_transfers_exact(one_chip, has_pv, has_chains, a):
+@pytest.mark.parametrize("has_pv,has_chains,walk", [
+    (True, True, False), (False, False, False), (False, True, False), (True, False, False),
+    (False, False, True),
+], ids=["pv+chains", "plain", "chains", "pv", "plain+walk"])
+def test_create_transfers_exact(one_chip, has_pv, has_chains, walk, a):
     """All four corners of the static-flag square the state machine
     compiles (has_pv / has_chains follow the batch's content): settlement
-    batches carry both, TPC-B's chains alone."""
+    batches carry both, TPC-B's chains alone; and the plain batch whose
+    lines are walked (SmallBank's: a `LinePlan`, whose shapes follow the
+    batch bucket alone)."""
     i32 = lambda *shape: np.zeros(shape, np.int32)
     pending = commit_exact.PendingInfo(
         found=np.zeros(N, bool), amount=np.zeros((N, 4), np.uint32),
@@ -124,10 +127,14 @@ def test_create_transfers_exact(one_chip, has_pv, has_chains, a):
         sub_head_pos=i32(2 * N), f_perm=i32(N), f_inv_perm=i32(N),
         f_head_pos=i32(N), f_sub_head_pos=i32(N),
     )
+    lines = commit_exact.LinePlan(
+        order=i32(N + commit_exact.LINE_WIDTH), start=i32(N), count=i32(N),
+        pred=i32(N + commit_exact.LINE_WIDTH, 2), at=i32(N),
+    ) if walk else None
     compiled = _compile(
         commit_exact.create_transfers_exact, one_chip,
         _ledger_state(a), _transfer_batch(N), np.zeros(N, np.uint32),
-        pending, i32(N), plan,
+        pending, i32(N), plan, lines,
         has_pv=has_pv, has_chains=has_chains,
     )
     # The v5e program carries the sweep count out beside the bail flag:

@@ -1301,7 +1301,8 @@ class StateMachine:
     def _ct_refuse(why: str) -> None:
         """A batch the dispatch-ahead turns away: it runs whole at its own
         turn, behind a settled window. Counted where the decision is
-        taken (pv, history, overlap, dup, stored_id, window_full)."""
+        taken (pv, history, overlap, dup, stored_id, window_full,
+        behind_bail)."""
         tracer.count(f"sm.ct.dispatch_refused.{why}")
         return None
 
@@ -1332,6 +1333,11 @@ class StateMachine:
             # first (a pipeline stall, never corruption). Also keeps the
             # scratch ring's slot-reuse distance ≥ the in-flight count.
             return self._ct_refuse("window_full")
+        if any(h["gen"] != self._state_gen for h in self._ct_pending):
+            # A bail rolled the state token back under the window: the
+            # handles still out refire at their finish, on the state as it
+            # stands then, so nothing may run on the device ahead of them.
+            return self._ct_refuse("behind_bail")
         events = np.atleast_1d(events)
         n = len(events)
         if n == 0:
@@ -1959,20 +1965,38 @@ class StateMachine:
 
             # Host-side sort plan: a ~100 µs numpy lexsort here replaces ~ms of
             # device lax.sort inside the kernel (SortPlan docstring).
+            has_chains = bool(np.any(linked))
             with tracer.span("sm.ct.plan"):
+                flags_p, dr_p, cr_p = (
+                    np.asarray(b.flags), np.asarray(b.dr_slot), np.asarray(b.cr_slot)
+                )
                 plan = commit_exact.build_sort_plan(
-                    np.asarray(b.flags), np.asarray(b.dr_slot), np.asarray(b.cr_slot),
+                    flags_p, dr_p, cr_p,
                     pinfo.dr_slot, pinfo.cr_slot, chain_id_p, pinfo.group,
                     int(self.state.ledger.shape[0]),
                 )
+                # The events that read a limited or balancing account are
+                # walked level by level before the sweeps where the walk is
+                # as deep as the sweeps' budget, however long their lines
+                # (a plain batch only: no post/void, no chain).
+                lines = None
+                if not (has_pv or has_chains):
+                    lim = lambda s, f: (s >= 0) & ((self.acc_flags[s] & int(f)) != 0)  # noqa: E731
+                    lines = commit_exact.build_line_plan(
+                        plan, flags_p, dr_p, cr_p,
+                        lim(dr_p, AccountFlags.DEBITS_MUST_NOT_EXCEED_CREDITS),
+                        lim(cr_p, AccountFlags.CREDITS_MUST_NOT_EXCEED_DEBITS),
+                    )
             x = {
                 "events": events, "ts": ts, "n": n, "is_pv": is_pv,
                 "defer": defer,
                 "dr_hist": dr_hist, "cr_hist": cr_hist,
                 "dr_slots": dr_slots, "cr_slots": cr_slots,
                 "pending_recs": pending_recs, "p_rec_idx": p_rec_idx,
-                "args": (b, host_code_p, pinfo, chain_id_p, plan),
-                "has_pv": has_pv, "has_chains": bool(np.any(linked)),
+                "args": (b, host_code_p, pinfo, chain_id_p, plan, lines),
+                "has_pv": has_pv, "has_chains": has_chains,
+                # The steps of one walk of the lines (0: no walk).
+                "line": 0 if lines is None else int(np.count_nonzero(lines.count)),
             }
             if tracer.enabled():
                 # What the batch brings the kernel: chains of two or more
@@ -1989,7 +2013,7 @@ class StateMachine:
             devicestats.note_call(
                 _EXACT_KERNEL,
                 (self.state, b, host_code_p, pinfo, chain_id_p, plan),
-                kwargs=dict(has_pv=has_pv, has_chains=x["has_chains"]),
+                kwargs=dict(has_pv=has_pv, has_chains=has_chains, lines=lines),
                 bucket=n_pad,
             )
         return x
@@ -1999,17 +2023,18 @@ class StateMachine:
         (this half stays OUTSIDE the jaxlint sync seam). The kernel's
         outputs ride in `x` to _exact_finish; the new token is returned
         for the caller to chain."""
-        b, host_code_p, pinfo, chain_id_p, plan = x.pop("args")
+        b, host_code_p, pinfo, chain_id_p, plan, lines = x.pop("args")
         with tracer.span("sm.ct.dispatch"):
             x["t_disp"] = tracer.device_dispatch(
                 _EXACT_KERNEL,
                 h2d_bytes=_staged_nbytes(b, host_code_p)
-                + _staged_nbytes(pinfo, chain_id_p) + _staged_nbytes(plan, 0),
+                + _staged_nbytes(pinfo, chain_id_p) + _staged_nbytes(plan, 0)
+                + _staged_nbytes(lines or (), 0),
             )
             (new_state, x["codes"], x["amounts"], x["dr_after"], x["cr_after"],
              x["bail"], x["sweeps"]) = self._ops.create_transfers_exact(
-                self.state, b, host_code_p, pinfo, chain_id_p, plan,
-                # tidy: allow=retrace-static-arg — deliberate bounded specialization: two bools → at most 4 kernel variants, each skipping a whole sweep phase
+                self.state, b, host_code_p, pinfo, chain_id_p, plan, lines=lines,
+                # tidy: allow=retrace-static-arg — deliberate bounded specialization: two bools → at most 4 kernel variants, each skipping a whole sweep phase; the line walk (plain batches only) adds one more, its shapes following the batch bucket alone
                 has_pv=x["has_pv"], has_chains=x["has_chains"],
             )
         return new_state
@@ -2039,7 +2064,11 @@ class StateMachine:
                     # taken at anyway.
                     chain_heads = x["chain_heads"]
                     slots_touched, slot_postings_max = x["slot_counts"]
-                    tracer.count("sm.exact.sweeps", int(x["sweeps"]))
+                    sweeps = int(x["sweeps"])
+                    tracer.count("sm.exact.sweeps", sweeps)
+                    # One walk before the first sweep and one after each sweep
+                    # that was not yet stable: as many walks as sweeps.
+                    tracer.count("sm.exact.line_steps", x["line"] * sweeps)
                     tracer.count("sm.exact.chains", int(np.count_nonzero(chain_heads)))
                     tracer.count(
                         "sm.exact.chains_rolled_back",

@@ -222,6 +222,15 @@ def hostops() -> Optional[ctypes.CDLL]:
             ctypes.c_int64, u32p, ctypes.c_int64, u32p, u8p,
         ]
         lib.hostops_gallop_mark_u32.restype = ctypes.c_int64
+    # The exact kernel's line walk (same stale-.so guard: older libraries
+    # keep the Python loop, commit_exact._line_walk).
+    if hasattr(lib, "hostops_line_plan"):
+        i32p = ctypes.POINTER(ctypes.c_int32)
+        lib.hostops_line_plan.argtypes = [
+            ctypes.c_int64, u8p, i32p, ctypes.c_int64, ctypes.c_int32,
+            i32p, i32p, i32p, i32p,
+        ]
+        lib.hostops_line_plan.restype = ctypes.c_int32
     # The C staging ladder hardcodes the wire-contract result codes; refuse
     # the shim (fall back to numpy) if the enums ever drift.
     from tigerbeetle_tpu.results import CreateTransferResult as _TR

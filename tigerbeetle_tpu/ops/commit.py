@@ -373,7 +373,8 @@ def read_balances(state: LedgerState, slots: jnp.ndarray):
 
 
 def create_transfers_exact(
-    state, b, host_code, pending, chain_id, plan=None, has_pv=True, has_chains=True
+    state, b, host_code, pending, chain_id, plan=None, has_pv=True, has_chains=True,
+    lines=None,
 ):
     """Facade re-export so every ops backend (this module, ShardedOps)
     exposes the same surface and the dispatcher never falls back silently.
@@ -382,5 +383,5 @@ def create_transfers_exact(
 
     return commit_exact.create_transfers_exact(
         state, b, host_code, pending, chain_id, plan,
-        has_pv=has_pv, has_chains=has_chains,
+        has_pv=has_pv, has_chains=has_chains, lines=lines,
     )

@@ -199,6 +199,135 @@ def plan_slot_segments(plan: SortPlan, posted: int):
     return len(heads), int(np.diff(heads, append=posted).max())
 
 
+class LinePlan(NamedTuple):
+    """A walk over the batch's lines: the events whose outcome reads a
+    limited or balancing account (a LINE: that account's postings in event
+    order), settled one LEVEL after another.
+
+    An event's level is one more than the highest level already on either
+    of its lines, so the events of a level share no line and every line
+    meets its events in event order. One walk settles every such event
+    against the exact balance its lines hold before it, whatever the
+    lines' length: the serial order, in as many steps as the deepest
+    chain of events from line to line, each step up to LINE_WIDTH events.
+    The sweeps then only confirm it."""
+
+    order: jnp.ndarray  # (n + LINE_WIDTH,) i32 — walked events by (level, event); 0 behind them
+    start: jnp.ndarray  # (n,) i32 — each step's first place in `order`
+    count: jnp.ndarray  # (n,) i32 — each step's events (<= LINE_WIDTH); 0 past the last
+    # (n + LINE_WIDTH, 2) i32 — per place in `order` and side (debit,
+    # credit): the place * 2 + side of the walked event before it on that
+    # line; 2 * (n + LINE_WIDTH) where none is; -1 where the side is on no line.
+    pred: jnp.ndarray
+    at: jnp.ndarray  # (n,) i32 — each event's place in `order`; -1 not walked
+
+
+# The events a walk step settles at most (the levels of a batch with many
+# short lines are wider: they take as many steps as they need).
+LINE_WIDTH = 512
+
+
+def _line_walk(reads, seg, nseg: int, none: int):
+    """The walk's order, places, predecessors and level sizes (C's
+    `hostops_line_plan`, which says what each is), from each record's
+    `reads` flag and slot segment `seg` (-1 none). Returns (depth, order,
+    at, pred, sizes); the loop below is the same walk, for a host without
+    the shim."""
+    import ctypes
+
+    import numpy as np
+
+    from tigerbeetle_tpu import native
+
+    n = len(reads) // 2
+    order = np.zeros(n, np.int32)
+    at = np.full(n, -1, np.int32)
+    pred = np.full(none, -1, np.int32)
+    sizes = np.zeros(n, np.int32)
+    lib = native.hostops()
+    if lib is not None and hasattr(lib, "hostops_line_plan"):
+        reads = np.ascontiguousarray(reads, np.uint8)
+        seg = np.ascontiguousarray(seg, np.int32)
+        p = lambda x, t=ctypes.c_int32: x.ctypes.data_as(ctypes.POINTER(t))  # noqa: E731
+        depth = lib.hostops_line_plan(n, p(reads, ctypes.c_uint8), p(seg), nseg, none,
+                                      p(order), p(at), p(pred), p(sizes))
+        if depth < 0:
+            raise MemoryError("hostops_line_plan: no scratch")
+        return depth, order, at, pred, sizes
+    reads, seg = reads.tolist(), seg.tolist()
+    read_seg = {g for r, g in zip(reads, seg) if r and g >= 0}
+    last_level, last_rec = {}, {}
+    level, pred_rec = [0] * n, [-2] * (2 * n)
+    for e in range(n):
+        if not (reads[e] or reads[e + n]):
+            continue
+        on = [r for r in (e, e + n) if seg[r] in read_seg]
+        for r in on:
+            pred_rec[r] = last_rec.get(seg[r], -1)  # both sides read before either moves
+        level[e] = 1 + max([last_level.get(seg[r], 0) for r in on], default=0)
+        for r in on:
+            last_level[seg[r]], last_rec[seg[r]] = level[e], r
+    walked = np.flatnonzero(level)
+    by_level = walked[np.argsort(np.asarray(level)[walked], kind="stable")]
+    order[: len(by_level)] = by_level
+    at[by_level] = np.arange(len(by_level), dtype=np.int32)
+    for e in by_level.tolist():
+        for side in (0, 1):
+            q = pred_rec[e + side * n]
+            pred[2 * at[e] + side] = (
+                -1 if q == -2 else none if q == -1 else 2 * at[q % n] + (q >= n)
+            )
+    depth = max(level)
+    sizes[:depth] = np.bincount(level, minlength=depth + 1)[1:]
+    return depth, order, at, pred, sizes
+
+
+def build_line_plan(plan: SortPlan, flags, dr_slot, cr_slot, dr_limit, cr_limit):
+    """Host-side walk of a plain batch (no post/void, no chain): or None
+    where the sweeps alone are sure to settle the batch, and it keeps the
+    variant without the walk. That is where no event reads a limited or
+    balancing account, and where the walk is shallower than MAX_SWEEPS:
+    a sweep settles at least the next level (it reads the levels below as
+    the sweep before settled them), so the sweeps need at most one more
+    than the walk's levels, the last to see them stable.
+
+    flags, dr_slot, cr_slot: the padded batch's; dr_limit / cr_limit: (n,)
+    bool, the limit flag of each event's debit / credit account. An event
+    is walked where it reads a side (that side's limit flag, or its
+    balancing flag); a line is the slot of a side some event reads: a
+    segment of the plan's (slot, event) order."""
+    import numpy as np
+
+    n = len(flags)
+    reads = np.concatenate([
+        (dr_slot >= 0) & (((flags & F_BAL_DR) != 0) | dr_limit),
+        (cr_slot >= 0) & (((flags & F_BAL_CR) != 0) | cr_limit),
+    ])
+    if not reads.any():
+        return None
+    # A record's line is named by the head of its slot's segment; the
+    # records without a slot share the last segment, which none reads.
+    seg = np.asarray(plan.head_pos)[np.asarray(plan.inv_perm)]
+    width = min(LINE_WIDTH, n)
+    none = 2 * (n + width)
+    depth, order, at, pred, sizes = _line_walk(reads, seg, 2 * n, none)
+    if depth < MAX_SWEEPS:
+        return None
+    # Steps: each level in pieces of at most `width` events.
+    size = sizes[:depth].astype(np.int64)
+    pieces = -(-size // width)
+    first = np.cumsum(size) - size
+    k = np.arange(int(pieces.sum())) - np.repeat(np.cumsum(pieces) - pieces, pieces)
+    start = np.zeros(n, np.int32)
+    count = np.zeros(n, np.int32)
+    start[: len(k)] = np.repeat(first, pieces) + k * width
+    count[: len(k)] = np.minimum(np.repeat(size, pieces) - k * width, width)
+    return LinePlan(
+        np.concatenate([order, np.zeros(width, np.int32)]), start, count,
+        pred.reshape(n + width, 2), at,
+    )
+
+
 def _static_ladder(state: LedgerState, b: TransferBatch, is_pv):
     """Order-independent rungs for REGULAR (non-post/void) events
     (reference ladder up to the exists check), with the balancing
@@ -400,6 +529,66 @@ def _add3_wide(a, b, c):
     return s2
 
 
+def _balance_rungs(code, amt, dr: Observed, cr: Observed, bal_dr, bal_cr, pend,
+                   ts_over, dr_limit, cr_limit):
+    """The regular ladder's rungs that read balances, in reference order,
+    after `code` (the rungs before them): the balancing clamps
+    (state_machine.zig:1286-1306), the overflow rungs (:1308-1324) and the
+    limit flags (tigerbeetle.zig:31-39). dr / cr are the balances the
+    event's two accounts hold before it; every argument has the same
+    leading shape, whatever it is. Returns (code, amount after the
+    clamps)."""
+    zero = jnp.zeros_like(amt)
+    # Balancing clamps: amount is capped at what the account can absorb
+    # without breaching its net balance.
+    dr_bal = _add3_wide(dr.debits_pending, dr.debits_posted, zero)
+    avail_d5, under_d = u128.sub(u128.widen(dr.credits_posted, 5), dr_bal)
+    avail_d = u128.select(under_d, zero, avail_d5[..., :4])
+    amt = u128.select(bal_dr, u128.min_(amt, avail_d), amt)
+    code = _ladder(code, bal_dr & u128.is_zero(amt), TR.EXCEEDS_CREDITS)
+
+    cr_bal = _add3_wide(cr.credits_pending, cr.credits_posted, zero)
+    avail_c5, under_c = u128.sub(u128.widen(cr.debits_posted, 5), cr_bal)
+    avail_c = u128.select(under_c, zero, avail_c5[..., :4])
+    amt2 = u128.select(bal_cr, u128.min_(amt, avail_c), amt)
+    code = _ladder(code, bal_cr & u128.is_zero(amt2) & ~u128.is_zero(amt),
+                   TR.EXCEEDS_DEBITS)
+    amt = amt2
+
+    code = _ladder(
+        code, pend & u128.sum_overflows(amt, dr.debits_pending),
+        TR.OVERFLOWS_DEBITS_PENDING,
+    )
+    code = _ladder(
+        code, pend & u128.sum_overflows(amt, cr.credits_pending),
+        TR.OVERFLOWS_CREDITS_PENDING,
+    )
+    code = _ladder(
+        code, u128.sum_overflows(amt, dr.debits_posted), TR.OVERFLOWS_DEBITS_POSTED
+    )
+    code = _ladder(
+        code, u128.sum_overflows(amt, cr.credits_posted), TR.OVERFLOWS_CREDITS_POSTED
+    )
+    u128_top = u128.widen(jnp.full_like(amt, 0xFFFFFFFF), 5)
+    over_d = u128.gt(_add3_wide(dr.debits_pending, dr.debits_posted, amt), u128_top)
+    code = _ladder(code, over_d, TR.OVERFLOWS_DEBITS)
+    over_c = u128.gt(_add3_wide(cr.credits_pending, cr.credits_posted, amt), u128_top)
+    code = _ladder(code, over_c, TR.OVERFLOWS_CREDITS)
+    code = _ladder(code, ts_over, TR.OVERFLOWS_TIMEOUT)
+
+    exceed_d = dr_limit & u128.gt(
+        _add3_wide(dr.debits_pending, dr.debits_posted, amt),
+        u128.widen(dr.credits_posted, 5),
+    )
+    code = _ladder(code, exceed_d, TR.EXCEEDS_CREDITS)
+    exceed_c = cr_limit & u128.gt(
+        _add3_wide(cr.credits_pending, cr.credits_posted, amt),
+        u128.widen(cr.debits_posted, 5),
+    )
+    code = _ladder(code, exceed_c, TR.EXCEEDS_DEBITS)
+    return code, amt
+
+
 def create_transfers_exact_impl(
     state: LedgerState,
     b: TransferBatch,
@@ -407,6 +596,7 @@ def create_transfers_exact_impl(
     pending: PendingInfo,
     chain_id: jnp.ndarray,
     plan: SortPlan | None = None,
+    lines: LinePlan | None = None,
     max_sweeps: int = MAX_SWEEPS,
     has_pv: bool = True,
     has_chains: bool = True,
@@ -728,65 +918,96 @@ def create_transfers_exact_impl(
         pv_dyn = _ladder(pv_dyn, is_pv & pending.found & ts_expired, TR.PENDING_TRANSFER_EXPIRED)
 
         # --- regular dynamic rungs --------------------------------------
-        code = reg_code
-
-        # Balancing clamps (state_machine.zig:1286-1306): amount is capped at
-        # what the account can absorb without breaching its net balance.
-        dr_bal = _add3_wide(dr.debits_pending, dr.debits_posted, jnp.zeros_like(amt))
-        avail_d5, under_d = u128.sub(u128.widen(dr.credits_posted, 5), dr_bal)
-        avail_d = u128.select(under_d, jnp.zeros((n, 4), dtype=U32), avail_d5[..., :4])
-        amt = u128.select(bal_dr, u128.min_(amt, avail_d), amt)
-        code = _ladder(code, bal_dr & u128.is_zero(amt), TR.EXCEEDS_CREDITS)
-
-        cr_bal = _add3_wide(cr.credits_pending, cr.credits_posted, jnp.zeros_like(amt))
-        avail_c5, under_c = u128.sub(u128.widen(cr.debits_posted, 5), cr_bal)
-        avail_c = u128.select(under_c, jnp.zeros((n, 4), dtype=U32), avail_c5[..., :4])
-        amt2 = u128.select(bal_cr, u128.min_(amt, avail_c), amt)
-        code = _ladder(code, bal_cr & u128.is_zero(amt2) & ~u128.is_zero(amt),
-                       TR.EXCEEDS_DEBITS)
-        amt = amt2
-
-        # Overflow rungs (state_machine.zig:1308-1324), in reference order.
-        code = _ladder(
-            code, pend & u128.sum_overflows(amt, dr.debits_pending),
-            TR.OVERFLOWS_DEBITS_PENDING,
+        code, amt = _balance_rungs(
+            reg_code, amt, dr, cr, bal_dr, bal_cr, pend, ts_over, dr_limit, cr_limit
         )
-        code = _ladder(
-            code, pend & u128.sum_overflows(amt, cr.credits_pending),
-            TR.OVERFLOWS_CREDITS_PENDING,
-        )
-        code = _ladder(
-            code, u128.sum_overflows(amt, dr.debits_posted), TR.OVERFLOWS_DEBITS_POSTED
-        )
-        code = _ladder(
-            code, u128.sum_overflows(amt, cr.credits_posted), TR.OVERFLOWS_CREDITS_POSTED
-        )
-        u128_top = u128.widen(jnp.broadcast_to(jnp.array(
-            [0xFFFFFFFF] * 4, dtype=U32), (n, 4)), 5)
-        over_d = u128.gt(_add3_wide(dr.debits_pending, dr.debits_posted, amt), u128_top)
-        code = _ladder(code, over_d, TR.OVERFLOWS_DEBITS)
-        over_c = u128.gt(_add3_wide(cr.credits_pending, cr.credits_posted, amt), u128_top)
-        code = _ladder(code, over_c, TR.OVERFLOWS_CREDITS)
-        code = _ladder(code, ts_over, TR.OVERFLOWS_TIMEOUT)
-
-        # Limit flags (tigerbeetle.zig:31-39).
-        exceed_d = dr_limit & u128.gt(
-            _add3_wide(dr.debits_pending, dr.debits_posted, amt),
-            u128.widen(dr.credits_posted, 5),
-        )
-        code = _ladder(code, exceed_d, TR.EXCEEDS_CREDITS)
-        exceed_c = cr_limit & u128.gt(
-            _add3_wide(cr.credits_pending, cr.credits_posted, amt),
-            u128.widen(cr.debits_posted, 5),
-        )
-        code = _ladder(code, exceed_c, TR.EXCEEDS_DEBITS)
-
         code = jnp.where(is_pv, pv_dyn, code)
         amt = u128.select(is_pv, resolved_pv, amt)
         return code, amt
 
     def masked(ok, amount):
         return u128.select(ok, amount, jnp.zeros_like(amount))
+
+    if lines is not None:
+        # The walk settles regular events against running balances of
+        # adds alone: no post/void (pending removals) and no chain (whose
+        # visibility depends on the observer) on this variant.
+        assert not (has_pv or has_chains), "the line walk takes plain batches"
+        width = lines.order.shape[0] - n
+        walked = lines.at >= 0
+        o = lines.order
+        on_line = lines.pred >= 0  # (n + width, 2)
+        # Where a walked side reads its line's moves so far: the place of
+        # the walked event before it, or the zero row behind the places
+        # (first on its line, or on no line).
+        none = 2 * (n + width)
+        reads_at = jnp.where(on_line, lines.pred, none)
+        # What each walked event brings, in walk order.
+        walk_static = (
+            reg_code[o], amount0[o], bal_dr[o], bal_cr[o], pend[o], ts_over[o],
+            dr_limit[o], cr_limit[o], reads_at,
+        )
+
+        def settle_lines(ok, amount, obs):
+            """One walk: every walked event judged, level by level, against
+            the balances its lines hold before it (the pre-batch row, the
+            events no walk settles as (ok, amount) say, the walked ones as
+            this walk settled them) and, on a side that is no line, `obs`.
+            Returns (ok, amount) with the walked events' outcomes replaced
+            by the walk's."""
+            # The balance each record sees from the events not walked.
+            fixed = ok & ~walked
+            still, _ = observe(fixed, fixed, masked(fixed, amount))
+            seen = jnp.stack([
+                jnp.stack([u128.select(on_line[:, s], f_[o + s * n], g_[o + s * n])
+                           for f_, g_ in zip(still, obs)], axis=1)
+                for s in (0, 1)
+            ], axis=1)  # (n + width, side, field, limb)
+            # Each walked event's moves on its two lines so far, by place
+            # and side (a step writes its own rows; a level's rows are
+            # written before any later level reads them), and a zero row.
+            moved = jnp.zeros((none + 1, 4, 4), dtype=U32)
+            out = jnp.zeros((n + width, 5), dtype=U32)  # ok, amount
+
+            def step_once(t, carry):
+                moved, out = carry
+                at, many = lines.start[t], lines.count[t]
+                take = lambda a: jax.lax.dynamic_slice_in_dim(a, at, width, 0)  # noqa: E731
+                (code0, amt0, bdr, bcr, pnd, tso, dlim, clim, src) = map(take, walk_static)
+                before = moved[src.reshape(-1)].reshape(width, 2, 4, 4)
+                sides = take(seen)
+                dr, cr = (
+                    Observed(*[u128.add(sides[:, s, i], before[:, s, i])[0] for i in range(4)])
+                    for s in (0, 1)
+                )
+                code, amt = _balance_rungs(
+                    code0, amt0, dr, cr, bdr, bcr, pnd, tso, dlim, clim
+                )
+                ok_now = (jnp.arange(width, dtype=I32) < many) & (code == 0)
+                move = masked(ok_now, amt)
+                zero = jnp.zeros_like(move)
+                # The debit side moves debits_(pending|posted), the credit
+                # side credits_(pending|posted).
+                held, posted = u128.select(pnd, move, zero), u128.select(pnd, zero, move)
+                adds = jnp.stack([
+                    jnp.stack([held, posted, zero, zero], axis=1),
+                    jnp.stack([zero, zero, held, posted], axis=1),
+                ], axis=1)
+                after = u128.add(before, adds)[0].reshape(2 * width, 4, 4)
+                # Lanes past `many` write rows of later steps, which those
+                # steps write again before anything reads them.
+                moved = jax.lax.dynamic_update_slice_in_dim(moved, after, 2 * at, 0)
+                out = jax.lax.dynamic_update_slice_in_dim(
+                    out, jnp.concatenate([ok_now.astype(U32)[:, None], amt], axis=1), at, 0
+                )
+                return moved, out
+
+            steps = jnp.sum(lines.count > 0, dtype=I32)
+            _, out = jax.lax.fori_loop(0, steps, step_once, (moved, out))
+            place = jnp.maximum(lines.at, 0)
+            new_ok = jnp.where(walked, out[place, 0] != 0, ok)
+            new_amt = u128.select(walked, out[place, 1:], amount)
+            return new_ok, masked(new_ok, new_amt)
 
     false_n = jnp.zeros((n,), dtype=bool)
 
@@ -808,11 +1029,21 @@ def create_transfers_exact_impl(
         ok, amount, it, _, _, _, _ = carry
         code, amt, under, _, obs = step(ok, amount)
         new_ok = code == 0
-        stable = jnp.all(new_ok == ok) & jnp.all(masked(new_ok, amt) == masked(ok, amount))
+        new_amount = masked(new_ok, amt)
+        stable = jnp.all(new_ok == ok) & jnp.all(new_amount == masked(ok, amount))
+        if lines is not None:
+            # An unstable sweep's outcomes are walked along the lines before
+            # the next: the first event (in event order) the sweep got
+            # right stays right, so the fixed point is reached as surely,
+            # and the walk is only a better speculation.
+            with jax.named_scope("line"):
+                new_ok, new_amount = jax.lax.cond(
+                    stable, lambda o, a, _: (o, a), settle_lines, new_ok, new_amount, obs
+                )
         # Carry the step's outputs out of the loop: at the stable fixed
         # point they ARE the consistent final evaluation (new_ok == ok), so
         # no post-loop re-evaluation is needed.
-        return new_ok, masked(new_ok, amt), it + 1, stable, code, obs, under
+        return new_ok, new_amount, it + 1, stable, code, obs, under
 
     # Seed speculation with a free "sweep 0": evaluate the dynamic ladder
     # against the PRE-batch balances (all in-batch deltas zero — `base` IS
@@ -826,9 +1057,13 @@ def create_transfers_exact_impl(
     with jax.named_scope("balance_check"):
         seed_code, seed_amt = evaluate(base, false_n, false_n)
     init_ok = seed_code == 0
+    init_amount = masked(init_ok, seed_amt)
+    if lines is not None:
+        with jax.named_scope("line"):
+            init_ok, init_amount = settle_lines(init_ok, init_amount, base)
     zero_obs = Observed(*([jnp.zeros((2 * n, 4), dtype=U32)] * 4))
     init = (
-        init_ok, masked(init_ok, seed_amt), jnp.int32(0), jnp.array(False),
+        init_ok, init_amount, jnp.int32(0), jnp.array(False),
         seed_code, zero_obs, jnp.array(False),
     )
     with jax.named_scope("sweep"):

@@ -70,10 +70,11 @@ class ShardedOps:
 
     def create_transfers_exact(
         self, state, b, host_code, pending, chain_id, plan=None,
-        has_pv=True, has_chains=True,
+        has_pv=True, has_chains=True, lines=None,
     ):
-        # has_pv/has_chains are single-chip trace-skip optimizations; the
-        # sharded kernels are built once with the general (True) trace.
+        # has_pv/has_chains and the line walk are single-chip
+        # specializations; the sharded kernels are built once with the
+        # general (True) trace and settle long lines by the sweeps alone.
         if plan is None:
             return self._exact(state, b, host_code, pending, chain_id)
         return self._exact_plan(state, b, host_code, pending, chain_id, plan)
