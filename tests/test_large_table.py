@@ -7,15 +7,17 @@ slots and 64-event batches whose slots come from the top of the table
 too, a hot cash slot among them; `create_accounts` over several batches
 to the very last slot, and one more; a checkpoint of more accounts than a
 batch registers, restored after a crash with every account byte-exact (its
-immutable half from the account log, its balances from the blob), the
-spans and the counter the checkpoint records, and a blob that grows by the
-balances alone.
+immutable half from the account log, its balances from the balance log),
+the spans and the counters the checkpoint records, and a checkpoint that
+writes the balances of the touched accounts alone, to the balance log and
+not to the blob.
 """
 
 import dataclasses
 import io
 import json
 import os
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -25,6 +27,7 @@ from tigerbeetle_tpu.constants import PRODUCTION, PRODUCTION_16M, TEST_MIN
 from tigerbeetle_tpu.models import oracle as om
 from tigerbeetle_tpu.models.state_machine import StateMachine
 from tigerbeetle_tpu.testing.cluster import Cluster, parse_results
+from tigerbeetle_tpu.vsr import snapshot
 from tigerbeetle_tpu.vsr.header import Operation
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -53,8 +56,9 @@ def test_the_preset_is_production_with_a_larger_table_and_grid():
 
 def test_the_preset_holds_the_deployment_it_was_made_for():
     """Slots for every account; a grid for two live checkpoint trailers
-    (64 B of balances an account and the manifests) and the account log
-    (64 B an account, once) beside the store's content of a run of 1,900
+    (the manifests), the account log (64 B an account, once) and the
+    balance log at its largest (three copies of the table, 72 B a row, while
+    a checkpoint rewrites it) beside the store's content of a run of 1,900
     full batches, under 85% of it."""
     config, preset = deployment(), PRODUCTION_16M
     assert config["start"]["config"] == preset.name
@@ -63,12 +67,14 @@ def test_the_preset_holds_the_deployment_it_was_made_for():
         config["accounts_per_branch"] + config["tellers_per_branch"] + 2)
     assert config["transfers_max"] == preset.transfers_max
     payload = preset.lsm_block_size - 64
-    trailer = -(-(config["accounts"] * 64 + (8 << 20)) // payload) + 1
+    trailer = -(-(8 << 20) // payload) + 1
     account_log = -(-config["accounts"] // (payload // 64))
+    balance_log = 3 * -(-config["accounts"] // (payload // 72))
     # PRODUCTION's grid at its fullest (85% of 2^15 blocks, PERF.md section 4) held two
     # trailers of 515 blocks (128 B an account then): the rest was content.
     content = int(0.85 * PRODUCTION.grid_block_count) - 2 * 515
-    assert 2 * trailer + account_log + content <= 0.85 * preset.grid_block_count
+    assert (2 * trailer + account_log + balance_log + content
+            <= 0.85 * preset.grid_block_count)
     # one trailer's chunks are listed in ONE index block (replica._trailer_write asserts it)
     assert trailer - 1 <= (payload - 32) // 4
 
@@ -205,10 +211,11 @@ def test_a_checkpoint_of_many_batches_of_accounts_is_restored_to_the_byte(store_
     checkpoint's own op (its beat leaves its full account-log block
     pending, so the checkpoint flushes it), and a seventh after the
     checkpoint; crash, restart from the trailer and the WAL, and every
-    account's 128 bytes read as they did. The blob carries the balances,
-    the account log's blocks and its tail, and none of the immutable
+    account's 128 bytes read as they did. The blob carries the account
+    log's blocks and its tail, the balance log's (one row for each account
+    a transfer touched, as the table holds it), and none of the accounts'
     columns. The checkpoint left its span, its four leaves and its blob's
-    length on the tracer."""
+    length and its count of balance rows on the tracer."""
     was = tracer.enabled()
     tracer.enable()
     tracer.reset()
@@ -244,11 +251,19 @@ def test_a_checkpoint_of_many_batches_of_accounts_is_restored_to_the_byte(store_
         rpb = sm.account_log.records_per_block
         blob = replica._trailer_read(replica.superblock.state.trailer_block)
         with np.load(io.BytesIO(blob)) as z:
-            assert int(z["version"]) == 8 and int(z["account_count"]) == count
-            assert not [k for k in z.files if k.startswith("acc_")]
+            assert int(z["version"]) == 9 and int(z["account_count"]) == count
+            assert not [k for k in z.files if k.startswith(("acc_", "bal_"))]
             assert len(z["alog_blocks"]) == count // rpb >= 2
             assert len(z["alog_tail"]) == count % rpb > 0
-            assert z["bal_dpo"].shape == (count, 4)
+            assert len(z["blog_blocks"]) * sm.balance_log.records_per_block + len(
+                z["blog_tail"]) == sm.balance_log.count
+        logged = sm.balance_log.export_all()
+        table = np.concatenate(sm._read_balances(np.arange(count)), axis=1)
+        assert (np.diff(logged["slot"].astype(np.int64)) > 0).all()
+        assert logged["slot"].tolist() == np.flatnonzero(table.any(axis=1)).tolist()
+        assert 0 < len(logged) < count
+        assert logged.view(np.uint64).reshape(-1, 9)[:, 1:].view(np.uint32).tobytes() == (
+            table[logged["slot"].astype(np.int64)].tobytes())
 
         for _ in range(3):
             pay(cluster, client, rng, next_id, count)
@@ -271,6 +286,8 @@ def test_a_checkpoint_of_many_batches_of_accounts_is_restored_to_the_byte(store_
         assert (snap["vsr.checkpoint.encode.read"]["total_ms"]
                 <= snap["vsr.checkpoint.encode"]["total_ms"] + 0.001)
         assert snap["vsr.checkpoint.blob_bytes"]["count"] == len(blob)
+        assert snap["vsr.checkpoint.balance_rows"]["count"] == len(logged)
+        assert "vsr.checkpoint.balance_dense" not in snap
 
         cluster.storages[0].sync()
         cluster.crash_replica(0)
@@ -291,21 +308,63 @@ def test_a_checkpoint_of_many_batches_of_accounts_is_restored_to_the_byte(store_
 
 
 def test_a_checkpoint_blob_grows_by_the_balances_alone():
-    """Each account adds its 64 bytes of balances to the blob, and the
-    account log's share of a block reference (28 bytes a block of 63
-    accounts at this size); the immutable 64 bytes are not in it. Beyond
-    that the blob holds a bounded overhead: headers, manifests, the free
-    set and the log's tail."""
-    cluster = Cluster(replica_count=1, sm_backend="jax")
-    client = cluster.clients[100]
-    client.register()
-    cluster.run_until(lambda: client.registered)
-    replica = cluster.replicas[0]
+    """A checkpoint writes the balances of the accounts touched since the
+    last one alone, to the balance log, and the blob carries none. Nine
+    hundred registrations add no row, and the blob grows with them by the
+    account log's block references (28 bytes a block of 63 accounts at this
+    size) and its tail alone, under 2 bytes an account. Transfers over a few accounts add one row for
+    each of them, ascending by slot, as the table holds it; transfers over
+    all of them add a row for each until the log would hold more rows than
+    two copies of the table: that checkpoint writes every slot afresh, and
+    the next ones append again."""
+    sm = StateMachine(TEST_MIN, backend="jax")
+    at = SimpleNamespace(state_machine=sm, clients={}, committed_timestamp_max=0,
+                               config_epoch=0, slot_epoch={})
+    log = sm.balance_log
     sizes = {}
     for first in range(1, 15 * N + 1, N):
-        create(cluster, client, first)
-        sizes[replica.state_machine.account_count] = len(replica._save_snapshot())
+        assert len(sm.create_accounts(accounts_batch(first, N))) == 0
+        sizes[sm.account_count] = len(snapshot.encode(at))
+    assert log.count == 0
     (c0, b0), (c1, b1) = min(sizes.items()), max(sizes.items())
-    assert 64 <= (b1 - b0) / (c1 - c0) < 66
-    for c, b in sizes.items():
-        assert 64 * c < b < 64 * c + (24 << 10)
+    assert 0 < (b1 - b0) / (c1 - c0) < 2
+    count = sm.account_count
+    next_id = iter(range(1, 1 << 20))
+
+    def pay(dr) -> set:
+        t = np.zeros(len(dr), dtype=types.TRANSFER_DTYPE)
+        t["id_lo"] = [next(next_id) for _ in dr]
+        t["debit_account_id_lo"], t["credit_account_id_lo"] = dr, dr % count + 1
+        t["amount_lo"], t["ledger"], t["code"] = 3, 1, 1
+        assert len(sm.create_transfers(t)) == 0
+        return {int(i) - 1 for i in np.concatenate([dr, dr % count + 1])}
+
+    def checkpoint() -> np.ndarray:
+        """The rows this checkpoint appended (the whole log where it was
+        rewritten); each equal to the table's slot."""
+        before = log.count
+        blob = snapshot.encode(at)
+        assert len(blob) < 32 * count  # half of what the balances alone would take
+        with np.load(io.BytesIO(blob)) as z:
+            assert not [k for k in z.files if k.startswith("bal_")]
+        rows = log.export_all()[before if log.count >= before else 0:]
+        table = np.concatenate(sm._read_balances(np.arange(count)), axis=1)
+        got = rows.view(np.uint64).reshape(-1, 9)[:, 1:].view(np.uint32)
+        assert got.tobytes() == table[rows["slot"].astype(np.int64)].tobytes()
+        return rows["slot"].tolist()
+
+    rng = np.random.default_rng(43)
+    touched = set()
+    for _ in range(3):
+        touched |= pay(rng.integers(1, count + 1, N).astype(np.uint64))
+    assert checkpoint() == sorted(touched) and len(touched) < count // 2
+    every = list(range(count))
+    for first in range(1, count + 1, N):
+        pay(np.arange(first, first + N, dtype=np.uint64))
+    assert checkpoint() == every and log.count == len(touched) + count
+    for first in range(1, count + 1, N):
+        pay(np.arange(first, first + N, dtype=np.uint64))
+    assert checkpoint() == every and log.count == count  # rewritten: 2 x 960 < 1,344 + 960
+    again = pay(np.arange(5, 5 + N, dtype=np.uint64))
+    assert checkpoint() == sorted(again) and log.count == count + len(again)
+    assert checkpoint() == [] and log.count == count + len(again)

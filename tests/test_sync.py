@@ -336,6 +336,48 @@ class TestChunkedSync:
         assert rb.state_machine.lookup_accounts(ids, zeros).tobytes() == want.tobytes()
         assert types.u128_of(want[0], "debits_posted") > 0
 
+    def test_a_sparse_checkpoint_syncs_its_balances_once_their_log_arrived(
+        self, monkeypatch
+    ):
+        """The checkpoints a receiver syncs are sparse: the balance log
+        holds rows for the 21 accounts the transfers touched, not the 120.
+        A receiver with an empty grid installs the blob with every balance
+        at zero, and has them from the log once block sync has brought its
+        blocks; then the table reads as the primary's, byte for byte."""
+        from tigerbeetle_tpu.io.storage import MemStorage
+        from tigerbeetle_tpu.vsr.replica import Replica
+
+        cl, bi, c = self._lagging_backup_cluster()
+        primary = next(r for r in cl.replicas if r is not None and r.is_primary)
+        slots = np.arange(120)
+        assert 0 < primary.state_machine.balance_log.count < 120
+        cl.storages[bi] = MemStorage(cl.zone.total_size, seed=997)
+        Replica.format(cl.storages[bi], cl.zone, cl.cluster_id, bi, 3)
+        seen = []
+        finish = Replica._finish_block_sync
+
+        def finish_and_look(replica):
+            sm = replica.state_machine
+            seen.append((sm.balance_log.count,
+                         np.concatenate(sm._read_balances(slots), axis=1).any()))
+            finish(replica)
+
+        monkeypatch.setattr(Replica, "_finish_block_sync", finish_and_look)
+        cl.restart_replica(bi)
+        target = max(r.commit_min for r in cl.replicas if r is not None)
+        cl.run_until(
+            lambda: cl.replicas[bi].commit_min >= target, max_ticks=200_000
+        )
+        rows, any_balance = seen[0]
+        assert 0 < rows < 120 and not any_balance
+        rb = cl.replicas[bi]
+        assert rb.commit_min == primary.commit_min
+        got, want = (
+            np.concatenate(r.state_machine._read_balances(slots), axis=1)
+            for r in (rb, primary)
+        )
+        assert got.tobytes() == want.tobytes() and want.any()
+
 
 class TestStaleInstallAbandon:
     def test_install_abandons_when_drain_overtakes_blob(self):

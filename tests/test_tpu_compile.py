@@ -25,6 +25,7 @@ import jax
 from jax.sharding import SingleDeviceSharding
 
 from tigerbeetle_tpu.constants import PRODUCTION, PRODUCTION_16M
+from tigerbeetle_tpu.models.state_machine import BALANCE_READ_CHUNK
 from tigerbeetle_tpu.ops import commit as commit_ops
 from tigerbeetle_tpu.ops import commit_exact
 
@@ -157,8 +158,9 @@ def test_create_transfers_exact(one_chip, has_pv, has_chains, walk, a):
 def test_the_balance_access_entries(one_chip, a):
     """`register_accounts` and `write_balances` at the batch bucket, and
     `read_balances` at the bucket (a `lookup_accounts` request: the one
-    program the benchmark's `compiles_in_window` reads) and over the whole
-    table (the checkpoint's `snapshot.encode`: every slot gathered)."""
+    program the benchmark's `compiles_in_window` reads) and at the
+    checkpoint's one shape (`snapshot.append_balances`: the touched slots,
+    or every slot, in chunks of `BALANCE_READ_CHUNK`)."""
     u32 = lambda *shape: np.zeros(shape, np.uint32)
     slots = np.zeros(N, np.int32)
     state = _ledger_state(a)
@@ -166,6 +168,6 @@ def test_the_balance_access_entries(one_chip, a):
              state, slots, u32(N), u32(N), np.zeros(N, bool))
     _compile(commit_ops.write_balances, one_chip,
              state, slots, u32(N, 4), u32(N, 4), u32(N, 4), u32(N, 4))
-    for k in (N, a):
+    for k in (N, BALANCE_READ_CHUNK):
         compiled = _compile(commit_ops.read_balances, one_chip, state, np.zeros(k, np.int32))
         assert [o.shape for o in compiled.out_info] == [(k, 4)] * 4

@@ -105,15 +105,16 @@ class Config:
 PRODUCTION = Config()
 # PRODUCTION with a balance table of 2^24 slots (1.125 GiB on the device:
 # 72 B a slot), for deployments whose accounts outnumber 2^20: TPC-B at
-# scale 160 is 16,001,920 of them. The checkpoint trailer carries every
-# account's balances (64 B each, vsr/snapshot.py), and the previous trailer
-# is only released once the new one is durable, so the grid has to hold
-# two: at 16,001,920 accounts 2 x 3,912 blocks of 256 KiB. The accounts'
-# immutable half is in the account log once: 3,908 blocks. Beside them the
-# store's content, which PRODUCTION's 2^15 blocks hold with room (a run of
-# 1,900 full batches peaks at 85% of them with two trailers of 515 blocks,
-# PERF.md section 4: some 26,800 blocks of content). 7,824 + 3,908 + 26,800
-# = 38,532 of 2^16 blocks, 59%.
+# scale 160 is 16,001,920 of them. The accounts' immutable half is in the
+# account log once: 3,908 blocks of 256 KiB. Their balances are in the
+# balance log, 72 B a row (vsr/snapshot.py), which holds at most two copies
+# of the table, and three while a checkpoint rewrites it (the old blocks
+# are released once the new checkpoint is durable): 3 x 4,397 blocks. The
+# trailers carry manifests alone. Beside them the store's content, which
+# PRODUCTION's 2^15 blocks hold with room (a run of 1,900 full batches
+# peaks at 85% of them with two trailers of 515 blocks, PERF.md section 4:
+# some 26,800 blocks of content). 13,191 + 3,908 + 26,800 = 43,899 of 2^16
+# blocks, 67%.
 PRODUCTION_16M = dataclasses.replace(
     PRODUCTION,
     name="production_16m",

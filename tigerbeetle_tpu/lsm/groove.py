@@ -6,7 +6,8 @@ declares four — accounts, transfers, posted, account_history,
 state_machine.zig:167-303). This build keeps accounts device/RAM-resident
 (they are the flagship kernel's working set, bounded by accounts_max),
 their immutable half also in a DurableLog in creation order (the accounts
-groove's object tree), and transfers in DurableLog + DurableIndex; this
+groove's object tree) and their balances, as of each checkpoint, in
+another, and transfers in DurableLog + DurableIndex; this
 module adds the remaining
 two grooves so NO state grows unbounded in Python structures:
 
@@ -49,7 +50,7 @@ HISTORY_DTYPE = np.dtype(
 
 # One account's immutable half, as the account log stores it: everything
 # create_accounts writes once. The balances are the other half; they change
-# with every transfer and stay on the device (and in the checkpoint blob).
+# with every transfer and stay on the device (and in the balance log below).
 ACCOUNT_LOG_DTYPE = np.dtype([
     ("id_lo", "<u8"), ("id_hi", "<u8"),
     ("user_data_128_lo", "<u8"), ("user_data_128_hi", "<u8"),
@@ -57,6 +58,17 @@ ACCOUNT_LOG_DTYPE = np.dtype([
     ("user_data_32", "<u4"), ("ledger", "<u4"), ("code", "<u4"), ("flags", "<u4"),
 ])
 assert ACCOUNT_LOG_DTYPE.itemsize == 64
+
+# One account's balances at a checkpoint, as the balance log stores them:
+# its slot, then the four u128 balances as (lo, hi) u64 pairs. A checkpoint
+# appends the rows of the accounts its ops touched; a later row of a slot
+# supersedes an earlier one.
+BALANCE_LOG_DTYPE = np.dtype([("slot", "<u8")] + [
+    (f"{name}_{half}", "<u8")
+    for name in ("debits_pending", "debits_posted", "credits_pending", "credits_posted")
+    for half in ("lo", "hi")
+])
+assert BALANCE_LOG_DTYPE.itemsize == 72
 
 
 class PostedGroove:

@@ -179,6 +179,17 @@ class DurableLog:
             self._tail[: self._tail_len].copy(),
         )
 
+    def release_all(self) -> None:
+        """Empty the log: its blocks go back to the grid (held until the
+        next checkpoint is durable, where the grid defers its frees), so
+        that a rewrite starts at row 0."""
+        for b in self.blocks:
+            self.grid.release(b)
+        self.blocks = []
+        self._pending_blocks = []
+        self._tail_len = 0
+        self.count = 0
+
     def restore(self, blocks: np.ndarray, tail: np.ndarray) -> None:
         self.blocks = [int(b) for b in blocks]
         self._pending_blocks = []

@@ -87,6 +87,11 @@ DISPATCH_WINDOW_MAX = PIPELINE_PREPARE_QUEUE_MAX
 # the refusal.
 EXACT_DISPATCH_MAX = 2
 
+# Slots a checkpoint reads back from the device in one `read_balances` call
+# (16 MiB of balances): every such read has this one shape, the last padded
+# to it, so a checkpoint compiles nothing new however many slots it reads.
+BALANCE_READ_CHUNK = 1 << 18
+
 _FAST_KERNEL = "create_transfers_fast"
 _EXACT_KERNEL = "create_transfers_exact"
 
@@ -287,9 +292,19 @@ class StateMachine:
         # once at creation in slot order: the checkpoint references its
         # blocks instead of re-encoding every account, and a restore
         # rebuilds the mirrors from it (restore_accounts).
-        from tigerbeetle_tpu.lsm.groove import ACCOUNT_LOG_DTYPE
+        from tigerbeetle_tpu.lsm.groove import ACCOUNT_LOG_DTYPE, BALANCE_LOG_DTYPE
 
         self.account_log = DurableLog(self.grid, ACCOUNT_LOG_DTYPE)
+        # The other half: each checkpoint appends the balances of the slots
+        # its ops touched (snapshot.encode), and a restore replays the rows
+        # in order (restore_balances).
+        self.balance_log = DurableLog(self.grid, BALANCE_LOG_DTYPE)
+        # One byte a slot, set for every slot a committed create_transfers
+        # batch names (_mark_balances) and cleared by the checkpoint that
+        # writes its row; the spare last byte takes the -1 of an id that
+        # names no account.
+        # tidy: owner=commit|loop — marked by the op apply, read and cleared by the checkpoint, which runs on a quiescent stage
+        self._balance_dirty = np.zeros(a + 1, dtype=bool)
         # Transfer-id membership pre-filter (no false negatives): keeps the
         # per-batch duplicate-id check O(batch) instead of O(tables).
         # tidy: owner=commit|store — adds are commit-side only (the store job passes add_bloom=False); probes are commit-side
@@ -465,6 +480,71 @@ class StateMachine:
         self._register_accounts(
             slots, self.acc_ledger[:count], self.acc_flags[:count],
             np.ones(count, dtype=bool),
+        )
+
+    def _mark_balances(self, *slots) -> None:  # tidy: thread=commit
+        """Mark the slots a committed create_transfers batch names, whatever
+        each event's result: every event's debit and credit account, and a
+        post/void's pending transfer's two as the store held them (-1 where
+        an id names no account). The set is a function of the batch and the
+        state before it, the same on every route the batch may take (and
+        after a bail or a refire), so the next checkpoint appends the same
+        balance-log rows on every replica."""
+        for s in slots:
+            self._balance_dirty[s] = True
+
+    def take_touched_slots(self) -> np.ndarray:
+        """The slots marked since the last call, ascending, and clear the
+        marks (the checkpoint's)."""
+        touched = np.flatnonzero(self._balance_dirty[: self.account_count])
+        self._balance_dirty[:] = False
+        return touched
+
+    def balance_read_chunk(self) -> int:
+        """The slots of one of the checkpoint's reads (BALANCE_READ_CHUNK,
+        or the whole table's bucket where it is smaller)."""
+        return min(BALANCE_READ_CHUNK, 1 << (self.config.accounts_max - 1).bit_length())
+
+    def balance_rows(self, slots: np.ndarray) -> np.ndarray:
+        """The balance-log rows of at most balance_read_chunk() slots, read
+        from the table in one call of that one shape."""
+        k = len(slots)
+        read = slots
+        if self._ops is not None:
+            read = np.zeros(self.balance_read_chunk(), dtype=np.int64)
+            read[:k] = slots  # the padding reads slot 0, sliced away below
+        dp, dpo, cp, cpo = self._read_balances(read)
+        rows = np.empty(k, dtype=self.balance_log.dtype)
+        u = rows.view(np.uint64).reshape(k, 9)
+        u[:, 0] = slots
+        for i, limbs in enumerate((dp, dpo, cp, cpo)):
+            # (k, 4) little-endian u32 limbs are (lo, hi) u64 pairs.
+            u[:, 1 + 2 * i : 3 + 2 * i] = np.ascontiguousarray(
+                limbs[:k], dtype="<u4"
+            ).view("<u8")
+        return rows
+
+    def restore_balances(self, count: int) -> None:
+        """Write the balances of the first `count` slots from the balance
+        log: zero, then every row in log order, a later row of a slot over
+        an earlier one (a checkpoint restore; the log's blocks must all be
+        present)."""
+        if count == 0:
+            return
+        table = np.zeros((count, 8), dtype=np.uint64)
+        for _base, rows in self.balance_log.scan_range(0, self.balance_log.count):
+            u = rows.view(np.uint64).reshape(len(rows), 9)
+            slots = u[:, 0].astype(np.int64)
+            # One checkpoint's rows ascend by slot: a slot repeats inside
+            # a window only across two checkpoints' rows, so the window is
+            # written run by run, each run's slots distinct.
+            cuts = np.flatnonzero(slots[1:] <= slots[:-1]) + 1
+            for s, e in zip(np.r_[0, cuts], np.r_[cuts, len(rows)]):
+                table[slots[s:e]] = u[s:e, 1:]
+        self._write_balances(
+            np.arange(count, dtype=np.int32),
+            *(np.ascontiguousarray(table[:, i : i + 2]).view(np.uint32)
+              for i in range(0, 8, 2)),
         )
 
     def _defer_store(self, recs: np.ndarray, ts=None) -> None:  # tidy: thread=commit
@@ -1216,11 +1296,12 @@ class StateMachine:
         self.state = new_state
         self._count_route("fast_batches")
         with tracer.span("sm.ct.post"):
-            return self._ct_post_fast(events, ts, codes_h[:n])
+            return self._ct_post_fast(events, ts, codes_h[:n], dr_slots, cr_slots)
 
-    def _ct_post_fast(self, events, ts, codes) -> np.ndarray:
+    def _ct_post_fast(self, events, ts, codes, dr_slots, cr_slots) -> np.ndarray:
         """The fast kernel's codes → the reply's result records, with the
-        OK rows deferred to the store."""
+        OK rows deferred to the store and the batch's slots marked."""
+        self._mark_balances(dr_slots, cr_slots)
         ok = codes == 0
         if np.any(ok):
             if ok.all():
@@ -1391,6 +1472,7 @@ class StateMachine:
             handle = {
                 "kernel": _FAST_KERNEL, "events": events, "ts": ts, "n": n,
                 "codes": codes_dev, "bail": bail_dev, "t_disp": t_disp,
+                "dr_slots": dr_slots, "cr_slots": cr_slots,
             }
         handle.update(
             timestamp=timestamp, prev_state=self.state, gen=self._state_gen,
@@ -1439,7 +1521,10 @@ class StateMachine:
             else:
                 self._count_route("fast_batches")
                 with tracer.span("sm.ct.post"):
-                    results = self._ct_post_fast(events, handle["ts"], codes_h[:n])
+                    results = self._ct_post_fast(
+                        events, handle["ts"], codes_h[:n],
+                        handle["dr_slots"], handle["cr_slots"],
+                    )
                     # The handle holds the last references to the kernel's outputs
                     # and to the state before it: letting go of device buffers can
                     # take as long as the posting, and is part of it.
@@ -1593,7 +1678,7 @@ class StateMachine:
         self._count_route("fast_batches")
         # The store is deferred past the reply send (replica._finish_commit
         # flushes in op order): the reply is fully determined here.
-        return self._ct_post_fast(events, ts, codes)
+        return self._ct_post_fast(events, ts, codes, dr_slots, cr_slots)
 
     def _device_batch(self, events, ts, dr_slots, cr_slots, host_code):
         """Pack events into the kernel's SoA form, padded to a power-of-two
@@ -1992,6 +2077,7 @@ class StateMachine:
                 "defer": defer,
                 "dr_hist": dr_hist, "cr_hist": cr_hist,
                 "dr_slots": dr_slots, "cr_slots": cr_slots,
+                "p_dr": pinfo_np["dr_slot"], "p_cr": pinfo_np["cr_slot"],
                 "pending_recs": pending_recs, "p_rec_idx": p_rec_idx,
                 "args": (b, host_code_p, pinfo, chain_id_p, plan, lines),
                 "has_pv": has_pv, "has_chains": has_chains,
@@ -2082,6 +2168,7 @@ class StateMachine:
             return None
         defer = x["defer"]
         self._count_route("exact_batches")
+        self._mark_balances(x["dr_slots"], x["cr_slots"], x["p_dr"], x["p_cr"])
         tracer.count("sm.exact.store_deferred", int(defer))
         with tracer.span("sm.ct.post"):
             codes = codes_h[:n]
@@ -2364,8 +2451,19 @@ class StateMachine:
         rows = self.transfer_index.lookup_batch(xfer_keys)
         found_rows = np.unique(rows[rows != NOT_FOUND])
         pend_acct_keys = np.zeros(0, dtype=acct_keys.dtype)
+        # The batch's marks (_mark_balances): the events' accounts, and a
+        # post/void's pending transfer's as the store holds it.
+        marked = [acct_keys]
         if len(found_rows):
             recs = self.transfer_log.gather(found_rows)
+            prow = rows[len(events):]
+            hit = ((events["flags"] & _PV_FLAGS) != 0) & (prow != NOT_FOUND)
+            if hit.any():
+                prec = recs[np.searchsorted(found_rows, prow[hit])]
+                marked += [
+                    pack_keys(prec["debit_account_id_lo"], prec["debit_account_id_hi"]),
+                    pack_keys(prec["credit_account_id_lo"], prec["credit_account_id_hi"]),
+                ]
             for rec in recs:
                 orc.transfers.preload(
                     types.u128_of(rec, "id"), oracle_mod.transfer_from_numpy(rec)
@@ -2384,6 +2482,8 @@ class StateMachine:
         # Writeback: balances to the device, new transfers to the log,
         # groove overlays into the durable grooves.
         self._writeback_accounts(orc)
+        slots = self.account_index.lookup_batch(np.concatenate(marked))
+        self._mark_balances(np.where(slots == NOT_FOUND, -1, slots.astype(np.int64)))
         new_ids = [
             i for i in dict.keys(orc.transfers) if i not in orc.transfers.fetched_keys
         ]

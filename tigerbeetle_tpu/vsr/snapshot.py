@@ -10,9 +10,13 @@ never execute code (it previously could: object-dtype arrays forced
 could pass the body checksum).
 
 Sections:
-  accounts   — the count and the exact u128 balances (u32 limbs); each
-               account's immutable fields live in the account log, whose
-               blocks the blob references like the transfer log's
+  accounts   — the count alone (version 9): each account's immutable fields
+               live in the account log, appended once at registration, and
+               its balances in the balance log, to which each checkpoint
+               appends the rows of the accounts touched since the last one
+               (or, once the log holds more rows than two copies of the
+               table, every account's afresh); the blob references both
+               logs' blocks and tails like the transfer log's
   transfers  — wire-layout TRANSFER_DTYPE rows, commit order
   posted     — pending-transfer fulfillment map (timestamp → u8)
   history    — HISTORY_DTYPE rows (reference AccountHistoryGrooveValue,
@@ -34,7 +38,9 @@ U64_MAX = (1 << 64) - 1
 
 # One AccountHistoryGrooveValue row; u128 values as (lo, hi) u64 pairs.
 # (The durable history groove stores exactly this layout on disk.)
-from tigerbeetle_tpu.lsm.groove import ACCOUNT_LOG_DTYPE, HISTORY_DTYPE  # noqa: E402
+from tigerbeetle_tpu.lsm.groove import (  # noqa: E402
+    ACCOUNT_LOG_DTYPE, BALANCE_LOG_DTYPE, HISTORY_DTYPE,
+)
 
 CLIENT_ENTRY_DTYPE = np.dtype(
     [
@@ -122,7 +128,7 @@ def content_logs(sm):
     """(prefix, DurableLog) for every object log the checkpoint persists."""
     return (
         ("log", sm.transfer_log), ("hlog", sm.history.log),
-        ("alog", sm.account_log),
+        ("alog", sm.account_log), ("blog", sm.balance_log),
     )
 
 
@@ -163,12 +169,37 @@ def _slot_epochs_array(replica) -> np.ndarray:
     return rows
 
 
+def append_balances(sm) -> None:
+    """Bring the balance log up to the table: the rows of the slots the
+    ops since the last checkpoint touched, in slot order, read from the
+    device in chunks of one shape. Where the log would then hold more rows
+    than two copies of the table, its blocks are released instead (held
+    until the checkpoint is durable) and every slot is written afresh:
+    the log never holds more than three copies. Every block is flushed."""
+    count = sm.account_count
+    log = sm.balance_log
+    slots = sm.take_touched_slots()
+    dense = log.count + len(slots) > 2 * count
+    if dense:
+        log.release_all()
+        slots = np.arange(count, dtype=np.int64)
+        tracer.count("vsr.checkpoint.balance_dense")
+    tracer.count("vsr.checkpoint.balance_rows", len(slots))
+    chunk = sm.balance_read_chunk()
+    for lo in range(0, len(slots), chunk):
+        with tracer.span("vsr.checkpoint.encode.read"):
+            rows = sm.balance_rows(slots[lo : lo + chunk])
+        log.append_batch(rows)
+        log.flush_pending()
+
+
 def encode(replica) -> bytes:
     """Serialize the replica's replicated state at its current commit
-    point. Transfers and the accounts' immutable fields stay in the grid;
-    the blob carries the balances, LSM manifests + fences, each log's
+    point, appending the touched balances to the balance log first.
+    Transfers and the accounts' fields and balances stay in the grid; the
+    blob carries the account count, LSM manifests + fences, each log's
     block list + tail, the referenced-block checksum list, and the EWAH
-    free set — O(accounts + tables), never O(history). The SAME blob
+    free set — O(tables), never O(accounts) or O(history). The SAME blob
     serves local recovery and state sync: a peer installs the RAM state and fetches
     whichever referenced blocks its own grid is missing (block-level
     sync, reference replica.zig:2289,2413). Every section is
@@ -178,9 +209,7 @@ def encode(replica) -> bytes:
     sm = replica.state_machine
     # A deferred (or async-queued) store must never miss a checkpoint.
     sm.store_barrier()
-    count = sm.account_count
-    with tracer.span("vsr.checkpoint.encode.read"):
-        dp, dpo, cp, cpo = sm._read_balances(np.arange(count, dtype=np.int64))
+    append_balances(sm)
 
     client_rows = np.zeros(len(replica.clients), dtype=CLIENT_ENTRY_DTYPE)
     reply_blobs: List[bytes] = []
@@ -194,6 +223,7 @@ def encode(replica) -> bytes:
         reply_blobs.append(raw)
 
     sections = dict(
+        # v9: the balances leave the blob for the balance log (blog_*).
         # v8: the eight immutable account columns leave the blob for the
         # account log (alog_*). v7: per-tree storm-request flags
         # (queued-but-unplanned major compactions; a PLANNED storm
@@ -203,9 +233,8 @@ def encode(replica) -> bytes:
         # compaction-job descriptors. No migration path between versions
         # — data files are not carried across builds; the bump is
         # diagnostic.
-        version=np.uint32(8),
-        account_count=np.int64(count),
-        bal_dp=dp, bal_dpo=dpo, bal_cp=cp, bal_cpo=cpo,
+        version=np.uint32(9),
+        account_count=np.int64(sm.account_count),
         prepare_timestamp=np.uint64(replica.committed_timestamp_max),
         commit_timestamp=np.uint64(sm.commit_timestamp),
         # Count of committed RECONFIGUREs at this checkpoint + per-slot
@@ -277,14 +306,8 @@ def encode(replica) -> bytes:
         dtype=np.uint8,
     )
 
-    # Written over a buffer of the blob's size (the sections' bytes and,
-    # generously, a page of npy and zip headers each), so that a blob of
-    # 128 B an account is not copied again each time a BytesIO grows.
-    buf = _io.BytesIO(bytes(
-        sum(np.asarray(v).nbytes + 4096 for v in sections.values())
-    ))
+    buf = _io.BytesIO()
     np.savez(buf, **sections)
-    buf.truncate()
     return buf.getvalue()
 
 
@@ -301,11 +324,10 @@ def block_checksums(blob: bytes) -> dict:
 
 
 _TREE_PREFIXES = ("ti", "ai", "qi", "po", "hi")
-_LOG_PREFIXES = ("log", "hlog", "alog")
-_BALANCES = ("bal_dp", "bal_dpo", "bal_cp", "bal_cpo")
+_LOG_PREFIXES = ("log", "hlog", "alog", "blog")
 
 _LOCAL_REQUIRED = (
-    "account_count", *_BALANCES,
+    "account_count",
     "prepare_timestamp", "commit_timestamp", "config_epoch",
     "slot_epochs", "client_table", "client_replies",
     *(f"{p}_{s}" for p in _TREE_PREFIXES
@@ -327,10 +349,9 @@ def validate(blob: bytes) -> bool:
         count = int(z["account_count"])
         if count < 0:
             return False
-        for k in _BALANCES:
-            if z[k].shape != (count, 4):
-                return False
         if z["alog_tail"].dtype != ACCOUNT_LOG_DTYPE or len(z["alog_tail"]) > count:
+            return False
+        if z["blog_tail"].dtype != BALANCE_LOG_DTYPE:
             return False
         if z["client_table"].dtype != CLIENT_ENTRY_DTYPE:
             return False
@@ -360,12 +381,14 @@ def free_set_bytes(blob: bytes) -> bytes | None:
 
 
 def rebuild_from_logs(sm) -> None:
-    """The RAM state a restore derives from the restored object logs: the
+    """The state a restore derives from the restored object logs: the
     account mirrors, id index and device registration from the account
-    log, then the transfer-id Bloom pre-filter (no false negatives
-    allowed: every stored id is re-added). Requires every log block to be
-    present, so a block-level sync receiver runs it once they arrived."""
+    log, the balances from the balance log, then the transfer-id Bloom
+    pre-filter (no false negatives allowed: every stored id is re-added).
+    Requires every log block to be present, so a block-level sync receiver
+    runs it once they arrived."""
     sm.restore_accounts(sm.account_count)
+    sm.restore_balances(sm.account_count)
     for _base, recs in sm.transfer_log.scan_range(0, sm.transfer_log.count):
         sm.transfer_seen.add(recs["id_lo"], recs["id_hi"])
 
@@ -379,8 +402,8 @@ def install(replica, blob: bytes, rebuild: bool = True,
 
     rebuild=False defers rebuild_from_logs (it scans the object logs'
     grid blocks, which a block-level sync receiver does not hold yet): the
-    accounts are then counted and their balances written, but none is
-    found by id until the caller runs it, once the blocks arrive.
+    accounts are then counted, but none is found by id and every balance
+    reads zero until the caller runs it, once the blocks arrive.
     block_cks_map: pre-parsed block_checksums(blob), when the caller
     already computed it (avoids re-parsing the multi-MB blob).
     """
@@ -391,10 +414,6 @@ def install(replica, blob: bytes, rebuild: bool = True,
     sm = replica.state_machine
     count = int(z["account_count"])
     sm.account_count = count
-    sm._write_balances(
-        np.arange(count, dtype=np.int32),
-        z["bal_dp"], z["bal_dpo"], z["bal_cp"], z["bal_cpo"],
-    )
     # Checkpoint state lives in the grid — rewind the free set to the
     # checkpoint and re-attach manifests / fences / log blocks (posted +
     # history grooves included).
